@@ -704,9 +704,10 @@ class PixelBufferApp:
                 session_validator = AllowListValidator()
         self.session_validator = session_validator
         batching = config.backend.batching
-        # config `backend.engine`: jax/auto -> probe the device link and
-        # pick; device/tpu -> force the accelerator path; host -> force
-        # the native host engine. `device-encode: false` forces host.
+        # config `backend.engine`: jax/auto -> measure the device link
+        # and pick; device/tpu -> the accelerator path, strictly (no
+        # chip found is a start-up error); host -> the native host
+        # engine. `device-encode: false` forces host.
         engine = {
             "jax": "auto", "auto": "auto",
             "device": "device", "tpu": "device",
@@ -973,14 +974,10 @@ class PixelBufferApp:
         from ..runtime.native import get_engine
 
         get_engine()
-        # likewise kick the accelerator probe in the background NOW:
-        # a wedged TPU tunnel costs the deploy (daemon thread), never
-        # a user's first request — serving starts on the host engine
-        # and upgrades when the probe lands
-        if self.pipeline._engine == "auto":
-            from ..runtime.device_probe import probe_nonblocking
-
-            probe_nonblocking()
+        # decide the engine NOW, in this process, before the port
+        # opens: `device` fails start-up when it finds no chip, `auto`
+        # measures the link once and says what it chose (/healthz)
+        self._engine_info = self.pipeline.resolve_engine()
 
     def make_app(self) -> web.Application:
         middlewares = [
@@ -1424,6 +1421,7 @@ class PixelBufferApp:
             "protocols": getattr(self, "_protocols_enabled", {}),
             "session": self._session_snapshot(),
             "ingest": self._ingest_snapshot(),
+            **self._engine_snapshot(),
             "device_queue": device_queue,
             "io": io_snapshot(),
             "request_budget_ms": self.request_budget_s * 1000.0,
@@ -1439,6 +1437,30 @@ class PixelBufferApp:
             body["probes"] = await self._probe_dependencies_throttled()
             body["breakers"] = BOARD.snapshot()  # probes mint breakers
         return web.json_response(body)
+
+    def _engine_snapshot(self) -> dict:
+        """/healthz engine block: which engine serves and why, the
+        device as ``jax.devices()`` reports it in THIS process (None
+        when the host engine never initialised a backend), the link
+        `auto` measured, and every device-path failure that degraded
+        to the host since start."""
+        from ..models.tile_pipeline import (
+            TILE_DEVICE_FALLBACK,
+            TILE_DEVICE_LANES,
+        )
+        from ..render.engine import RENDER_FALLBACK
+
+        info = self._engine_info
+        return {
+            "engine": info["engine"],
+            "engine_reason": info["reason"],
+            "device": info["device"],
+            "link_mbps": info["link_mbps"],
+            "auto_verdict": info["auto_verdict"],
+            "tile_device_lanes_total": TILE_DEVICE_LANES.total(),
+            "tile_device_fallback_total": TILE_DEVICE_FALLBACK.total(),
+            "render_fallback_total": RENDER_FALLBACK.total(),
+        }
 
     async def _probe_dependencies_throttled(self) -> dict:
         """/healthz is unauthenticated, so ``?probe=1`` must not be an
@@ -3104,17 +3126,6 @@ def create_app(
 
 def main(argv: Optional[list] = None) -> None:
     import argparse
-    import os
-
-    # Some PJRT plugins only honor the platform selection made through
-    # jax.config, not the JAX_PLATFORMS env var alone — mirror the env
-    # var before anything touches a backend so `JAX_PLATFORMS=cpu
-    # python -m ...http.server` reliably runs CPU-only.
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        import jax
-
-        jax.config.update("jax_platforms", platforms)
 
     parser = argparse.ArgumentParser(description="TPU pixel-buffer service")
     parser.add_argument("--config", default="conf/config.yaml")

@@ -188,6 +188,12 @@ class DevicePlaneCache:
                 "max_bytes": self.max_bytes,
                 "hits": self.hits,
                 "misses": self.misses,
+                # where the resident planes live (device ids): on a
+                # multi-chip host staging goes to the default device
+                "devices": sorted({
+                    d.id for p in self._planes.values()
+                    for d in p.devices()
+                }),
             }
 
     @property

@@ -21,10 +21,11 @@ built on device** with static shapes, in two modes:
   words) work with no sort and no wide gather windows; the previous
   per-bit window packer (kept as ``_pack_bits_gather`` for pinned
   comparison benches) cost an argsort plus a 24-wide token window per
-  128-bit chunk and measured 0.006 GB/s on TPU. On TPU backends the
-  word emit can also run as a Pallas kernel (ops/pallas/bitpack.py,
-  per-block token->VMEM emit; interpret mode pins bit-exactness on
-  CPU). Up-filtered microscopy tiles are run-heavy, so this genuinely
+  128-bit chunk and measured 0.006 GB/s on TPU. Two Pallas emit
+  kernels exist beside it (ops/pallas/bitpack.py, per-block
+  token->VMEM emit): interpret mode pins them bit-exact on CPU, but
+  the TPU compiler refuses both today, so the scan packer is what a
+  TPU runs. Up-filtered microscopy tiles are run-heavy, so this genuinely
   compresses (typically 2-4x) while leaving the host only PNG chunk
   framing. **Per lane**, if the RLE stream would come out larger than
   the stored-block encoding (pathological no-run payloads expand past
@@ -510,20 +511,17 @@ _PACKERS = ("scan", "pallas", "pallas_dense", "gather")
 
 
 def default_packer() -> str:
-    """'pallas' (the scalar-prefetch token-window emit kernel) on real
-    TPU backends, 'scan' (the XLA prefix-sum packer) everywhere else.
-    Overridable with OMPB_BITPACK=scan|pallas|pallas_dense|gather
-    ('pallas_dense' is the r9 dense compare-reduce kernel, kept as the
-    pinned comparison point)."""
+    """'scan' (the XLA prefix-sum packer) on every backend: it is the
+    one packer the TPU compiler accepts today. OMPB_BITPACK names
+    another of scan|pallas|pallas_dense|gather explicitly; the two
+    Pallas kernels do not lower on the chip (KNOWN_GAPS), so there
+    they raise at compile time and the caller counts the fallback."""
     import os
 
     forced = os.environ.get("OMPB_BITPACK")
     if forced in _PACKERS:
         return forced
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "scan"
-    except Exception:  # pragma: no cover - backend init failure
-        return "scan"
+    return "scan"
 
 
 # ---------------------------------------------------------------------------
@@ -918,14 +916,13 @@ def zlib_dynamic_batch(
 
 
 def _interpret_for(packer: str) -> bool:
-    """Pallas runs in interpret mode off-TPU (tests pin bit-exactness
-    on the CPU backend through exactly this path)."""
+    """Interpret mode is the CPU backend's way to run a Pallas kernel
+    (tests pin bit-exactness through exactly this path). On the TPU
+    backend it is always False: the kernel compiles for real or the
+    compiler's refusal surfaces."""
     if not packer.startswith("pallas"):
         return False
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    return jax.default_backend() != "tpu"
 
 
 def _streams_core(

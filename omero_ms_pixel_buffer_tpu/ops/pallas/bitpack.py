@@ -1,15 +1,20 @@
 """Pallas TPU kernels: deflate token bit-packing in VMEM.
 
 The scan packer (ops/device_deflate._pack_bits_scan) expresses bit
-packing as cumsums + a monotone searchsorted + gathers — all XLA ops.
-The kernels here are the TPU-native alternative: one lane's packed
-words stay RESIDENT in VMEM across a sequential grid walk over
-fixed-size token blocks, so the emit is a chain of small dense block
-computations with zero HBM traffic for intermediates. Two
-formulations:
+packing as cumsums + a monotone searchsorted + gathers — all XLA ops,
+and is what every backend runs. The kernels here are the intended
+TPU-native alternative: one lane's packed words stay RESIDENT in VMEM
+across a sequential grid walk over fixed-size token blocks, so the
+emit is a chain of small dense block computations with zero HBM
+traffic for intermediates. NEITHER LOWERS ON THE v5e TODAY (Mosaic
+refuses the (1, TB) block shape, the in-kernel 1-D gathers and the
+unaligned dynamic output strip — KNOWN_GAPS, ROADMAP S1,
+tests/test_chip_compile.py), so they run in interpret mode on the CPU
+backend only and are reachable by the explicit ``OMPB_BITPACK`` name.
+Two formulations:
 
-``pack_tokens_sp`` — the r12 scalar-prefetch kernel (the default
-behind packer name "pallas"). The per-block starting bit offsets are
+``pack_tokens_sp`` — the r12 scalar-prefetch kernel (packer name
+"pallas"). The per-block starting bit offsets are
 precomputed OUTSIDE the kernel (one XLA cumsum over the token bit
 counts) and handed to a ``pltpu.PrefetchScalarGridSpec`` as the
 scalar-prefetch operand, so every grid step knows its word window
@@ -159,8 +164,7 @@ def _kernel_sp(base_ref, bits_ref, nbits_ref, out_ref):
     gh2 = _shift_right(gh, 2)
     acc = (gl - gl1) + (gh1 - gh2)
     strip = (slice(0, 1), pl.ds(wstart, _SPAN))
-    cur = pl.load(out_ref, strip)
-    pl.store(out_ref, strip, cur | acc)
+    out_ref[strip] = out_ref[strip] | acc
 
 
 @partial(jax.jit, static_argnames=("maxbits", "interpret"))
@@ -249,8 +253,7 @@ def _kernel(bits_ref, nbits_ref, out_ref, off_ref):
         ).sum(axis=1)
     )
     strip = (slice(0, 1), pl.ds(wstart, _SPAN))
-    cur = pl.load(out_ref, strip)
-    pl.store(out_ref, strip, cur | acc.reshape(1, _SPAN))
+    out_ref[strip] = out_ref[strip] | acc.reshape(1, _SPAN)
     off_ref[0] = base + jnp.sum(nb)
 
 

@@ -60,7 +60,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
 def lane_counts(real: int, n_devices: int) -> List[int]:
     """How many REAL lanes each mesh device serves when ``real`` lanes
     pad to a multiple of ``n_devices`` and shard contiguously — the
-    per-device accounting the MULTICHIP record reports."""
+    per-device accounting `last_dispatch` reports."""
     if n_devices <= 0:
         return []
     per = (max(real, 0) + n_devices - 1) // n_devices

@@ -1,27 +1,21 @@
-"""Persistent XLA compilation cache.
+"""Persistent XLA compilation cache, placed from outside.
 
-The device encode programs cost tens of seconds to compile per shape on
-TPU (the RLE deflate's dense packer alone is ~20 s). A serving process
-pays that once — but deploy restarts and bench child processes would
-pay it again, so compiled executables persist on disk and reload in
-milliseconds.
+The device encode programs cost tens of seconds to compile per shape
+for the TPU (the 32-lane fused filter+deflate program ~80 s), so a
+cold process is mostly compiling; compiled executables persist on disk
+and reload in milliseconds. The directory is part of the cache key, so
+it never moves:
 
-Two ways in:
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling keeps the
+  cache there; this module sets no directory in code.
+- unset: the config key ``jax.compilation-cache-dir`` if the operator
+  gave one (engages on any backend), else ``<checkout>/.jax_cache`` on
+  the TPU backend only — CPU AOT entries reload across machines with
+  mismatched vector-feature sets (XLA warns of SIGILL), so a CPU
+  backend stays uncached unless someone asked.
 
-- config key ``jax.compilation-cache-dir`` (validated in
-  utils/config.py, passed through ``TilePipeline``): an EXPLICIT
-  operator opt-in, so it engages on any backend — jax.config updates
-  only, no PJRT init — and caches every compile (min-compile-time 0),
-  which is what lets a test observe that a second pipeline
-  construction reuses the dir. Sharing an explicit CPU cache dir
-  across machines with different vector-feature sets is on the
-  operator (XLA warns of SIGILL for mismatched AOT entries).
-- env ``OMPB_JAX_CACHE_DIR`` (or the default ~/.cache location): the
-  ambient path, TPU-only — TPU compiles are the tens-of-seconds
-  problem this cache solves, and implicit CPU caching would risk the
-  cross-machine AOT mismatch silently.
-
-Empty path disables.
+Every compile is cached, however small: a second start on a warm
+directory then compiles nothing at all.
 """
 
 from __future__ import annotations
@@ -32,89 +26,63 @@ from typing import Optional
 
 log = logging.getLogger("omero_ms_pixel_buffer_tpu.jax_cache")
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: the in-checkout default (listed in .gitignore)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+#: where the cache lives once an enable call ENGAGED it (jax's cache
+#: dir is process-global: the first engagement wins)
 _enabled_path: Optional[str] = None
-#: an enable call actually ENGAGED the cache (pins the dir for the
-#: process); a declined ambient attempt must NOT set this, or it
-#: would block a later explicit config opt-in in the same process
-_done = False
-#: the ambient (env/default) path was evaluated and declined — cached
-#: so per-batch enable_persistent_cache(None) calls stay one branch
-_ambient_declined = False
+#: the unconfigured default was evaluated and declined (non-TPU
+#: backend) — cached so per-batch calls stay one branch; it must not
+#: block a later configured opt-in in the same process
+_default_declined = False
+#: the last configured dir that lost to an earlier engagement
+_ignored: Optional[str] = None
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> None:
-    """Idempotent; call before the first device compile. ``path`` is
-    the explicit configured dir (``jax.compilation-cache-dir``); None
-    falls back to the env/default TPU-only behavior. The first call
-    that ENGAGES wins — a later call with a different path logs and
-    is ignored (jax's cache dir is process-global)."""
-    global _done, _enabled_path, _ambient_declined
-    explicit = bool(path)
-    if _done:
-        if explicit and path != _enabled_path:
+def enable_persistent_cache(configured: Optional[str] = None) -> None:
+    """Idempotent; call before the first device compile. ``configured``
+    is the operator's ``jax.compilation-cache-dir``, honoured only
+    when ``JAX_COMPILATION_CACHE_DIR`` is unset."""
+    global _enabled_path, _default_declined, _ignored
+    if _enabled_path is not None:
+        if configured and configured not in (_enabled_path, _ignored):
+            _ignored = configured  # say it once, not once per batch
             log.warning(
-                "persistent compile cache already pinned to %r; "
-                "ignoring %r", _enabled_path, path,
+                "persistent compile cache already at %r; ignoring %r",
+                _enabled_path, configured,
             )
         return
-    if not explicit:
-        if _ambient_declined:
-            return
-        path = os.environ.get(
-            "OMPB_JAX_CACHE_DIR",
-            os.path.join(
-                os.path.expanduser("~"), ".cache", "ompb-jax-cache"
-            ),
-        )
-    if not path:
-        _ambient_declined = True
+    if _default_declined and not configured:
         return
-    try:
-        import jax
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-        if not explicit and jax.default_backend() != "tpu":
-            # TPU compiles are the tens-of-seconds problem this cache
-            # solves; CPU AOT entries also reload across processes
-            # with mismatched machine-feature sets (XLA warns of
-            # SIGILL), so CPU backends stay uncached unless the
-            # operator opted in via the config key
-            _ambient_declined = True
-            if os.environ.get("OMPB_JAX_CACHE_DIR"):
-                log.info(
-                    "OMPB_JAX_CACHE_DIR set but backend is %s; the "
-                    "persistent compile cache only engages on TPU "
-                    "(use jax.compilation-cache-dir to force)",
-                    jax.default_backend(),
-                )
+    path = os.environ.get(ENV_VAR)
+    if path:
+        _ignored = configured  # the variable wins, by design
+    else:
+        if not configured and jax.default_backend() != "tpu":
+            _default_declined = True
             return
+        path = configured or DEFAULT_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # ambient mode caches every compile that took >1s — the
-        # probe-sized programs stay out, the encode/filter programs
-        # all qualify; explicit mode caches everything so restarts
-        # (and tests) hit the dir deterministically
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            0.0 if explicit else 1.0,
-        )
-        # jax latches the cache backend at its first compile: a dir
-        # configured AFTER any jit ran (explicit mode in a warm
-        # process) silently never engages unless the cache module is
-        # re-pointed. Best-effort private API, fully guarded.
-        try:  # pragma: no cover - exercised indirectly
-            from jax._src import compilation_cache as _cc
-
-            if hasattr(_cc, "reset_cache"):
-                _cc.reset_cache()  # re-initializes lazily at next compile
-        except Exception:
-            pass
-        _enabled_path = path
-        _done = True
-    except Exception:  # pragma: no cover - best-effort acceleration
-        log.debug("persistent compilation cache unavailable", exc_info=True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # jax latches the cache at its first compile; re-point it so a dir
+    # engaged after a jit already ran (a warm process) still takes
+    compilation_cache.reset_cache()
+    _enabled_path = path
+    log.info("persistent compile cache at %s", path)
 
 
 def enabled_path() -> Optional[str]:
-    """The pinned cache dir, or None when the cache never engaged."""
+    """Where the cache lives, or None when it never engaged."""
     return _enabled_path

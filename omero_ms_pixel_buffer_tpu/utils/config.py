@@ -618,11 +618,12 @@ class IngestConfig:
 class JaxConfig:
     """The jax: block — runtime knobs for the accelerator toolchain.
 
-    ``compilation-cache-dir`` pins jax's persistent XLA compilation
-    cache (runtime/jax_cache.py) so the device encode programs' tens-
-    of-seconds TPU compiles survive process restarts; an explicit dir
-    engages on ANY backend (operator opt-in), unlike the TPU-only
-    ``OMPB_JAX_CACHE_DIR`` env fallback."""
+    ``compilation-cache-dir`` places jax's persistent XLA compilation
+    cache (runtime/jax_cache.py) so the device encode programs'
+    minute-long TPU compiles survive process restarts. It is the
+    operator's choice only when ``JAX_COMPILATION_CACHE_DIR`` is
+    unset (the variable wins); it engages on ANY backend, unlike the
+    TPU-only ``<checkout>/.jax_cache`` default."""
 
     compilation_cache_dir: Optional[str] = None
 

@@ -66,6 +66,11 @@ class Counter:
         with self._lock:
             self._values[key] += value
 
+    def total(self) -> float:
+        """Sum over every label set (the /healthz roll-up)."""
+        with self._lock:
+            return sum(self._values.values())
+
     def collect(self, openmetrics: bool = False) -> Iterable[str]:
         family = (
             _om_family(self.name, self.kind) if openmetrics else self.name

@@ -614,10 +614,43 @@ class TestMeshDegradation:
             svc.close()
 
 
+class _Svc:  # pipeline construction needs only the signature probe
+    def get_pixel_buffer(self, image_id):
+        return None
+
+
 class TestCompilationCache:
-    """config `jax.compilation-cache-dir` -> runtime/jax_cache: the
-    explicit dir engages on any backend, device programs land in it,
-    and a second TilePipeline construction reuses the same dir."""
+    """runtime/jax_cache: the cache is placed from outside.
+    ``JAX_COMPILATION_CACHE_DIR`` set -> JAX's own handling, no
+    directory set in code; unset -> the config key
+    ``jax.compilation-cache-dir`` on any backend, else one fixed path
+    inside the checkout, TPU backend only."""
+
+    @pytest.fixture
+    def fresh_cache(self, monkeypatch):
+        """Un-engage the module for the test; put jax's process-global
+        cache settings back afterwards."""
+        import jax
+        from jax.experimental.compilation_cache import (
+            compilation_cache,
+        )
+
+        from omero_ms_pixel_buffer_tpu.runtime import jax_cache
+
+        names = (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs",
+        )
+        saved = {n: getattr(jax.config, n) for n in names}
+        monkeypatch.setattr(jax_cache, "_enabled_path", None)
+        monkeypatch.setattr(jax_cache, "_default_declined", False)
+        monkeypatch.setattr(jax_cache, "_ignored", None)
+        monkeypatch.delenv(jax_cache.ENV_VAR, raising=False)
+        yield jax_cache
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
 
     def test_config_key_validated(self):
         from omero_ms_pixel_buffer_tpu.utils.config import (
@@ -641,26 +674,16 @@ class TestCompilationCache:
                  "jax": {"compilation-cache-dirr": "/tmp/x"}}
             )
 
-    def test_second_pipeline_hits_cache_dir(self, tmp_path, monkeypatch):
+    def test_second_pipeline_hits_cache_dir(self, tmp_path, fresh_cache):
         import jax
 
-        from omero_ms_pixel_buffer_tpu.runtime import jax_cache
-
-        cache_dir = str(tmp_path / "xla-cache")
-        # the module pins the dir process-globally once; reset for the
-        # test (and restore after)
-        monkeypatch.setattr(jax_cache, "_done", False)
-        monkeypatch.setattr(jax_cache, "_enabled_path", None)
         from omero_ms_pixel_buffer_tpu.models.tile_pipeline import (
             TilePipeline,
         )
 
-        class _Svc:  # construction needs only the signature probe
-            def get_pixel_buffer(self, image_id):
-                return None
-
+        cache_dir = str(tmp_path / "xla-cache")
         TilePipeline(_Svc(), compilation_cache_dir=cache_dir)
-        assert jax_cache.enabled_path() == cache_dir
+        assert fresh_cache.enabled_path() == cache_dir
         assert jax.config.jax_compilation_cache_dir == cache_dir
         # a device encode program persists into the dir...
         payload = np.zeros((1, 513), np.uint8)
@@ -671,12 +694,49 @@ class TestCompilationCache:
         # (idempotent enable), so a re-jit after dropping the in-
         # memory caches reloads from disk instead of recompiling
         TilePipeline(_Svc(), compilation_cache_dir=cache_dir)
-        assert jax_cache.enabled_path() == cache_dir
+        assert fresh_cache.enabled_path() == cache_dir
         jax.clear_caches()
         zlib_rle_batch(payload)
         assert set(os.listdir(cache_dir)) == entries, (
             "second run recompiled instead of hitting the cache dir"
         )
+
+    def test_env_var_places_cache_and_code_sets_no_dir(
+        self, tmp_path, fresh_cache, monkeypatch
+    ):
+        import jax
+
+        env_dir = str(tmp_path / "from-env")
+        # what jax itself does with the variable at import time
+        monkeypatch.setenv(fresh_cache.ENV_VAR, env_dir)
+        jax.config.update("jax_compilation_cache_dir", env_dir)
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, val: (
+                updates.append(name), real_update(name, val)
+            ),
+        )
+        fresh_cache.enable_persistent_cache(str(tmp_path / "from-config"))
+        assert "jax_compilation_cache_dir" not in updates
+        assert fresh_cache.enabled_path() == env_dir
+        assert not (tmp_path / "from-config").exists()
+        jax.clear_caches()
+        zlib_rle_batch(np.zeros((1, 515), np.uint8))
+        assert os.listdir(env_dir), "the env-placed dir did not fill"
+
+    def test_default_is_in_checkout_and_tpu_only(self, fresh_cache):
+        import jax
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert fresh_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+        before = jax.config.jax_compilation_cache_dir
+        fresh_cache.enable_persistent_cache(None)  # CPU backend here
+        assert fresh_cache.enabled_path() is None
+        assert jax.config.jax_compilation_cache_dir == before
+        # a declined default never blocks a later configured opt-in
+        assert fresh_cache._default_declined
 
 
 # ---------------------------------------------------------------------------
@@ -874,6 +934,45 @@ class TestScalarPrefetchEmit:
             finally:
                 del os.environ["OMPB_BITPACK"]
 
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_default_packer_is_scan_on_every_backend(
+        self, monkeypatch, backend
+    ):
+        """The scan packer is the one the chip's compiler accepts; the
+        Pallas ones are reachable by explicit name only."""
+        import jax
+
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            default_packer,
+        )
+
+        monkeypatch.delenv("OMPB_BITPACK", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert default_packer() == "scan"
+
+    def test_interpret_is_cpu_only_and_never_a_default(self, monkeypatch):
+        """Interpret mode is how the CPU backend runs a Pallas kernel;
+        on tpu it is always False, and a backend that cannot be asked
+        is an error, not a quiet interpreter."""
+        import jax
+
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            _interpret_for,
+        )
+
+        assert _interpret_for("pallas") is True  # CPU backend here
+        assert _interpret_for("scan") is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for packer in ("pallas", "pallas_dense", "scan", "gather"):
+            assert _interpret_for(packer) is False
+
+        def broken():
+            raise RuntimeError("backend init failed")
+
+        monkeypatch.setattr(jax, "default_backend", broken)
+        with pytest.raises(RuntimeError, match="backend init failed"):
+            _interpret_for("pallas")
+
 
 # ---------------------------------------------------------------------------
 # Streaming cross-batch encode queue (r12)
@@ -978,7 +1077,7 @@ class TestStreamingQueue:
         real = disp._readback_group
 
         def wedged(*args, **kwargs):
-            gate.wait(timeout=60)  # simulates a dropped-tunnel hang
+            gate.wait(timeout=60)  # simulates a hung device program
             return real(*args, **kwargs)
 
         disp._readback_group = wedged

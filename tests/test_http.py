@@ -3,6 +3,7 @@ format matrix — the reference's manual-curl verification matrix
 (README.md:129-144) as automated tests, against a fake session store +
 synthetic fixtures (SURVEY.md §4)."""
 
+import asyncio
 import io
 import json
 
@@ -361,3 +362,119 @@ class TestGuardsAndFuzz:
                 assert 400 <= r.status < 500, (path, r.status)
 
         loop.run_until_complete(run())
+
+
+class TestEngineVisibility:
+    """One process on the chip, no quiet host: /healthz says which
+    engine serves, why, and on what device; `engine: device` is strict
+    at start-up; a device-path failure that degrades to the host is
+    counted, not just logged."""
+
+    @pytest.fixture
+    def device_client(self, tmp_path, loop):
+        write_ome_tiff(
+            str(tmp_path / "img.ome.tiff"), IMG, tile_size=(64, 64)
+        )
+        registry = ImageRegistry()
+        registry.add(1, str(tmp_path / "img.ome.tiff"))
+        config = Config.from_dict(
+            {"session-store": {"type": "memory"},
+             "cache": {"enabled": False},
+             "backend": {
+                 "engine": "device",
+                 "png": {"device-deflate": True},
+                 # wide window: two concurrent requests coalesce into
+                 # one batch (a singleton takes the single-request path)
+                 "batching": {"coalesce-window-ms": 100.0,
+                              "buckets": [64]},
+             }}
+        )
+        app_obj = PixelBufferApp(
+            config,
+            pixels_service=PixelsService(registry),
+            session_store=MemorySessionStore({"cookie-1": "omero-key-1"}),
+        )
+        client = TestClient(TestServer(app_obj.make_app()), loop=loop)
+        loop.run_until_complete(client.start_server())
+        yield client
+        loop.run_until_complete(client.close())
+
+    async def test_healthz_names_engine_reason_and_device(
+        self, device_client
+    ):
+        import jax
+
+        body = await (await device_client.get("/healthz")).json()
+        assert body["engine"] == "device"
+        assert body["engine_reason"] == "configured"
+        assert body["device"] == {
+            "platform": "cpu",
+            "kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()),
+        }
+        assert body["link_mbps"] > 0
+        assert body["auto_verdict"] == "host"  # no chip here
+        assert "tile_device_fallback_total" in body
+        assert "render_fallback_total" in body
+
+    async def test_host_engine_reports_no_device(self, client):
+        body = await (await client.get("/healthz")).json()
+        # conftest pins JAX_PLATFORMS=cpu, so the default `auto` is
+        # host and says why
+        assert body["engine"] == "host"
+        assert "JAX_PLATFORMS=cpu" in body["engine_reason"]
+        assert body["device"] is None
+
+    def test_device_engine_without_a_chip_fails_startup(
+        self, monkeypatch
+    ):
+        # nobody asked for the CPU (JAX_PLATFORMS unset), yet JAX
+        # finds no tpu backend: an error, not a CPU run
+        monkeypatch.delenv("JAX_PLATFORMS")
+        config = Config.from_dict(
+            {"session-store": {"type": "memory"},
+             "backend": {"engine": "device"}}
+        )
+        with pytest.raises(RuntimeError, match="found no TPU"):
+            PixelBufferApp(config)
+
+    async def test_device_group_failure_is_counted_and_tile_still_200(
+        self, device_client
+    ):
+        from omero_ms_pixel_buffer_tpu.resilience import INJECTOR
+        from omero_ms_pixel_buffer_tpu.resilience.faultinject import (
+            first_n,
+        )
+
+        async def fallbacks():
+            body = await (await device_client.get("/healthz")).json()
+            return body["tile_device_fallback_total"]
+
+        before = await fallbacks()
+        INJECTOR.install(
+            "device.encode-group",
+            first_n(1, RuntimeError("injected device group failure")),
+        )
+        try:
+            resps = await asyncio.gather(*(
+                device_client.get(
+                    f"/tile/1/0/0/0?x={x}&y=0&w=64&h=64&format=png",
+                    headers=AUTH,
+                )
+                for x in (0, 64)
+            ))
+            for x, resp in zip((0, 64), resps):
+                assert resp.status == 200
+                decoded = np.array(
+                    Image.open(io.BytesIO(await resp.read()))
+                )
+                np.testing.assert_array_equal(
+                    decoded.astype(np.uint16),
+                    IMG[0, 0, 0, :64, x : x + 64],
+                )
+            assert INJECTOR.calls("device.encode-group") >= 1
+        finally:
+            INJECTOR.clear()
+        assert await fallbacks() == before + 2  # both lanes of the group
+        metrics = await (await device_client.get("/metrics")).text()
+        assert 'tile_device_fallback_total{site="encode_group"}' in metrics

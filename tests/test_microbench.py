@@ -1,6 +1,5 @@
 """Kernel-only microbench (runtime/microbench) — shape/correctness on
-the CPU backend with tiny sizes; the real numbers come from bench.py's
-bounded device child on TPU."""
+the CPU backend with tiny sizes; rates come only from a chip run."""
 
 import zlib
 
@@ -8,7 +7,6 @@ import numpy as np
 import pytest
 
 from omero_ms_pixel_buffer_tpu.runtime.microbench import (
-    project_throughput,
     run_microbench,
     synth_tiles,
 )
@@ -127,24 +125,6 @@ class TestPinnedPackerComparison:
         filtered = filter_tiles(tiles, "up")
         _, lengths = deflate_filtered_batch(filtered, 32, 1 + 64)
         assert np.asarray(lengths).mean() < 0.2 * 32 * (1 + 64)
-
-
-class TestProjection:
-    def test_compute_and_link_bound_projections(self, micro):
-        proj = project_throughput(micro, link_mbps=10.0)
-        colo = proj["projected_colocated_tiles_per_sec"]
-        tun = proj["projected_tunnel_tiles_per_sec"]
-        assert 0 < tun <= colo  # a 10 MB/s link can only slow it down
-        compute_bound = micro["chain_tiles_per_sec_compute"]
-        assert colo <= compute_bound * 1.01 + 0.2  # rounding slack
-
-    def test_no_link_means_no_tunnel_projection(self, micro):
-        proj = project_throughput(micro, link_mbps=None)
-        assert "projected_tunnel_tiles_per_sec" not in proj
-        assert proj["projected_colocated_tiles_per_sec"] > 0
-
-    def test_incomplete_micro_yields_empty(self):
-        assert project_throughput({"batch": 4}, 10.0) == {}
 
 
 class TestDynamicHuffmanMetrics:

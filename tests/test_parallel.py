@@ -85,9 +85,6 @@ class TestSpaceParallel:
 
 class TestGraftEntry:
     def test_entry_compiles(self):
-        import sys
-
-        sys.path.insert(0, "/root/repo")
         import __graft_entry__ as g
 
         fn, args = g.entry()
@@ -100,3 +97,11 @@ class TestGraftEntry:
         g.dryrun_multichip(8)
         g.dryrun_multichip(4)
         g.dryrun_multichip(1)
+
+    def test_dryrun_with_too_few_devices_names_the_flag(self):
+        import __graft_entry__ as g
+
+        with pytest.raises(
+            RuntimeError, match="xla_force_host_platform_device_count=64"
+        ):
+            g.dryrun_multichip(64)
