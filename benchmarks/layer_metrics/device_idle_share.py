@@ -1,0 +1,8 @@
+"""Device: 1 - (union of device-op intervals / traced slice)."""
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    if not trace or not trace.get("window_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
