@@ -52,6 +52,7 @@ after a shrink or heal doesn't pay the recompile inline.
 from __future__ import annotations
 
 import concurrent.futures
+import itertools
 import logging
 import threading
 import time
@@ -67,7 +68,15 @@ log = logging.getLogger("omero_ms_pixel_buffer_tpu.device_dispatch")
 DEVICE_STAGE_SECONDS = REGISTRY.histogram(
     "device_stage_seconds",
     "Device encode pipeline stage durations "
-    "(stage=stage|h2d|compute|hist|emit|d2h|frame)",
+    "(stage=h2d|compute|hist|emit|d2h|frame)",
+)
+# a family of its own on purpose: a wait is no stage, and a new label
+# on device_stage_seconds would add itself to every sum over the family
+DEVICE_QUEUE_WAIT_SECONDS = REGISTRY.histogram(
+    "device_queue_wait_seconds",
+    "Per group, the waits before its first stage (where=pool: from "
+    "submit to the submit thread taking the group up; where=slot: for "
+    "one of the queue-depth in-flight slots)",
 )
 
 
@@ -79,6 +88,82 @@ def _observe_stage(duration: float, stage: str) -> None:
     a device-stage spike in a dashboard pivots to a citable trace."""
     DEVICE_STAGE_SECONDS.observe(duration, stage=stage)
     defer_exemplar(DEVICE_STAGE_SECONDS, duration, stage=stage)
+
+
+def _observe_wait(duration: float, where: str) -> None:
+    DEVICE_QUEUE_WAIT_SECONDS.observe(duration, where=where)
+
+
+def _annotation(what: str, gid: int, lanes: int):
+    """``jax.profiler.TraceAnnotation`` ``ompb.queue.<what>``, started
+    here and ended by its ``__exit__`` (on any thread). It lands on the
+    host plane of the profiler's trace, on the clock of the device's
+    operations, so a device idle gap names the stage of the group the
+    host was in; with no profiler running it is an atomic flag test."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(f"ompb.queue.{what}", group=gid, lanes=lanes)
+
+
+class _Span:
+    """One interval of one group on two clocks: a histogram (through
+    ``observe``) on the host's ``perf_counter`` and a profiler
+    annotation over the same interval. Both start at construction, so
+    a stage that starts on one thread and ends on another (the launch
+    on the submit thread, the wait on the readback worker) is built
+    where it starts and entered, ``with span:``, where it ends. An
+    exception ends the annotation and observes nothing, like the
+    stamp pairs this replaced."""
+
+    __slots__ = ("gid", "lanes", "t0", "t1", "_observe", "_note")
+
+    def __init__(self, observe, what: Optional[str] = None,
+                 gid: int = 0, lanes: int = 0):
+        self.gid, self.lanes = gid, lanes
+        self._observe = observe
+        # no `what`: the histogram alone (a wait)
+        self._note = None if what is None else _annotation(what, gid, lanes)
+        self.t0 = time.perf_counter()
+        self.t1: Optional[float] = None
+
+    def __enter__(self) -> "_Span":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.perf_counter()
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._observe(self.t1 - self.t0)
+
+    def end(self) -> None:
+        self.__exit__(None, None, None)
+
+
+class _Marks:
+    """The annotations of a group whose stage ends are stamped from
+    inside a callback (the mesh methods: ``MeshManager.dispatch`` may
+    run it twice), opened and closed by hand, one open at a time:
+    ``next(stage)`` ends the open one at the stamp it returns and
+    opens ``ompb.queue.<stage>``. The histogram keeps reading the
+    stamps."""
+
+    __slots__ = ("gid", "lanes", "_open")
+
+    def __init__(self, stage: str, gid: int, lanes: int):
+        self.gid, self.lanes = gid, lanes
+        self._open = _annotation(stage, gid, lanes)
+
+    def next(self, stage: str) -> float:
+        t = time.perf_counter()
+        self._open.__exit__(None, None, None)
+        self._open = _annotation(stage, self.gid, self.lanes)
+        return t
+
+    def close(self) -> None:
+        self._open.__exit__(None, None, None)
+
+
 DEVICE_QUEUE_IDLE_SECONDS = REGISTRY.histogram(
     "device_queue_idle_seconds",
     "Device idle gap between one encode group's compute finishing and "
@@ -135,6 +220,9 @@ class DeviceEncodeDispatcher:
             max_workers=1, thread_name_prefix="devenc-readback"
         )
         self._slots = threading.Semaphore(self.queue_depth)
+        # per-dispatcher group ids: every stage annotation, the waits
+        # and the submitting request's flight record carry one
+        self._gids = itertools.count(1)
         self._donate: Optional[bool] = None
         self._closed = False
         # outstanding caller futures: close() drains against these
@@ -208,6 +296,27 @@ class DeviceEncodeDispatcher:
         return self._donate
 
     # -- queue telemetry ------------------------------------------------
+
+    @staticmethod
+    def _stage(stage: str, gid: int, lanes: int) -> _Span:
+        """``with self._stage("hist", gid, n):`` observes
+        ``device_stage_seconds{stage=...}`` and holds the annotation
+        ``ompb.queue.<stage>`` over the same interval."""
+        return _Span(
+            lambda dt: _observe_stage(dt, stage), stage, gid, lanes
+        )
+
+    @staticmethod
+    def _wait(where: str) -> _Span:
+        """``device_queue_wait_seconds{where=...}``, a histogram only.
+        A wait is no annotation: a dozen groups stand in the pool at
+        every instant of a closed loop and the submit thread sits on
+        the semaphore three quarters of the time, so the trace's
+        reduction, which labels a device idle gap by the host event
+        name that covers most of it, called the gaps ``wait_pool`` and
+        ``wait_slot`` (measured on the chip, PR 26) and hid the stage
+        that was running."""
+        return _Span(lambda dt: _observe_wait(dt, where))
 
     def _note_launch(self, t_launch: float) -> None:
         """Called as a group's device program is dispatched: samples
@@ -342,13 +451,18 @@ class DeviceEncodeDispatcher:
         with self._pending_lock:
             self._pending.add(fut)
         fut.add_done_callback(self._discard_pending)
+        gid = next(self._gids)
         # capture the submitting request's flight record NOW (the
         # caller runs inside the batcher's record scope); the queue's
         # worker threads re-scope it per group for deferred exemplars
         rec = current_record()
+        if rec is not None:
+            rec.tag("device_group", gid)  # /debug/requests names the group
+        # the wait for the submit thread starts here and ends there
+        pool = self._wait("pool")
         try:
             self._submit_pool.submit(
-                self._run_stage, stage_fn, fut, args, rec
+                self._run_stage, stage_fn, fut, args, rec, gid, pool
             )
         except RuntimeError as e:
             # close() raced the _closed check and shut the pool down:
@@ -387,25 +501,32 @@ class DeviceEncodeDispatcher:
         except concurrent.futures.InvalidStateError:
             pass
 
-    def _run_stage(self, stage_fn, fut, args, rec=None) -> None:
+    def _run_stage(
+        self, stage_fn, fut, args, rec, gid: int, pool: _Span
+    ) -> None:
         """Submit-thread trampoline: acquire an in-flight slot, stage +
         launch, chain the readback future into the caller's. Any
         failure resolves the caller future exceptionally (the pipeline
-        host-falls-back that group) without touching other groups."""
+        host-falls-back that group) without touching other groups.
+        The group's two waits are observed here, once each, whatever
+        the stage function does: ``pool`` ends as this is entered,
+        ``slot`` spans the acquire."""
         from ..resilience.faultinject import INJECTOR
 
+        pool.end()
         acquired = False
         try:
             INJECTOR.fire("device.encode-group")
             # bounded in-flight groups: backpressure lands HERE (the
             # submit thread), keeping callers non-blocking and the
             # device at most queue_depth groups ahead of readback
-            self._slots.acquire()
+            with self._wait("slot"):
+                self._slots.acquire()
             acquired = True
             with self._stats_lock:
                 self._inflight += 1
             with record_scope(rec):
-                rfut = stage_fn(*args)
+                rfut = stage_fn(gid, *args)
         except Exception as e:
             # resolve the caller's future instead of raising into the
             # executor: the pipeline host-falls-back this group
@@ -436,11 +557,12 @@ class DeviceEncodeDispatcher:
     # -- staging (submit thread) ---------------------------------------
 
     def _stage_group(
-        self, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
+        self, gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
         lanes, sizes, bit_depth, color_type, staged,
     ):
         import jax
 
+        n = len(lanes)
         mesh_mgr = self.mesh_manager
         if mesh_mgr is not None and not staged:
             # sharded groups run ENTIRELY on the readback worker: the
@@ -460,25 +582,22 @@ class DeviceEncodeDispatcher:
                 # content-adaptive codes instead of downgrading to rle
                 return self._readback.submit(
                     self._tid_bound(self._mesh_dynamic_group),
-                    tiles, rows, row_bytes, bpp, filter_mode,
+                    gid, tiles, rows, row_bytes, bpp, filter_mode,
                     lanes, sizes, bit_depth, color_type,
                 )
             return self._readback.submit(
                 self._tid_bound(self._mesh_group),
-                tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
+                gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
                 lanes, sizes, bit_depth, color_type,
             )
-        t0 = time.perf_counter()
-        if staged:
-            batch_dev = tiles
-            t_h2d = time.perf_counter()
-        else:
-            batch_dev = jax.device_put(tiles)
-            # blocking on the INPUT transfer only: earlier groups'
-            # compute keeps the device busy meanwhile
-            jax.block_until_ready(batch_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary: waits on the transfer engine, overlapped with earlier groups' compute
-            t_h2d = time.perf_counter()
-        _observe_stage(t_h2d - t0, "h2d")
+        with self._stage("h2d", gid, n):
+            if staged:
+                batch_dev = tiles
+            else:
+                batch_dev = jax.device_put(tiles)
+                # blocking on the INPUT transfer only: earlier groups'
+                # compute keeps the device busy meanwhile
+                jax.block_until_ready(batch_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary: waits on the transfer engine, overlapped with earlier groups' compute
         if deflate_mode == "dynamic":
             from ..ops.device_deflate import fused_filter_histogram_batch
 
@@ -486,11 +605,13 @@ class DeviceEncodeDispatcher:
                 batch_dev, rows, row_bytes, bpp, filter_mode=filter_mode,
                 donate=(not staged) and self._donate_ok(),
             )
-            t_dispatch = time.perf_counter()
-            self._note_launch(t_dispatch)
+            # the stage starts at the launch, here; the readback
+            # worker ends it when it has pulled the counts
+            hist = self._stage("hist", gid, n)
+            self._note_launch(hist.t0)
             return self._readback.submit(
                 self._tid_bound(self._dynamic_readback_group),
-                flat, counts, extras, real_b, t_dispatch, lanes, sizes,
+                flat, counts, extras, real_b, hist, lanes, sizes,
                 bit_depth, color_type,
             )
         from ..ops.device_deflate import fused_filter_deflate_batch
@@ -501,16 +622,16 @@ class DeviceEncodeDispatcher:
             packer=self._packer,
             donate=(not staged) and self._donate_ok(),
         )
-        t_dispatch = time.perf_counter()
-        self._note_launch(t_dispatch)
+        compute = self._stage("compute", gid, n)  # launch -> ready
+        self._note_launch(compute.t0)
         return self._readback.submit(
             self._tid_bound(self._readback_group),
-            streams, lengths, t_dispatch, lanes, sizes,
+            streams, lengths, compute, lanes, sizes,
             bit_depth, color_type,
         )
 
     def _stage_render_group(
-        self, planes, index_tables, color_luts, rows, row_bytes,
+        self, gid, planes, index_tables, color_luts, rows, row_bytes,
         filter_mode, deflate_mode, lanes, sizes, mask=None,
         staged=False,
     ):
@@ -526,39 +647,37 @@ class DeviceEncodeDispatcher:
             # arrays already live on one chip.
             return self._readback.submit(
                 self._tid_bound(self._mesh_render_group),
-                planes, index_tables, color_luts, rows, row_bytes,
+                gid, planes, index_tables, color_luts, rows, row_bytes,
                 filter_mode, deflate_mode, lanes, sizes, mask,
             )
         from ..render.engine import fused_render_filter_deflate_batch
 
-        t0 = time.perf_counter()
-        if staged:
-            batch_dev, mask_dev = planes, mask
-            t_h2d = time.perf_counter()
-        else:
-            batch_dev = jax.device_put(planes)
-            mask_dev = None if mask is None else jax.device_put(mask)
-            # blocking on the INPUT transfer only: earlier groups'
-            # compute keeps the device busy meanwhile
-            jax.block_until_ready(batch_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary: waits on the transfer engine, overlapped with earlier groups' compute
-            t_h2d = time.perf_counter()
-        _observe_stage(t_h2d - t0, "h2d")
+        n = len(lanes)
+        with self._stage("h2d", gid, n):
+            if staged:
+                batch_dev, mask_dev = planes, mask
+            else:
+                batch_dev = jax.device_put(planes)
+                mask_dev = None if mask is None else jax.device_put(mask)
+                # blocking on the INPUT transfer only: earlier groups'
+                # compute keeps the device busy meanwhile
+                jax.block_until_ready(batch_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary: waits on the transfer engine, overlapped with earlier groups' compute
         streams, lengths = fused_render_filter_deflate_batch(
             batch_dev, index_tables, color_luts, rows, row_bytes,
             filter_mode=filter_mode, mode=deflate_mode,
             packer=self._packer, mask=mask_dev,
         )
-        t_dispatch = time.perf_counter()
-        self._note_launch(t_dispatch)
+        compute = self._stage("compute", gid, n)  # launch -> ready
+        self._note_launch(compute.t0)
         return self._readback.submit(
             self._tid_bound(self._readback_group),
-            streams, lengths, t_dispatch, lanes, sizes, 8, 2,
+            streams, lengths, compute, lanes, sizes, 8, 2,
         )
 
     # -- mesh groups (readback worker) ---------------------------------
 
     def _mesh_render_group(
-        self, planes, index_tables, color_luts, rows, row_bytes,
+        self, gid, planes, index_tables, color_luts, rows, row_bytes,
         filter_mode, deflate_mode, lanes, sizes, mask=None,
     ):
         """One sharded render group on the readback worker (same
@@ -576,6 +695,7 @@ class DeviceEncodeDispatcher:
 
         t0 = time.perf_counter()
         stamps = {}
+        marks = _Marks("h2d", gid, len(lanes))
 
         def _pad_lanes(arr, padded_b):
             b = arr.shape[0]
@@ -597,7 +717,7 @@ class DeviceEncodeDispatcher:
                     mesh, _pad_lanes(jnp.asarray(mask), padded_b)
                 )
             jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
-            stamps["h2d"] = time.perf_counter()
+            stamps["h2d"] = marks.next("compute")
             out = sharded_render_filter_deflate(
                 mesh, sharded, index_tables, color_luts, rows,
                 row_bytes, filter_mode=filter_mode,
@@ -606,9 +726,12 @@ class DeviceEncodeDispatcher:
             )
             return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
 
-        streams, lengths = self.mesh_manager.dispatch(
-            run, real_lanes=len(lanes), tag="render"
-        )
+        try:
+            streams, lengths = self.mesh_manager.dispatch(
+                run, real_lanes=len(lanes), tag="render"
+            )
+        finally:
+            marks.close()
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
         # noted AFTER the managed dispatch returns: dispatch() may
@@ -619,11 +742,11 @@ class DeviceEncodeDispatcher:
         _observe_stage(t_ready - t_h2d, "compute")
         self._note_compute_done(t_ready, t_ready - t_h2d)
         return self._pull_and_frame(
-            streams, lengths, t_ready, lanes, sizes, 8, 2
+            streams, lengths, gid, lanes, sizes, 8, 2
         )
 
     def _mesh_group(
-        self, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
+        self, gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
         lanes, sizes, bit_depth, color_type,
     ):
         """One sharded group on the readback worker: pad pow2 (the
@@ -641,6 +764,7 @@ class DeviceEncodeDispatcher:
 
         t0 = time.perf_counter()
         stamps = {}
+        marks = _Marks("h2d", gid, len(lanes))
 
         def run(mesh):
             n = mesh.shape["data"]
@@ -654,7 +778,7 @@ class DeviceEncodeDispatcher:
                 )
             sharded = shard_batch(mesh, batch)
             jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
-            stamps["h2d"] = time.perf_counter()
+            stamps["h2d"] = marks.next("compute")
             out = sharded_filter_deflate(
                 mesh, sharded, rows, row_bytes, bpp,
                 filter_mode=filter_mode, deflate_mode=deflate_mode,
@@ -665,9 +789,12 @@ class DeviceEncodeDispatcher:
             # shrinks, not at a later pull
             return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
 
-        streams, lengths = self.mesh_manager.dispatch(
-            run, real_lanes=len(lanes), tag="tiles"
-        )
+        try:
+            streams, lengths = self.mesh_manager.dispatch(
+                run, real_lanes=len(lanes), tag="tiles"
+            )
+        finally:
+            marks.close()
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
         # noted AFTER the managed dispatch returns: dispatch() may
@@ -678,12 +805,12 @@ class DeviceEncodeDispatcher:
         _observe_stage(t_ready - t_h2d, "compute")
         self._note_compute_done(t_ready, t_ready - t_h2d)
         return self._pull_and_frame(
-            streams, lengths, t_ready, lanes, sizes, bit_depth,
+            streams, lengths, gid, lanes, sizes, bit_depth,
             color_type,
         )
 
     def _mesh_dynamic_group(
-        self, tiles, rows, row_bytes, bpp, filter_mode,
+        self, gid, tiles, rows, row_bytes, bpp, filter_mode,
         lanes, sizes, bit_depth, color_type,
     ):
         """Dynamic-Huffman on the mesh: the two-pass chain with the
@@ -709,6 +836,7 @@ class DeviceEncodeDispatcher:
 
         t0 = time.perf_counter()
         stamps = {}
+        marks = _Marks("h2d", gid, len(lanes))
 
         def run(mesh):
             n = mesh.shape["data"]
@@ -722,22 +850,25 @@ class DeviceEncodeDispatcher:
                 )
             sharded = shard_batch(mesh, batch)
             jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
-            stamps["h2d"] = time.perf_counter()
+            stamps["h2d"] = marks.next("hist")
             flat, counts, extras = sharded_filter_histogram(
                 mesh, sharded, rows, row_bytes, bpp,
                 filter_mode=filter_mode,
             )
             counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the dynamic host hop (pass-1 counts, a few KB)
-            stamps["hist"] = time.perf_counter()
+            stamps["hist"] = marks.next("emit")
             tables = build_dynamic_tables(counts_np, extras_np, real=b)
             out = sharded_dynamic_emit(
                 mesh, flat, tables, packer=self._packer
             )
             return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
 
-        streams, lengths = self.mesh_manager.dispatch(
-            run, real_lanes=len(lanes), tag="dynamic"
-        )
+        try:
+            streams, lengths = self.mesh_manager.dispatch(
+                run, real_lanes=len(lanes), tag="dynamic"
+            )
+        finally:
+            marks.close()
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
         t_hist = stamps.get("hist", t_h2d)
@@ -747,7 +878,7 @@ class DeviceEncodeDispatcher:
         _observe_stage(t_ready - t_hist, "emit")
         self._note_compute_done(t_ready, t_ready - t_h2d)
         return self._pull_and_frame(
-            streams, lengths, t_ready, lanes, sizes, bit_depth,
+            streams, lengths, gid, lanes, sizes, bit_depth,
             color_type,
         )
 
@@ -781,7 +912,7 @@ class DeviceEncodeDispatcher:
         )
 
     def _stage_supertile_group(
-        self, stack, index_tables, color_luts, rel_rects,
+        self, gid, stack, index_tables, color_luts, rel_rects,
         tile_w, tile_h, filter_mode, deflate_mode, lanes,
     ):
         # mesh-only entry point (the pipeline routes single-device
@@ -790,12 +921,12 @@ class DeviceEncodeDispatcher:
         # worker so the blocking dispatch stays inside MeshManager
         return self._readback.submit(
             self._tid_bound(self._mesh_supertile_group),
-            stack, index_tables, color_luts, rel_rects,
+            gid, stack, index_tables, color_luts, rel_rects,
             tile_w, tile_h, filter_mode, deflate_mode, lanes,
         )
 
     def _mesh_supertile_group(
-        self, stack, index_tables, color_luts, rel_rects,
+        self, gid, stack, index_tables, color_luts, rel_rects,
         tile_w, tile_h, filter_mode, deflate_mode, lanes,
     ):
         """One mesh-fused super-tile on the readback worker: plan the
@@ -814,6 +945,8 @@ class DeviceEncodeDispatcher:
 
         t0 = time.perf_counter()
         stamps = {}
+        n_lanes = len(lanes)
+        marks = _Marks("h2d", gid, n_lanes)
         c, stack_h, stack_w = stack.shape
 
         def run(mesh):
@@ -831,7 +964,7 @@ class DeviceEncodeDispatcher:
             sub_dev = jnp.asarray(sub)
             coords_dev = jnp.asarray(coords)
             jax.block_until_ready(sub_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
-            stamps["h2d"] = time.perf_counter()
+            stamps["h2d"] = marks.next("compute")
             out = sharded_supertile_carve_deflate(
                 mesh, sub_dev, index_tables, color_luts, coords_dev,
                 tile_h, tile_w, filter_mode=filter_mode,
@@ -840,9 +973,12 @@ class DeviceEncodeDispatcher:
             out = jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
             return out, rows_map
 
-        (streams, lengths), rows_map = self.mesh_manager.dispatch(
-            run, real_lanes=len(lanes), tag="supertile"
-        )
+        try:
+            (streams, lengths), rows_map = self.mesh_manager.dispatch(
+                run, real_lanes=len(lanes), tag="supertile"
+            )
+        finally:
+            marks.close()
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
         self._note_launch(t_h2d)
@@ -852,27 +988,26 @@ class DeviceEncodeDispatcher:
         # custom pull: the real rows are scattered chip-major through
         # the slot padding, so pull the (tiny) lengths first, then the
         # kept rows' streams bounded by their true max
-        sel = np.asarray(rows_map, dtype=np.int64)
-        lengths_np = np.asarray(jax.device_get(lengths))[sel]  # ompb-lint: disable=jax-hotpath -- readback worker: lengths pull, a few bytes per lane
-        full_cap = streams.shape[1]
-        max_len = int(lengths_np.max()) if len(lanes) else 0
-        cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
-        streams_np = np.asarray(
-            jax.device_get(streams[:, :cap])  # ompb-lint: disable=jax-hotpath -- readback worker: the one bounded streams pull for the group
-        )[sel]
-        with self._stats_lock:
-            self._dd_cap[(tile_w, tile_h)] = min(
-                full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
-            )
-        t_d2h = time.perf_counter()
-        _observe_stage(t_d2h - t_ready, "d2h")
+        with self._stage("d2h", gid, n_lanes):
+            sel = np.asarray(rows_map, dtype=np.int64)
+            lengths_np = np.asarray(jax.device_get(lengths))[sel]  # ompb-lint: disable=jax-hotpath -- readback worker: lengths pull, a few bytes per lane
+            full_cap = streams.shape[1]
+            max_len = int(lengths_np.max()) if len(lanes) else 0
+            cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
+            streams_np = np.asarray(
+                jax.device_get(streams[:, :cap])  # ompb-lint: disable=jax-hotpath -- readback worker: the one bounded streams pull for the group
+            )[sel]
+            with self._stats_lock:
+                self._dd_cap[(tile_w, tile_h)] = min(
+                    full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
+                )
         out: Dict[int, bytes] = {}
-        for j, lane in enumerate(lanes):
-            out[lane] = frame_png(
-                streams_np[j, : int(lengths_np[j])].tobytes(),
-                tile_w, tile_h, 8, 2,
-            )
-        _observe_stage(time.perf_counter() - t_d2h, "frame")
+        with self._stage("frame", gid, n_lanes):
+            for j, lane in enumerate(lanes):
+                out[lane] = frame_png(
+                    streams_np[j, : int(lengths_np[j])].tobytes(),
+                    tile_w, tile_h, 8, 2,
+                )
         return out
 
     # -- mesh-resize jit warmup ----------------------------------------
@@ -974,7 +1109,7 @@ class DeviceEncodeDispatcher:
     # -- readback (readback worker) ------------------------------------
 
     def _dynamic_readback_group(
-        self, flat, counts, extras, real_b, t_dispatch, lanes, sizes,
+        self, flat, counts, extras, real_b, hist: _Span, lanes, sizes,
         bit_depth, color_type,
     ) -> Dict[int, bytes]:
         """Dynamic mode pass 2 on the readback worker: pull the pass-1
@@ -986,23 +1121,23 @@ class DeviceEncodeDispatcher:
 
         from ..ops.device_deflate import dynamic_emit_batch
 
-        counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion (pass-1 counts, a few KB)
-        t_hist = time.perf_counter()
-        _observe_stage(t_hist - t_dispatch, "hist")
-        streams, lengths = dynamic_emit_batch(
-            flat, counts_np, extras_np, packer=self._packer, real=real_b
-        )
-        jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        t_ready = time.perf_counter()
-        _observe_stage(t_ready - t_hist, "emit")
-        self._note_compute_done(t_ready, t_ready - t_dispatch)
+        gid = hist.gid
+        with hist:  # started at the launch, on the submit thread
+            counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion (pass-1 counts, a few KB)
+        with self._stage("emit", gid, hist.lanes) as emit:
+            streams, lengths = dynamic_emit_batch(
+                flat, counts_np, extras_np, packer=self._packer,
+                real=real_b,
+            )
+            jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+        self._note_compute_done(emit.t1, emit.t1 - hist.t0)
         return self._pull_and_frame(
-            streams, lengths, t_ready, lanes, sizes, bit_depth,
+            streams, lengths, gid, lanes, sizes, bit_depth,
             color_type,
         )
 
     def _readback_group(
-        self, streams, lengths, t_dispatch, lanes, sizes,
+        self, streams, lengths, compute: _Span, lanes, sizes,
         bit_depth, color_type,
     ) -> Dict[int, bytes]:
         """Runs on the readback worker: wait for the device, pull the
@@ -1011,17 +1146,17 @@ class DeviceEncodeDispatcher:
 
         # intended stage boundary: this thread EXISTS to absorb the
         # device wait so submitters never do
-        jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        t_ready = time.perf_counter()
-        _observe_stage(t_ready - t_dispatch, "compute")
-        self._note_compute_done(t_ready, t_ready - t_dispatch)
+        gid = compute.gid
+        with compute:  # started at the launch, on the submit thread
+            jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+        self._note_compute_done(compute.t1, compute.t1 - compute.t0)
         return self._pull_and_frame(
-            streams, lengths, t_ready, lanes, sizes, bit_depth,
+            streams, lengths, gid, lanes, sizes, bit_depth,
             color_type,
         )
 
     def _pull_and_frame(
-        self, streams, lengths, t_ready, lanes, sizes, bit_depth,
+        self, streams, lengths, gid, lanes, sizes, bit_depth,
         color_type,
     ) -> Dict[int, bytes]:
         """Shared tail: pull the compressed bytes in ONE sync (the
@@ -1030,37 +1165,37 @@ class DeviceEncodeDispatcher:
 
         from ..ops.png import frame_png
 
-        w, h = sizes[0]
-        full_cap = streams.shape[1]
-        # _dd_cap is shared with host-fallback paths on other threads;
-        # the stats lock makes the read-update pair coherent (r14
-        # lock-discipline burndown — was a documented KNOWN_GAPS item)
-        with self._stats_lock:
-            cap_hint = self._dd_cap.get(
-                (w, h), 1 << max(full_cap // 4, 64).bit_length()
-            )
-        guess = min(cap_hint, full_cap)
         real = len(lanes)
-        lengths_np, streams_np = jax.device_get(
-            (lengths[:real], streams[:real, :guess])
-        )
-        max_len = int(lengths_np.max()) if real else 0
-        if max_len > guess:
-            cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
-            # guess overflow: one extra pull, rare by construction
-            # (the cap tracks the running max)
-            streams_np = np.asarray(streams[:real, :cap])  # ompb-lint: disable=jax-hotpath -- guess-overflow path: a second bounded pull, not a per-lane sync
-        with self._stats_lock:
-            self._dd_cap[(w, h)] = min(
-                full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
+        with self._stage("d2h", gid, real):
+            w, h = sizes[0]
+            full_cap = streams.shape[1]
+            # _dd_cap is shared with host-fallback paths on other
+            # threads; the stats lock makes the read-update pair
+            # coherent (r14 lock-discipline burndown — was a documented
+            # KNOWN_GAPS item)
+            with self._stats_lock:
+                cap_hint = self._dd_cap.get(
+                    (w, h), 1 << max(full_cap // 4, 64).bit_length()
+                )
+            guess = min(cap_hint, full_cap)
+            lengths_np, streams_np = jax.device_get(
+                (lengths[:real], streams[:real, :guess])
             )
-        t_d2h = time.perf_counter()
-        _observe_stage(t_d2h - t_ready, "d2h")
+            max_len = int(lengths_np.max()) if real else 0
+            if max_len > guess:
+                cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
+                # guess overflow: one extra pull, rare by construction
+                # (the cap tracks the running max)
+                streams_np = np.asarray(streams[:real, :cap])  # ompb-lint: disable=jax-hotpath -- guess-overflow path: a second bounded pull, not a per-lane sync
+            with self._stats_lock:
+                self._dd_cap[(w, h)] = min(
+                    full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
+                )
         out: Dict[int, bytes] = {}
-        for j, lane in enumerate(lanes):
-            out[lane] = frame_png(
-                streams_np[j, : int(lengths_np[j])].tobytes(),
-                sizes[j][0], sizes[j][1], bit_depth, color_type,
-            )
-        _observe_stage(time.perf_counter() - t_d2h, "frame")
+        with self._stage("frame", gid, real):
+            for j, lane in enumerate(lanes):
+                out[lane] = frame_png(
+                    streams_np[j, : int(lengths_np[j])].tobytes(),
+                    sizes[j][0], sizes[j][1], bit_depth, color_type,
+                )
         return out

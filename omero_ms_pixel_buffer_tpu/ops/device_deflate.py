@@ -88,6 +88,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .kernel_scope import kernel
+
 _MOD = 65521  # largest prime < 2^16 (adler32 modulus)
 _BLOCK = 65535  # max stored-block payload (16-bit LEN)
 _MAX_MATCH = 258  # deflate maximum match length
@@ -205,6 +207,7 @@ def max_stream_len(payload_len: int) -> int:
     return 2 + _packing_maxbits(payload_len) // 8 + 4
 
 
+@kernel("ompb_frame")
 def _adler32_lane(payload: jax.Array) -> jax.Array:
     """adler32 for one lane: (L,) uint8 -> uint32 scalar.
 
@@ -229,6 +232,7 @@ def _adler32_lane(payload: jax.Array) -> jax.Array:
     return (s2.astype(jnp.uint32) << 16) | s1.astype(jnp.uint32)
 
 
+@kernel("ompb_frame")
 def _adler_bytes(adler: jax.Array) -> jax.Array:
     return jnp.stack(
         [
@@ -245,6 +249,7 @@ def _adler_bytes(adler: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@kernel("ompb_tokens")
 def _run_decompose(payload: jax.Array):
     """Z_RLE run decomposition without a serial scan.
 
@@ -283,6 +288,7 @@ def _run_decompose(payload: jax.Array):
     return is_lit, is_match, mlen
 
 
+@kernel("ompb_tokens")
 def _rle_tokens(payload: jax.Array):
     """Per-position fixed-Huffman (bits, nbits) token arrays from the
     Z_RLE decomposition."""
@@ -327,28 +333,32 @@ def _pack_bits_scan(bits: jax.Array, nbits: jax.Array, maxbits: int):
     scan, a monotone gather, or elementwise — no sort, no scatter, no
     per-bit work. Zero-length tokens (run interiors) contribute zero
     and need no compaction."""
-    ntok = bits.shape[0]
-    offs = jnp.cumsum(nbits) - nbits  # exclusive; non-decreasing
-    total_bits = offs[-1] + nbits[-1]
-    s = (offs & 31).astype(jnp.uint32)
-    val = bits.astype(jnp.uint32)
-    lo = val << s
-    # logical right shift by 32 - s without the s=0 UB: >> (31-s) >> 1
-    hi = (val >> (jnp.uint32(31) - s)) >> jnp.uint32(1)
     zero = jnp.zeros(1, jnp.uint32)
-    tl = jnp.concatenate([zero, jnp.cumsum(lo)])  # (ntok+1,)
-    th = jnp.concatenate([zero, jnp.cumsum(hi)])
-    nwords = maxbits // 32
-    edges = (jnp.arange(nwords, dtype=jnp.int32) + 1) * 32
-    c = jnp.searchsorted(offs, edges, side="left")  # tokens below edge
-    gl = tl[c]
-    gh = th[c]
-    gl1 = jnp.concatenate([zero, gl[:-1]])  # Tl[c[w-1]]
-    gh1 = jnp.concatenate([zero, gh[:-1]])  # Th[c[w-1]]
-    gh2 = jnp.concatenate([zero, gh1[:-1]])  # Th[c[w-2]]
-    words = (gl - gl1) + (gh1 - gh2)
-    shifts = (jnp.arange(4, dtype=jnp.uint32) * 8)[None, :]
-    packed = ((words[:, None] >> shifts) & 0xFF).astype(jnp.uint8)
+    # second-level scopes: under `ompb_pack` a trace names the packer's
+    # three steps, so its `%while` loop says which of them it is
+    with jax.named_scope("offsets"):
+        offs = jnp.cumsum(nbits) - nbits  # exclusive; non-decreasing
+        total_bits = offs[-1] + nbits[-1]
+        s = (offs & 31).astype(jnp.uint32)
+        val = bits.astype(jnp.uint32)
+        lo = val << s
+        # logical right shift by 32 - s without the s=0 UB: >> (31-s) >> 1
+        hi = (val >> (jnp.uint32(31) - s)) >> jnp.uint32(1)
+        tl = jnp.concatenate([zero, jnp.cumsum(lo)])  # (ntok+1,)
+        th = jnp.concatenate([zero, jnp.cumsum(hi)])
+    with jax.named_scope("searchsorted"):
+        nwords = maxbits // 32
+        edges = (jnp.arange(nwords, dtype=jnp.int32) + 1) * 32
+        c = jnp.searchsorted(offs, edges, side="left")  # tokens below edge
+    with jax.named_scope("gather"):
+        gl = tl[c]
+        gh = th[c]
+        gl1 = jnp.concatenate([zero, gl[:-1]])  # Tl[c[w-1]]
+        gh1 = jnp.concatenate([zero, gh[:-1]])  # Th[c[w-1]]
+        gh2 = jnp.concatenate([zero, gh1[:-1]])  # Th[c[w-2]]
+        words = (gl - gl1) + (gh1 - gh2)
+        shifts = (jnp.arange(4, dtype=jnp.uint32) * 8)[None, :]
+        packed = ((words[:, None] >> shifts) & 0xFF).astype(jnp.uint8)
     return packed.reshape(-1), total_bits
 
 
@@ -415,6 +425,7 @@ def _pack_bits_gather(bits: jax.Array, nbits: jax.Array, maxbits: int):
     return packed, total_bits
 
 
+@kernel("ompb_tokens")
 def _lane_tokens(payload: jax.Array) -> tuple:
     """(L,) payload -> (L+1,) (bits, nbits) token arrays including the
     block-header token (BFINAL=1, BTYPE=01 -> LSB-first value 3)."""
@@ -424,6 +435,7 @@ def _lane_tokens(payload: jax.Array) -> tuple:
     return bits, nbits
 
 
+@kernel("ompb_frame", static_argnames=("cap",))
 def _stored_lane(payload: jax.Array, adler: jax.Array, cap: int):
     """One lane's stored-block zlib stream, zero-padded to ``cap``
     bytes — the per-lane fallback when RLE would expand past the
@@ -447,6 +459,7 @@ def _stored_lane(payload: jax.Array, adler: jax.Array, cap: int):
     return jnp.pad(stream, (0, cap - stream.shape[0]))
 
 
+@kernel("ompb_frame", static_argnames=("eob_bits",))
 def _frame_lane(payload: jax.Array, packed: jax.Array, body_bits,
                 eob_bits: int = 7):
     """Zlib-frame one lane's packed deflate body, then pick per lane
@@ -488,6 +501,7 @@ def _zlib_rle(
     return jax.vmap(_frame_lane)(payloads, packed, body_bits)
 
 
+@kernel("ompb_pack", static_argnames=("maxbits", "packer", "interpret"))
 def _pack_dispatch(bits, nbits, maxbits: int, packer: str, interpret: bool):
     """Route batched token arrays through the selected packer."""
     if packer == "pallas":
@@ -534,8 +548,8 @@ def _adler32_device(payloads: jax.Array) -> jax.Array:
     return jax.vmap(_adler32_lane)(payloads)
 
 
-@jax.jit
-def _zlib_stored(payloads: jax.Array) -> jax.Array:
+@kernel("ompb_frame")
+def _stored_streams(payloads: jax.Array) -> jax.Array:
     b, n = payloads.shape
     nblocks = max(1, -(-n // _BLOCK))
     pieces = [
@@ -557,6 +571,9 @@ def _zlib_stored(payloads: jax.Array) -> jax.Array:
     adler = _adler32_device(payloads)
     pieces.append(jax.vmap(_adler_bytes)(adler))
     return jnp.concatenate(pieces, axis=1)
+
+
+_zlib_stored = jax.jit(_stored_streams)
 
 
 def zlib_stored_batch(payloads) -> jax.Array:
@@ -605,7 +622,13 @@ def _dyn_stats_lane(payload: jax.Array):
     symbol counts, () int32 total match extra bits). Runs the same
     run decomposition the emit pass reruns, so the counts describe
     exactly the tokens pass 2 will produce."""
-    is_lit, is_match, mlen = _run_decompose(payload)
+    return _symbol_counts(payload, *_run_decompose(payload))
+
+
+@kernel("ompb_hist")
+def _symbol_counts(payload, is_lit, is_match, mlen):
+    """The histogram half of pass 1: one scatter-add of the decomposed
+    tokens' symbols, and the sum of the match extra bits."""
     sym = jnp.where(
         is_lit,
         payload.astype(jnp.int32),
@@ -841,6 +864,7 @@ def build_dynamic_tables(
     return hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
 
 
+@kernel("ompb_tokens")
 def _dyn_lane_tokens(payload, lit_b, lit_n, ml_b, ml_n):
     """Pass-2 body tokens for one lane through ITS code tables."""
     is_lit, is_match, mlen = _run_decompose(payload)
@@ -850,6 +874,19 @@ def _dyn_lane_tokens(payload, lit_b, lit_n, ml_b, ml_n):
     nbits = jnp.where(
         is_lit, lit_n[payload], jnp.where(is_match, ml_n[mlen], 0)
     )
+    return bits, nbits
+
+
+@kernel("ompb_tokens")
+def _dyn_tokens(payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n):
+    """Pass-2 token arrays of a batch: header ++ body ++ explicit EOB."""
+    body_b, body_n = jax.vmap(_dyn_lane_tokens)(
+        payloads, lit_b, lit_n, ml_b, ml_n
+    )
+    bits = jnp.concatenate(
+        [hdr_b, body_b, eob_b[:, None].astype(jnp.uint32)], axis=1
+    )
+    nbits = jnp.concatenate([hdr_n, body_n, eob_n[:, None]], axis=1)
     return bits, nbits
 
 
@@ -867,13 +904,9 @@ def dynamic_emit_local(
     its exact total (header included) beats fixed, so every lane's
     bits fit the fixed worst-case ``_packing_maxbits`` and the stream
     cap stays ``max_stream_len(L)``."""
-    body_b, body_n = jax.vmap(_dyn_lane_tokens)(
-        payloads, lit_b, lit_n, ml_b, ml_n
+    bits, nbits = _dyn_tokens(
+        payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
     )
-    bits = jnp.concatenate(
-        [hdr_b, body_b, eob_b[:, None].astype(jnp.uint32)], axis=1
-    )
-    nbits = jnp.concatenate([hdr_n, body_n, eob_n[:, None]], axis=1)
     if packer == "gather":
         # the legacy window packer assumes >= 7-bit real tokens (its
         # WIN sizing); dynamic codes can be 1 bit, so route to scan
@@ -937,12 +970,37 @@ def _streams_core(
     return _zlib_rle(flat, packer, interpret)
 
 
+@kernel("ompb_filter", static_argnames=("rows", "row_bytes"))
+def _flatten_rows(filtered: jax.Array, rows: int, row_bytes: int):
+    """The leading rows x row_bytes region of each lane's filtered
+    scanlines as one payload: (B, H, RB) -> (B, rows * row_bytes)."""
+    return filtered[:, :rows, :row_bytes].reshape(filtered.shape[0], -1)
+
+
+@kernel(
+    "ompb_filter", static_argnames=("rows", "row_bytes", "bpp", "filter_mode")
+)
+def _filter_flat(tiles, rows: int, row_bytes: int, bpp: int, filter_mode: str):
+    """The filter half of the fused chains: native-dtype tiles
+    (B, H, W[, S]) -> big-endian byte rows -> filtered scanlines ->
+    flat payloads (B, rows * row_bytes)."""
+    from .convert import to_big_endian_bytes
+    from .png import _filter_batch
+
+    rows_be = to_big_endian_bytes(tiles)
+    if rows_be.ndim == 4:
+        # (B, H, W, S*itemsize) interleaved sample bytes -> scanrows
+        rows_be = rows_be.reshape(*rows_be.shape[:2], -1)
+    filtered = _filter_batch(rows_be, bpp, filter_mode)
+    return _flatten_rows(filtered, rows, row_bytes)
+
+
 @partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
 def _filtered_to_streams(
     filtered: jax.Array, rows: int, row_bytes: int, mode: str,
     packer: str, interpret: bool,
 ):
-    flat = filtered[:, :rows, :row_bytes].reshape(filtered.shape[0], -1)
+    flat = _flatten_rows(filtered, rows, row_bytes)
     return _streams_core(flat, mode, packer, interpret)
 
 
@@ -960,9 +1018,7 @@ def _pad_pow2_lanes(arr: jax.Array):
     return arr, b
 
 
-@partial(jax.jit, static_argnums=(1, 2))
-def _filtered_to_flat(filtered: jax.Array, rows: int, row_bytes: int):
-    return filtered[:, :rows, :row_bytes].reshape(filtered.shape[0], -1)
+_filtered_to_flat = jax.jit(_flatten_rows, static_argnums=(1, 2))
 
 
 def deflate_filtered_batch(
@@ -1002,15 +1058,7 @@ def filter_deflate_local(
     (streams, lengths). Traceable under jit, vmap, and shard_map —
     parallel/sharding.py maps exactly this over the mesh, which is
     what makes multi-chip bytes identical to single-device bytes."""
-    from .convert import to_big_endian_bytes
-    from .png import _filter_batch
-
-    rows_be = to_big_endian_bytes(tiles)
-    if rows_be.ndim == 4:
-        # (B, H, W, S*itemsize) interleaved sample bytes -> scanrows
-        rows_be = rows_be.reshape(*rows_be.shape[:2], -1)
-    filtered = _filter_batch(rows_be, bpp, filter_mode)
-    flat = filtered[:, :rows, :row_bytes].reshape(filtered.shape[0], -1)
+    flat = _filter_flat(tiles, rows, row_bytes, bpp, filter_mode)
     return _streams_core(flat, mode, packer, interpret)
 
 
@@ -1074,14 +1122,7 @@ def fused_filter_deflate_batch(
 
 
 def _filter_histogram_core(tiles, rows, row_bytes, bpp, filter_mode):
-    from .convert import to_big_endian_bytes
-    from .png import _filter_batch
-
-    rows_be = to_big_endian_bytes(tiles)
-    if rows_be.ndim == 4:
-        rows_be = rows_be.reshape(*rows_be.shape[:2], -1)
-    filtered = _filter_batch(rows_be, bpp, filter_mode)
-    flat = filtered[:, :rows, :row_bytes].reshape(filtered.shape[0], -1)
+    flat = _filter_flat(tiles, rows, row_bytes, bpp, filter_mode)
     counts, extras = jax.vmap(_dyn_stats_lane)(flat)
     return flat, counts, extras
 

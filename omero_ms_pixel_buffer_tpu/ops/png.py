@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .kernel_scope import kernel
+
 PNG_SIGNATURE = b"\x89PNG\r\n\x1a\n"
 
 # filter type codes (PNG spec 4.5.4)
@@ -221,6 +223,7 @@ def encode_png(
 # ---------------------------------------------------------------------------
 
 
+@kernel("ompb_filter", static_argnames=("bpp", "mode"))
 def _filter_batch(rows: jnp.ndarray, bpp: int, mode: str) -> jnp.ndarray:
     """rows: (B, H, RB) uint8 big-endian row bytes -> (B, H, 1+RB)
     filtered scanlines. Pure elementwise/shift ops; XLA fuses the whole
