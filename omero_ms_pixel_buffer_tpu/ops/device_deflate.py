@@ -16,12 +16,14 @@ built on device** with static shapes, in two modes:
   prefix-sum packer** (``_pack_bits_scan``): because tokens occupy
   disjoint bit ranges, the sum of their word-aligned contributions has
   no carries, so each output word is an exact difference of wrapping
-  prefix sums — two cumsums over tokens, one monotone ``searchsorted``
-  for word boundaries, two monotone gathers, all dense. O(tokens +
-  words) work with no sort and no wide gather windows; the previous
-  per-bit window packer (kept as ``_pack_bits_gather`` for pinned
-  comparison benches) cost an argsort plus a 24-wide token window per
-  128-bit chunk and measured 0.006 GB/s on TPU. Two Pallas emit
+  prefix sums — two cumsums over tokens, the token count below each
+  word boundary (``_tokens_below_edges``: one scatter of each word's
+  last token and one running maximum over the words), two monotone
+  gathers. O(tokens + words) work with no loop, no sort and no wide
+  gather windows; the previous per-bit window packer (kept as
+  ``_pack_bits_gather`` for pinned comparison benches) cost an argsort
+  plus a 24-wide token window per 128-bit chunk and measured 0.006
+  GB/s on TPU. Two Pallas emit
   kernels exist beside it (ops/pallas/bitpack.py, per-block
   token->VMEM emit): interpret mode pins them bit-exact on CPU, but
   the TPU compiler refuses both today, so the scan packer is what a
@@ -313,6 +315,26 @@ _TOKEN_VALUE_BITS = 20
 _TOKEN_MAX_NBITS = 21
 
 
+def _tokens_below_edges(offs: jax.Array, nwords: int) -> jax.Array:
+    """c[w] = how many tokens start below bit 32 * (w + 1): what
+    ``searchsorted(offs, edges, side="left")`` answers, without the
+    search. ``offs`` is non-decreasing, so the tokens below an edge are
+    those whose word index ``offs >> 5`` is at most w, and the last of
+    them is the last token of the nearest occupied word at or before
+    w: each word's last token writes its position + 1 (one scatter;
+    every other token, and any token at or beyond the last edge, aims
+    out of range and is dropped), and a running maximum carries the
+    count across the words no token starts in."""
+    word = offs >> 5
+    last_in_word = jnp.concatenate(
+        [word[1:] != word[:-1], jnp.ones(1, bool)]
+    )
+    ends = jnp.zeros(nwords, jnp.int32).at[
+        jnp.where(last_in_word, word, nwords)
+    ].set(jnp.arange(1, offs.shape[0] + 1, dtype=jnp.int32), mode="drop")
+    return lax.cummax(ends)
+
+
 def _pack_bits_scan(bits: jax.Array, nbits: jax.Array, maxbits: int):
     """Carry-free prefix-sum bit packer: token (bits, nbits) arrays ->
     (LSB-first packed bytes, total body bits).
@@ -329,13 +351,14 @@ def _pack_bits_scan(bits: jax.Array, nbits: jax.Array, maxbits: int):
                  +  (Th[c[w-1]] - Th[c[w-2]])    # spill from w-1
 
     with Tl/Th the wrapping cumsums and c[w] the token count below
-    each 32-bit boundary (one monotone searchsorted). Everything is a
-    scan, a monotone gather, or elementwise — no sort, no scatter, no
-    per-bit work. Zero-length tokens (run interiors) contribute zero
-    and need no compaction."""
+    each 32-bit boundary (``_tokens_below_edges``). Everything is a
+    scan, one scatter, a monotone gather, or elementwise — no loop,
+    no sort, no per-bit work. Zero-length tokens (run interiors)
+    contribute zero and need no compaction."""
     zero = jnp.zeros(1, jnp.uint32)
     # second-level scopes: under `ompb_pack` a trace names the packer's
-    # three steps, so its `%while` loop says which of them it is
+    # three steps (`searchsorted` is the boundary count: the name says
+    # what the step answers, and the benchmark's reader knows it)
     with jax.named_scope("offsets"):
         offs = jnp.cumsum(nbits) - nbits  # exclusive; non-decreasing
         total_bits = offs[-1] + nbits[-1]
@@ -347,9 +370,7 @@ def _pack_bits_scan(bits: jax.Array, nbits: jax.Array, maxbits: int):
         tl = jnp.concatenate([zero, jnp.cumsum(lo)])  # (ntok+1,)
         th = jnp.concatenate([zero, jnp.cumsum(hi)])
     with jax.named_scope("searchsorted"):
-        nwords = maxbits // 32
-        edges = (jnp.arange(nwords, dtype=jnp.int32) + 1) * 32
-        c = jnp.searchsorted(offs, edges, side="left")  # tokens below edge
+        c = _tokens_below_edges(offs, maxbits // 32)
     with jax.named_scope("gather"):
         gl = tl[c]
         gh = th[c]
@@ -491,10 +512,11 @@ def _frame_lane(payload: jax.Array, packed: jax.Array, body_bits,
 def _zlib_rle(
     payloads: jax.Array, packer: str = "scan", interpret: bool = False
 ) -> tuple:
-    # vmap, not lax.map: the scan packer fuses into streaming scans
-    # and monotone gathers, so batching lanes costs no extra residency
-    # — and the while-loop form compiled ~5x slower on TPU (measured
-    # 126s vs 26s for the 512-tile shape)
+    # vmap, not lax.map: the scan packer is scans, one scatter and
+    # monotone gathers, so batching lanes costs no extra residency.
+    # Compiling is what costs: on the v5e the packer alone takes
+    # 28-38 s at 1 lane and 49-59 s at 2 for the 512-tile shape
+    # (PERF.md §6, PR 27)
     bits, nbits = jax.vmap(_lane_tokens)(payloads)
     maxbits = _packing_maxbits(payloads.shape[1])
     packed, body_bits = _pack_dispatch(bits, nbits, maxbits, packer, interpret)

@@ -8,8 +8,9 @@ operation belongs to instead of ``%fusion.47``:
     ompb_filter   byteswap, PNG scanline filter, byte-row reshapes
     ompb_hist     pass-1 symbol counts of the dynamic encode
     ompb_tokens   run decomposition and per-position (bits, nbits)
-    ompb_pack     the bit packer (second level: offsets, searchsorted,
-                  gather, so a ``%while`` loop says what it is)
+    ompb_pack     the bit packer (second level: offsets, searchsorted
+                  -- the count of tokens below each word edge --
+                  and gather, so a trace says which step costs what)
     ompb_frame    adler32, zlib framing, the stored fallback
 
 ``kernel(scope)`` makes the function an inner ``jax.jit`` whose symbol

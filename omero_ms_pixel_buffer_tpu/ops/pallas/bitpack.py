@@ -1,8 +1,9 @@
 """Pallas TPU kernels: deflate token bit-packing in VMEM.
 
 The scan packer (ops/device_deflate._pack_bits_scan) expresses bit
-packing as cumsums + a monotone searchsorted + gathers — all XLA ops,
-and is what every backend runs. The kernels here are the intended
+packing as cumsums + a count of the tokens below each word edge (one
+scatter, one running maximum) + gathers — all XLA ops, and is what every
+backend runs. The kernels here are the intended
 TPU-native alternative: one lane's packed words stay RESIDENT in VMEM
 across a sequential grid walk over fixed-size token blocks, so the
 emit is a chain of small dense block computations with zero HBM

@@ -160,6 +160,109 @@ class TestPackerEquivalence:
         np.testing.assert_array_equal(s1, s2)
 
 
+def _cell_constant_nbits():
+    """Token bit counts of a 524800-byte constant payload (the cell's
+    512x512 uint16 shape) and the packer's word count for it: one
+    literal, then a 258-byte match every 258 positions and zero-length
+    tokens between."""
+    from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+        _lane_tokens,
+        _packing_maxbits,
+    )
+
+    nbits = np.asarray(_lane_tokens(np.full(524800, 7, np.uint8))[1])
+    return nbits, _packing_maxbits(524800) // 32
+
+
+# name -> (nbits, nwords): where a search is right for free and a
+# count can be wrong
+_BOUNDARY_CASES = {
+    "32 one-bit tokens in each word": lambda: (np.ones(32 * 5, np.int32), 6),
+    "21-bit tokens straddle every edge": lambda: (
+        np.full(400, 21, np.int32), 400 * 21 // 32 + 2),
+    "long stretches of zero-length tokens": _cell_constant_nbits,
+    "the stream fills maxbits exactly": lambda: (
+        np.full(2048 // 16, 16, np.int32), 2048 // 32),
+    "offsets at and beyond the last edge": lambda: (
+        np.full(10, 16, np.int32), 4),
+    "all-zero tail after total_bits": lambda: (
+        np.array([3, 9, 0, 0, 12, 1, 0], np.int32), 64),
+    "zero-length tokens first and last": lambda: (
+        np.array([0, 0, 31, 1, 0, 32 - 11, 11, 0, 0], np.int32), 5),
+}
+
+
+class TestBoundaryCount:
+    """The packer's boundary step, ``c[w]`` = tokens starting below bit
+    32 * (w + 1), is one scatter of each word's last token and a
+    running maximum; it must answer what the binary search it replaced
+    answers."""
+
+    @pytest.mark.parametrize("case", sorted(_BOUNDARY_CASES))
+    def test_matches_searchsorted(self, case):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            _tokens_below_edges,
+        )
+
+        nbits, nwords = _BOUNDARY_CASES[case]()
+        offs = np.cumsum(nbits) - nbits
+        edges = (np.arange(nwords) + 1) * 32
+        want = np.searchsorted(offs, edges, side="left")
+        got = np.asarray(_tokens_below_edges(offs.astype(np.int32), nwords))
+        assert got.dtype == np.int32 and got.shape == (nwords,)
+        np.testing.assert_array_equal(got, want)
+
+
+def _cell_payloads(lanes: int) -> np.ndarray:
+    """Up-filtered 512x512 uint16 scanlines, the cell's payloads
+    (L = 524800): noise over a ramp, and flat stretches of runs."""
+    from omero_ms_pixel_buffer_tpu.ops.png import filter_rows_np
+
+    r = np.random.default_rng(2700 + lanes)
+    yy, xx = np.mgrid[0:512, 0:512]
+    out = []
+    for lane in range(lanes):
+        tile = 2000 + 3 * xx + 2 * yy + r.normal(0, 120, (512, 512))
+        tile[100 * lane : 100 * lane + 200] = 4095  # run-heavy rows
+        be = tile.clip(0, 65535).astype(">u2").view(np.uint8)
+        out.append(filter_rows_np(be.reshape(512, 1024), 2, "up").ravel())
+    return np.stack(out)
+
+
+class TestCellShape:
+    """The benchmark cell's shape, 1 and 2 lanes: the fixed-Huffman
+    stream is the numpy twin's byte for byte (the twin still finds its
+    word boundaries with ``np.searchsorted``), and the dynamic stream
+    inflates to its payload."""
+
+    @pytest.mark.parametrize("lanes", [1, 2])
+    def test_rle_streams_are_the_numpy_twins(self, lanes):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import zlib_rle_np
+
+        payloads = _cell_payloads(lanes)
+        assert payloads.shape == (lanes, 524800)
+        streams, lengths = (np.asarray(a) for a in zlib_rle_batch(payloads))
+        for lane in range(lanes):
+            got = bytes(streams[lane][: lengths[lane]])
+            assert got == zlib_rle_np(payloads[lane]), lane
+            assert zlib.decompress(got) == payloads[lane].tobytes()
+
+    @pytest.mark.parametrize("lanes", [1, 2])
+    def test_dynamic_streams_round_trip(self, lanes):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            zlib_dynamic_batch,
+        )
+
+        payloads = _cell_payloads(lanes)
+        streams, lengths = (
+            np.asarray(a) for a in zlib_dynamic_batch(payloads)
+        )
+        for lane in range(lanes):
+            assert lengths[lane] <= stored_stream_len(524800)
+            got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
+            assert got == payloads[lane].tobytes(), lane
+
+
 class TestPallasBitpack:
     """The Pallas per-block VMEM-emit kernel, interpret mode on CPU:
     streams must decompress to the input AND be bit-exact against the

@@ -212,7 +212,7 @@ def test_kernel_symbols_survive_strip_debuginfo(lowered, program):
     assert symbols == PROGRAMS[program][0]
 
 
-def test_the_packers_second_level_names_its_loop(lowered):
+def test_the_packers_second_level_is_named_and_has_no_loop(lowered):
     text = hlo_text(lowered("_zlib_rle"))
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for step in ("offsets", "searchsorted", "gather"):
@@ -220,12 +220,11 @@ def test_the_packers_second_level_names_its_loop(lowered):
             re.search(rf"ompb_pack/(?:vmap\()?{step}\)?(?:/|$)", n)
             for n in names
         ), step
-    # the program's one loop is the binary search: inside the private
-    # function that `ompb_pack/searchsorted` calls (asserted above)
-    assert len(re.findall(r" while\(", text)) == 1
-    searchsorted = re.search(
-        r"\n%?(searchsorted[\w.]*) [^\n]*\{\n(.*?)\n\}", text, re.S)
-    assert searchsorted and " while(" in searchsorted.group(2)
+    # `searchsorted` was a binary search, a `while` of ~log2(tokens)
+    # gathers and 40% of the chip's busy time (PERF.md, PR 27); it is a
+    # count now, and neither emit program may grow a loop back
+    assert " while(" not in text
+    assert " while(" not in hlo_text(lowered("_zlib_dynamic"))
 
 
 def test_a_named_scope_alone_does_not_reach_the_cache_key():
