@@ -723,6 +723,7 @@ class PixelBufferApp:
             png_level=config.backend.png.level,
             png_strategy=config.backend.png.strategy,
             max_tile_bytes=config.backend.max_tile_mb << 20,
+            plane_cache_bytes=config.backend.plane_cache_mb << 20,
             device_deflate=config.backend.png.device_deflate,
             device_deflate_mode=config.backend.png.device_deflate_mode,
             queue_depth=config.backend.png.queue_depth,

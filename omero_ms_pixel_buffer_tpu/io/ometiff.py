@@ -24,6 +24,7 @@ so reads can be chunk-aligned and batched (SURVEY.md §7 step 3).
 from __future__ import annotations
 
 import base64
+import concurrent.futures
 import hashlib
 import logging
 import mmap
@@ -62,6 +63,10 @@ _SUPPORTED_COMPRESSIONS = (1, 5, 7, 8, 32773, 50000)
 # codecs the native batch decoder does NOT handle; their blocks decode
 # in-tree on the Python side of the batched read
 _PYTHON_SIDE_CODECS = (7, 50000)
+
+# a single-region read of at least this many compressed blocks goes
+# through the batched decode
+_BATCH_DECODE_BLOCKS = 16
 
 _TYPE_SIZES = {1: 1, 2: 1, 3: 2, 4: 4, 5: 8, 6: 1, 7: 1, 8: 2, 9: 4,
                10: 8, 11: 4, 12: 8, 16: 8, 17: 8, 18: 8}
@@ -777,6 +782,15 @@ class OmeTiffPixelBuffer(PixelBuffer):
 
     def get_tile_at(self, level, z, c, t, x, y, w, h) -> np.ndarray:
         reader = self._reader_for(z, c, t, x, y, w, h, level)
+        if (
+            reader.compressed
+            and len(reader.plan_region(x, y, w, h)) >= _BATCH_DECODE_BLOCKS
+        ):
+            # a region of many blocks (a whole plane on its way to
+            # HBM): decode them on the native pool, not one by one
+            tile = self.read_tiles([(z, c, t, x, y, w, h)], level)[0]
+            if tile is not None:
+                return tile
         return self._extract_channel(reader.read_region(x, y, w, h), c)
 
     def read_tiles(self, coords, level: int = 0):
@@ -905,15 +919,18 @@ def write_ome_tiff(
     predictor: int = 1,  # 2 = horizontal differencing (zlib/lzw/zstd)
     jpeg_quality: int = 90,
     jpeg_subsampling: int = 0,  # 0=4:4:4, 1=4:2:2, 2=4:2:0
+    workers: Optional[int] = None,
 ) -> None:
     """Write 5D TCZYX (or 6D TCZYXS for RGB, S=3) data as a (pyramidal)
     OME-TIFF: planes in XYCZT page order, pyramid levels as SubIFDs,
     tiled storage. ``bigtiff`` emits the 64-bit-offset layout
     (magic 43) used by whole-slide pyramids past 4 GB.
 
-    The writer assembles the file in memory (it exists for fixtures
-    and exports); writing an actual multi-GB slide needs RAM to match.
-    The READER is the production surface and mmaps files of any size.
+    Blocks are cut, byte-ordered and compressed on ``workers`` threads
+    (default: one per core; zlib, zstd and numpy release the GIL) and
+    streamed to the file in the serial order, a bounded number in
+    flight: the bytes are those of ``workers=1``, and the writer holds
+    a few blocks beyond its input, never a copy of the image.
     """
     if data.ndim == 6:
         if data.shape[5] != 3:
@@ -923,6 +940,7 @@ def write_ome_tiff(
     T, C, Z, Y, X = data.shape[:5]
     bo = ">" if big_endian else "<"
     dtype = data.dtype
+    be_dtype = dtype.newbyteorder(bo)
     comp_code = {
         None: 1, "zlib": 8, "lzw": 5, "packbits": 32773, "jpeg": 7,
         "zstd": 50000,
@@ -935,6 +953,8 @@ def write_ome_tiff(
         )
     if comp_code == 7 and dtype != np.dtype(np.uint8):
         raise TiffError("JPEG compression requires uint8 samples")
+    if workers is None:
+        workers = min(32, os.cpu_count() or 1)
     # JPEG tile streams ship abbreviated: tables go once into tag 347
     # (the reference reads this form through Bio-Formats); all tiles
     # share one table set because quality/subsampling are constant
@@ -961,13 +981,6 @@ def write_ome_tiff(
     fl = _TIFF_FLAVORS[bigtiff]
     cnt_fmt, cnt_len, entry_len = fl.cnt_fmt, fl.cnt_len, fl.entry_len
     inline, off_fmt, off_typ = fl.inline, fl.off_fmt, fl.off_typ
-
-    buf = bytearray()
-    if bigtiff:
-        buf += b"MM\x00+" if big_endian else b"II+\x00"
-        buf += struct.pack(bo + "HH", 8, 0) + b"\x00" * 8  # ifd0 ptr @8
-    else:
-        buf += (b"MM\x00*" if big_endian else b"II*\x00") + b"\x00" * 4
 
     def pack(fmt, *vals):
         return struct.pack(bo + fmt, *vals)
@@ -1016,38 +1029,68 @@ def write_ome_tiff(
             )
         return raw
 
+    def encode_tile(plane2d: np.ndarray, ty: int, tx: int) -> bytes:
+        tw, th = tile_size
+        nsamples = plane2d.shape[2] if plane2d.ndim == 3 else 1
+        block = np.zeros((th, tw) + plane2d.shape[2:], dtype=be_dtype)
+        sub = plane2d[ty : ty + th, tx : tx + tw]
+        block[: sub.shape[0], : sub.shape[1]] = sub  # byte-orders too
+        return encode_block(block.tobytes(), tw * nsamples, nsamples)
+
+    def encode_strip(plane2d: np.ndarray) -> bytes:
+        nsamples = plane2d.shape[2] if plane2d.ndim == 3 else 1
+        return encode_block(
+            plane2d.astype(be_dtype, copy=False).tobytes(),
+            plane2d.shape[1] * nsamples, nsamples,
+        )
+
+    workers = max(workers, 1)
+    pool = concurrent.futures.ThreadPoolExecutor(
+        workers, thread_name_prefix="tiff-write"
+    )
+    in_flight = 4 * workers
+    pos = 0  # the file position: everything below appends
+
+    def append(raw: bytes) -> None:
+        nonlocal pos
+        out.write(raw)
+        pos += len(raw)
+
+    def pad_even() -> None:
+        if pos % 2:
+            append(b"\x00")
+
     def write_blocks(plane2d: np.ndarray):
         """Write tiles (or one strip) for a 2D/3D plane; returns
-        (offsets, counts, tile_meta)."""
-        be = np.ascontiguousarray(plane2d.astype(dtype.newbyteorder(bo), copy=False))
-        nsamples = plane2d.shape[2] if plane2d.ndim == 3 else 1
+        (offsets, counts)."""
         offsets, counts = [], []
         if tile_size:
             tw, th = tile_size
-            for ty in range(0, plane2d.shape[0], th):
-                for tx in range(0, plane2d.shape[1], tw):
-                    block = np.zeros(
-                        (th, tw) + plane2d.shape[2:],
-                        dtype=dtype.newbyteorder(bo),
-                    )
-                    sub = be[ty : ty + th, tx : tx + tw]
-                    block[: sub.shape[0], : sub.shape[1]] = sub
-                    raw = encode_block(
-                        block.tobytes(), tw * nsamples, nsamples
-                    )
-                    offsets.append(len(buf))
-                    counts.append(len(raw))
-                    buf.extend(raw)
-                    if len(raw) % 2:
-                        buf.extend(b"\x00")
-        else:
-            raw = encode_block(
-                be.tobytes(), plane2d.shape[1] * nsamples, nsamples
+            jobs = (
+                (encode_tile, plane2d, ty, tx)
+                for ty in range(0, plane2d.shape[0], th)
+                for tx in range(0, plane2d.shape[1], tw)
             )
-            offsets.append(len(buf))
+        else:
+            jobs = iter([(encode_strip, plane2d)])
+
+        def emit(raw: bytes) -> None:
+            offsets.append(pos)
             counts.append(len(raw))
-            buf.extend(raw)
+            append(raw)
+            if tile_size and len(raw) % 2:
+                append(b"\x00")
+
+        pending: collections.deque = collections.deque()
+        for fn, *args in jobs:
+            pending.append(pool.submit(fn, *args))
+            if len(pending) >= in_flight:
+                emit(pending.popleft().result())
+        while pending:
+            emit(pending.popleft().result())
         return offsets, counts
+
+    next_pointers = []  # file position of each main IFD's next pointer
 
     def build_ifd(plane2d, description=None, sub_ifd_offsets=None) -> int:
         """Append pixel data + IFD for one plane image; returns the IFD
@@ -1112,52 +1155,62 @@ def write_ome_tiff(
             if typ in (2, 7):  # ASCII / UNDEFINED: raw bytes
                 raw = values
             else:
-                fmt = _TYPE_FMT[typ]
-                raw = b"".join(pack(fmt, v) for v in values)
+                raw = struct.pack(
+                    f"{bo}{len(values)}{_TYPE_FMT[typ]}", *values
+                )
             if len(raw) <= inline:
                 fields.append(raw + b"\x00" * (inline - len(raw)))
             else:
-                if len(buf) % 2:
-                    buf.extend(b"\x00")
-                fields.append(pack(off_fmt, len(buf)))
-                buf.extend(raw)
-        if len(buf) % 2:
-            buf.extend(b"\x00")
-        ifd_off = len(buf)
-        buf.extend(pack(cnt_fmt, len(entries)))
+                pad_even()
+                fields.append(pack(off_fmt, pos))
+                append(raw)
+        pad_even()
+        ifd_off = pos
+        table = [pack(cnt_fmt, len(entries))]
         for (tag, typ, count, _), field in zip(entries, fields):
-            buf.extend(pack("HH", tag, typ) + pack(off_fmt, count) + field)
-        buf.extend(pack(off_fmt, 0))  # next pointer (patched at chaining)
+            table.append(pack("HH", tag, typ) + pack(off_fmt, count) + field)
+        append(b"".join(table))
+        append(pack(off_fmt, 0))  # next pointer (patched at chaining)
         return ifd_off
 
-    main_offsets = []
-    first = True
-    for t in range(T):
-        for z in range(Z):
-            for c in range(C):  # XYCZT: C fastest
-                plane = data[t, c, z]
-                subs = []
-                level = plane
-                for _ in range(1, pyramid_levels):
-                    level = level[::2, ::2]
-                    subs.append(build_ifd(level))
-                main_offsets.append(
-                    build_ifd(
-                        plane,
-                        description=ome if first else None,
-                        sub_ifd_offsets=subs or None,
-                    )
+    try:
+        with open(path, "wb") as out:
+            if bigtiff:
+                append(b"MM\x00+" if big_endian else b"II+\x00")
+                append(struct.pack(bo + "HH", 8, 0) + b"\x00" * 8)  # ifd0 @8
+            else:
+                append(
+                    (b"MM\x00*" if big_endian else b"II*\x00")
+                    + b"\x00" * 4
                 )
-                first = False
+            main_offsets = []
+            first = True
+            for t in range(T):
+                for z in range(Z):
+                    for c in range(C):  # XYCZT: C fastest
+                        plane = data[t, c, z]
+                        subs = []
+                        level = plane
+                        for _ in range(1, pyramid_levels):
+                            level = level[::2, ::2]
+                            subs.append(build_ifd(level))
+                        main_offsets.append(
+                            build_ifd(
+                                plane,
+                                description=ome if first else None,
+                                sub_ifd_offsets=subs or None,
+                            )
+                        )
+                        # the next pointer is the last field written
+                        next_pointers.append(pos - struct.calcsize(off_fmt))
+                        first = False
 
-    # chain main IFDs
-    struct.pack_into(bo + off_fmt, buf, 8 if bigtiff else 4, main_offsets[0])
-    for prev, nxt in zip(main_offsets, main_offsets[1:]):
-        # next-pointer sits after the entry table of prev
-        (n,) = struct.unpack_from(bo + cnt_fmt, buf, prev)
-        struct.pack_into(
-            bo + off_fmt, buf, prev + cnt_len + entry_len * n, nxt
-        )
-
-    with open(path, "wb") as f:
-        f.write(buf)
+            # chain main IFDs: the header names the first, each one's
+            # next pointer the one after it
+            for at, target in zip(
+                [8 if bigtiff else 4] + next_pointers, main_offsets
+            ):
+                out.seek(at)
+                out.write(pack(off_fmt, target))
+    finally:
+        pool.shutdown(wait=True, cancel_futures=True)

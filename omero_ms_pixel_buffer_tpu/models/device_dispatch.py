@@ -164,6 +164,13 @@ class _Marks:
         self._open.__exit__(None, None, None)
 
 
+DEVICE_GROUP_LANES = REGISTRY.histogram(
+    "device_group_lanes",
+    "Real lanes of each encode group launched on the device, before "
+    "the padding to its program's lane count",
+    buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, float("inf")),
+)
+
 DEVICE_QUEUE_IDLE_SECONDS = REGISTRY.histogram(
     "device_queue_idle_seconds",
     "Device idle gap between one encode group's compute finishing and "
@@ -318,10 +325,12 @@ class DeviceEncodeDispatcher:
         that was running."""
         return _Span(lambda dt: _observe_wait(dt, where))
 
-    def _note_launch(self, t_launch: float) -> None:
-        """Called as a group's device program is dispatched: samples
-        occupancy and classifies the launch as overlapped (the device
-        was still computing the previous group) or post-idle-gap."""
+    def _note_launch(self, t_launch: float, lanes: int) -> None:
+        """Called as a group's device program is dispatched: counts
+        the group and its real lanes, samples occupancy and classifies
+        the launch as overlapped (the device was still computing the
+        previous group) or post-idle-gap."""
+        DEVICE_GROUP_LANES.observe(lanes)
         with self._stats_lock:
             self._groups += 1
             self._occupancy_sum += self._inflight
@@ -399,7 +408,9 @@ class DeviceEncodeDispatcher:
         """Enqueue one encode group; returns a Future resolving to
         {lane_index: png_bytes}. ``tiles`` is either a host ndarray
         (bucket path — staged H2D on the submit thread) or an already
-        device-resident batch (plane-cache crops, ``staged=True``).
+        device-resident batch the caller gives up (plane-cache crops,
+        ``staged=True``: donated to the program like a host-staged one;
+        its lane axis may be padded beyond ``lanes``).
         All lanes in a group share one real (w, h) — ``rows``/
         ``row_bytes`` describe it — but ``sizes`` still rides along
         for framing. Returns immediately: staging happens on the
@@ -603,15 +614,18 @@ class DeviceEncodeDispatcher:
 
             flat, counts, extras, real_b = fused_filter_histogram_batch(
                 batch_dev, rows, row_bytes, bpp, filter_mode=filter_mode,
-                donate=(not staged) and self._donate_ok(),
+                donate=self._donate_ok(),
             )
             # the stage starts at the launch, here; the readback
             # worker ends it when it has pulled the counts
             hist = self._stage("hist", gid, n)
-            self._note_launch(hist.t0)
+            self._note_launch(hist.t0, len(lanes))
+            # a plane-cache group arrives with its lane axis already
+            # padded: the host plans only the real lanes, as it does
+            # for a group it padded itself
             return self._readback.submit(
                 self._tid_bound(self._dynamic_readback_group),
-                flat, counts, extras, real_b, hist, lanes, sizes,
+                flat, counts, extras, min(real_b, n), hist, lanes, sizes,
                 bit_depth, color_type,
             )
         from ..ops.device_deflate import fused_filter_deflate_batch
@@ -620,10 +634,10 @@ class DeviceEncodeDispatcher:
             batch_dev, rows, row_bytes, bpp,
             filter_mode=filter_mode, mode=deflate_mode,
             packer=self._packer,
-            donate=(not staged) and self._donate_ok(),
+            donate=self._donate_ok(),
         )
         compute = self._stage("compute", gid, n)  # launch -> ready
-        self._note_launch(compute.t0)
+        self._note_launch(compute.t0, len(lanes))
         return self._readback.submit(
             self._tid_bound(self._readback_group),
             streams, lengths, compute, lanes, sizes,
@@ -668,7 +682,7 @@ class DeviceEncodeDispatcher:
             packer=self._packer, mask=mask_dev,
         )
         compute = self._stage("compute", gid, n)  # launch -> ready
-        self._note_launch(compute.t0)
+        self._note_launch(compute.t0, len(lanes))
         return self._readback.submit(
             self._tid_bound(self._readback_group),
             streams, lengths, compute, lanes, sizes, 8, 2,
@@ -737,7 +751,7 @@ class DeviceEncodeDispatcher:
         # noted AFTER the managed dispatch returns: dispatch() may
         # re-invoke run() once on a probe-shrink retry, and the queue
         # telemetry must count each submitted group exactly once
-        self._note_launch(t_h2d)
+        self._note_launch(t_h2d, len(lanes))
         _observe_stage(t_h2d - t0, "h2d")
         _observe_stage(t_ready - t_h2d, "compute")
         self._note_compute_done(t_ready, t_ready - t_h2d)
@@ -800,7 +814,7 @@ class DeviceEncodeDispatcher:
         # noted AFTER the managed dispatch returns: dispatch() may
         # re-invoke run() once on a probe-shrink retry, and the queue
         # telemetry must count each submitted group exactly once
-        self._note_launch(t_h2d)
+        self._note_launch(t_h2d, len(lanes))
         _observe_stage(t_h2d - t0, "h2d")
         _observe_stage(t_ready - t_h2d, "compute")
         self._note_compute_done(t_ready, t_ready - t_h2d)
@@ -872,7 +886,7 @@ class DeviceEncodeDispatcher:
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
         t_hist = stamps.get("hist", t_h2d)
-        self._note_launch(t_h2d)
+        self._note_launch(t_h2d, len(lanes))
         _observe_stage(t_h2d - t0, "h2d")
         _observe_stage(t_hist - t_h2d, "hist")
         _observe_stage(t_ready - t_hist, "emit")
@@ -981,7 +995,7 @@ class DeviceEncodeDispatcher:
             marks.close()
         t_ready = time.perf_counter()
         t_h2d = stamps.get("h2d", t0)
-        self._note_launch(t_h2d)
+        self._note_launch(t_h2d, len(lanes))
         _observe_stage(t_h2d - t0, "h2d")
         _observe_stage(t_ready - t_h2d, "compute")
         self._note_compute_done(t_ready, t_ready - t_h2d)

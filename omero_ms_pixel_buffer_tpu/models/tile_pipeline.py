@@ -202,6 +202,7 @@ class TilePipeline:
         buckets: Sequence[int] = (256, 512, 1024),
         engine: str = "auto",
         use_plane_cache: bool = True,
+        plane_cache_bytes: Optional[int] = None,
         max_tile_bytes: int = 256 << 20,
         device_deflate: bool = False,
         device_deflate_mode: str = "dynamic",
@@ -263,6 +264,9 @@ class TilePipeline:
 
             enable_persistent_cache(compilation_cache_dir)
         self.use_plane_cache = use_plane_cache
+        # byte budget of the HBM plane cache (config
+        # `backend.plane-cache-mb`); None = the cache's default
+        self.plane_cache_bytes = plane_cache_bytes
         self._plane_cache = None  # built lazily on first device batch
         # serving mesh: "auto" -> built on first device batch when >1
         # accelerator is visible (tests inject one via `pipeline.mesh =
@@ -323,9 +327,11 @@ class TilePipeline:
         submitted group's future resolves before the threads die.
         Idempotent; the server's cleanup hook calls it."""
         with self._state_lock:
-            disp = self._dispatcher
+            disp, planes = self._dispatcher, self._plane_cache
         if disp is not None:
             disp.close()
+        if planes is not None:
+            planes.close()
         self._encode_pool.shutdown(wait=False)
 
     def encode_signature(self) -> str:
@@ -348,16 +354,30 @@ class TilePipeline:
             ns = svc.invalidate(image_id)
         if ns is None:
             return
-        if self._plane_cache is not None:
-            self._plane_cache.invalidate_ns(ns)
+        with self._state_lock:
+            planes = self._plane_cache
+        if planes is not None:
+            planes.invalidate_ns(ns)
         block_cache = getattr(svc, "block_cache", None)
         if block_cache is not None and hasattr(block_cache, "purge_ns"):
             block_cache.purge_ns(ns)
 
+    def _get_plane_cache(self):
+        """The HBM plane cache, built on the first device batch."""
+        with self._state_lock:
+            if self._plane_cache is None:
+                from .device_cache import DevicePlaneCache
+
+                self._plane_cache = DevicePlaneCache(
+                    max_bytes=self.plane_cache_bytes
+                )
+            return self._plane_cache
+
     def plane_cache_snapshot(self) -> Optional[dict]:
         """/healthz view of the HBM plane tier; None when the device
         path hasn't staged anything (host serving never builds it)."""
-        cache = self._plane_cache
+        with self._state_lock:
+            cache = self._plane_cache
         return None if cache is None else cache.snapshot()
 
     @property
@@ -2039,11 +2059,7 @@ class TilePipeline:
         warm projection pan never round-trips through the host."""
         z, c, t, x, y, w, h = coord
         try:
-            from .device_cache import DevicePlaneCache
-
-            if self._plane_cache is None:
-                self._plane_cache = DevicePlaneCache()
-            cache = self._plane_cache
+            cache = self._get_plane_cache()
             size_x, size_y = buf.level_size(level)
             if x + w > size_x or y + h > size_y:
                 return None  # crop would clamp at the plane edge
@@ -2308,20 +2324,16 @@ class TilePipeline:
                 )
 
     def _stage_plane_lanes(self, ctxs, resolved):
-        """Group device-eligible PNG lanes by resident plane; stages
-        planes into HBM on first touch. Lanes whose crop would clamp at
-        the plane edge (region + bucket exceeding the plane) stay on
-        the host path — PNG filters require the region at crop origin."""
-        from .device_cache import DevicePlaneCache
-
-        if self._plane_cache is None:
-            self._plane_cache = DevicePlaneCache()
-        groups: Dict[Tuple, List[int]] = {}
-        handles: Dict[Tuple, object] = {}
+        """Group device-eligible PNG lanes by resident plane; the
+        planes a batch admits to HBM are staged side by side. Lanes
+        whose crop would clamp at the plane edge (region + bucket
+        exceeding the plane) stay on the host path — PNG filters
+        require the region at crop origin."""
+        cache = self._get_plane_cache()
+        eligible: List[Tuple[int, Tuple]] = []  # (lane, plane key)
         # one admission touch per PLANE per batch (a plane serves every
         # bucket group; keying attempts on the group would double-touch)
-        planes: Dict[Tuple, object] = {}
-        attempted: set = set()
+        wanted: Dict[Tuple, Tuple] = {}
         for i, (ctx, rt) in enumerate(zip(ctxs, resolved)):
             if rt is None or ctx.format != "png" or ctx.render is not None:
                 # render lanes (format is also "png") have their own
@@ -2347,23 +2359,29 @@ class TilePipeline:
             if rt.x + bw > size_x or rt.y + bh > size_y:
                 continue  # edge lane: host path keeps filter semantics
             plane_key = (rt.meta.image_id, rt.level, ctx.z, ctx.c, ctx.t)
-            key = plane_key + (bh, bw, meta_dtype.str)
-            if plane_key not in planes:
-                if plane_key in attempted:
-                    continue  # cold this batch; later lanes stay host
-                attempted.add(plane_key)
-                try:
-                    plane = self._plane_cache.get_plane(
-                        rt.buffer, rt.level, ctx.z, ctx.c, ctx.t
-                    )
-                except Exception:
-                    log.exception("plane staging failed; host path")
-                    TILE_DEVICE_FALLBACK.inc(site="plane_staging")
-                    plane = None
-                if plane is None:
-                    continue
-                planes[plane_key] = plane
-            handles[key] = planes[plane_key]
+            wanted.setdefault(
+                plane_key, (rt.buffer, rt.level, ctx.z, ctx.c, ctx.t)
+            )
+            eligible.append((i, plane_key + (bh, bw, meta_dtype.str)))
+        groups: Dict[Tuple, List[int]] = {}
+        handles: Dict[Tuple, object] = {}
+        if not wanted:
+            return groups, handles
+
+        def staging_failed(exc):
+            # that plane's lanes stay host-staged; the others go on
+            log.error("plane staging failed; host path", exc_info=exc)
+            TILE_DEVICE_FALLBACK.inc(site="plane_staging")
+
+        planes = dict(zip(
+            wanted,
+            cache.get_planes(list(wanted.values()), on_error=staging_failed),
+        ))
+        for i, key in eligible:
+            plane = planes[key[:-3]]
+            if plane is None:
+                continue  # cold this batch: the lane stays host-staged
+            handles[key] = plane
             groups.setdefault(key, []).append(i)
         return groups, handles
 
@@ -2375,7 +2393,7 @@ class TilePipeline:
         itemsize = dtype.itemsize
         coords = [(resolved[i].y, resolved[i].x) for i in lanes]
         with TRACER.start_span("batch_device"):
-            device_batch = self._plane_cache.crop_batch(
+            device_batch = self._get_plane_cache().crop_batch(
                 plane, coords, bh, bw
             )
             if self.use_pallas and pallas_supports((bh, bw), dtype):
@@ -2507,28 +2525,19 @@ class TilePipeline:
         tiles never exist on the host at all."""
         self._log_device_deflate()
         disp = self._get_dispatcher()
+        cache = self._get_plane_cache()
         itemsize = dtype.itemsize
-        coords = [(resolved[i].y, resolved[i].x) for i in lanes]
+        groups: Dict[Tuple[int, int], List[int]] = {}
+        for i in lanes:
+            groups.setdefault((resolved[i].w, resolved[i].h), []).append(i)
+        pending = []
         with TRACER.start_span("batch_device"):
-            device_batch = self._plane_cache.crop_batch(
-                plane, coords, bh, bw
-            )
-            groups: Dict[Tuple[int, int], List[int]] = {}
-            for j, i in enumerate(lanes):
-                groups.setdefault(
-                    (resolved[i].w, resolved[i].h), []
-                ).append(j)
-            pending = []
-            for (w, h), js in groups.items():
-                sub = (
-                    device_batch
-                    if len(js) == device_batch.shape[0]
-                    else device_batch[jnp.asarray(js)]
-                )
-                idxs = [lanes[j] for j in js]
+            for (w, h), idxs in groups.items():
+                coords = [(resolved[i].y, resolved[i].x) for i in idxs]
                 try:
                     fut = disp.submit(
-                        sub, h, 1 + w * itemsize, itemsize,
+                        cache.crop_batch(plane, coords, bh, bw),
+                        h, 1 + w * itemsize, itemsize,
                         self.png_filter, self.device_deflate_mode, idxs,
                         [(w, h)] * len(idxs), itemsize * 8, 0,
                         staged=True,

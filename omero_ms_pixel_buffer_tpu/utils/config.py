@@ -115,6 +115,11 @@ class BackendConfig:
     # Per-request allocation guard (MiB); 0 disables. The reference
     # allocates w*h*bpp unchecked (TileRequestHandler.java:98-103).
     max_tile_mb: int = 256
+    # Byte budget (MiB) of the HBM plane cache (models/device_cache):
+    # whole decoded planes held on the chip, evicted LRU beyond it;
+    # 0 disables. A v5e has 16 GB: a deployment that holds a Z stack
+    # resident sets this to the stack's size.
+    plane_cache_mb: int = 4096
 
 
 @dataclasses.dataclass
@@ -715,6 +720,19 @@ class Config:
                 f"{value!r} (expected dynamic|rle|stored)"
             )
         return value
+
+    @staticmethod
+    def _parse_plane_cache_mb(value) -> int:
+        try:
+            mb = int(value)
+        except (TypeError, ValueError):
+            raise ConfigError(
+                "Invalid value for 'backend.plane-cache-mb': "
+                f"{value!r} (expected MiB, an integer >= 0)"
+            ) from None
+        if isinstance(value, bool) or mb < 0:
+            raise ConfigError("'backend.plane-cache-mb' must be >= 0")
+        return mb
 
     @staticmethod
     def _parse_queue_depth(value) -> int:
@@ -1621,6 +1639,14 @@ class Config:
         tracing = raw.get("http-tracing") or {}
         jmx = raw.get("jmx-metrics") or {}
         be_raw = raw.get("backend") or {}
+        unknown = set(be_raw) - {
+            "engine", "batching", "png", "max-tile-mb", "plane-cache-mb",
+        }
+        if unknown:
+            # a misspelt plane budget must not run at the default
+            raise ConfigError(
+                f"Unknown keys in 'backend' block: {sorted(unknown)}"
+            )
         batching_raw = be_raw.get("batching") or {}
         png_raw = be_raw.get("png") or {}
         engine = be_raw.get("engine", "jax")
@@ -1659,6 +1685,9 @@ class Config:
                 ),
             ),
             max_tile_mb=int(be_raw.get("max-tile-mb", 256)),
+            plane_cache_mb=cls._parse_plane_cache_mb(
+                be_raw.get("plane-cache-mb", 4096)
+            ),
         )
         log_raw = raw.get("logging") or {}
         return cls(
