@@ -10,8 +10,10 @@ built on device** with static shapes, in two modes:
   match policy + fixed-Huffman coding. Maximal runs of identical bytes
   become distance-1 matches (literal head + length-3..258 matches,
   short tails literal), found with associative scans (cummax/cummin)
-  instead of a serial scan; every token maps through precomputed
-  fixed-Huffman tables to a (bits, nbits) pair; token bit offsets are
+  instead of a serial scan; every position gets ONE token index
+  (0-255 a literal, 256 + L a match of length L, 515 none) and looks
+  up one packed ``bits | nbits << 20`` table of the fixed-Huffman
+  code with it, one gather a position; token bit offsets are
   an exclusive cumsum; and the bitstream is packed by the **carry-free
   prefix-sum packer** (``_pack_bits_scan``): because tokens occupy
   disjoint bit ranges, the sum of their word-aligned contributions has
@@ -37,16 +39,19 @@ built on device** with static shapes, in two modes:
   dynamic-Huffman encode. Pass 1 runs ON DEVICE fused with the PNG
   filter (``fused_filter_histogram_batch``): the same Z_RLE run
   decomposition, but instead of emitting code bits it histograms the
-  286-symbol literal/length alphabet per lane (one scatter-add) and
-  sums the match extra-bits — only ``(B, 286)`` counts cross the link.
+  token indices per lane (one scatter-add into 516 bins, no table
+  looked up a position) and folds the 259 per-length bins into the 29
+  length symbols and the match extra-bits by constant maps — only
+  ``(B, 286)`` counts cross the link.
   The HOST then builds per-lane length-limited (15) canonical Huffman
   codes from the counts (heap build + frequency damping, the same
   algorithm as native/fast_deflate.cc), the RFC 1951 §3.2.7 dynamic
   block header (code-length tree, CL 16/17/18 run coding) as a
   zero-padded token array, and per-lane code TABLES. Pass 2 re-runs
-  the decomposition on device and emits through the per-lane tables —
-  header tokens ++ body tokens ++ explicit EOB — into the same
-  carry-free packer. Per lane the host picks min(dynamic, fixed)
+  the decomposition on device, packs each lane's four tables into one
+  token table there and emits through it, again one gather a
+  position — header tokens ++ body tokens ++ explicit EOB — into the
+  same carry-free packer. Per lane the host picks min(dynamic, fixed)
   analytically from the counts BEFORE emitting (a fixed-winning lane
   just gets the fixed tables + 3-bit header), and the framing keeps
   the stored fallback, so every lane is min(dynamic, rle, stored) in
@@ -265,9 +270,10 @@ def _run_decompose(payload: jax.Array):
       next_start = reverse-cummin of later starts   (where the run ends)
 
     Returns per-position ``(is_lit, is_match, mlen)`` — the SAME
-    decomposition feeds the fixed-Huffman emit, the dynamic histogram
-    pass, and the dynamic emit, which is what makes pass 2 of the
-    two-pass encode consistent with pass 1's counts by construction.
+    decomposition (as ``_token_index``) feeds the fixed-Huffman emit,
+    the dynamic histogram pass, and the dynamic emit, which is what
+    makes pass 2 of the two-pass encode consistent with pass 1's
+    counts by construction.
     """
     n = payload.shape[0]
     arange = jnp.arange(n, dtype=jnp.int32)
@@ -290,18 +296,23 @@ def _run_decompose(payload: jax.Array):
     return is_lit, is_match, mlen
 
 
+# One index a position names its token: 0-255 a literal of that value,
+# 256 + L a distance-1 match of length L (3..258), _NO_TOKEN a position
+# inside a match, which emits nothing. Every lookup below is by it.
+_NO_TOKEN = 256 + _MAX_MATCH + 1
+_TOKEN_KINDS = _NO_TOKEN + 1
+
+
 @kernel("ompb_tokens")
-def _rle_tokens(payload: jax.Array):
-    """Per-position fixed-Huffman (bits, nbits) token arrays from the
+def _token_index(payload: jax.Array) -> jax.Array:
+    """(L,) uint8 -> (L,) int32 token index of each position, from the
     Z_RLE decomposition."""
     is_lit, is_match, mlen = _run_decompose(payload)
-    lit_bits = jnp.asarray(_LIT_BITS)[payload]
-    lit_n = jnp.asarray(_LIT_NBITS)[payload]
-    m_bits = jnp.asarray(_MATCH_BITS)[mlen]
-    m_n = jnp.asarray(_MATCH_NBITS)[mlen]
-    bits = jnp.where(is_lit, lit_bits, jnp.where(is_match, m_bits, 0))
-    nbits = jnp.where(is_lit, lit_n, jnp.where(is_match, m_n, 0))
-    return bits, nbits
+    return jnp.where(
+        is_lit,
+        payload.astype(jnp.int32),
+        jnp.where(is_match, 256 + mlen, _NO_TOKEN),
+    )
 
 
 # Maximum significant bits in any token's code value: a FIXED match
@@ -310,9 +321,44 @@ def _rle_tokens(payload: jax.Array):
 # additionally include the distance code (5 bits fixed / 1 bit
 # dynamic), whose bits are zero (symbol 0 reverses to 0). The packers
 # only require value < 2^32 and a <= 2-word span, which 20-bit values
-# satisfy at any alignment.
+# satisfy at any alignment; and a value and its count share one uint32
+# of the token table (20 + 12 bits).
 _TOKEN_VALUE_BITS = 20
 _TOKEN_MAX_NBITS = 21
+
+
+@kernel("ompb_tokens")
+def _token_table(lit_b, lit_n, ml_b, ml_n) -> jax.Array:
+    """The (516,) uint32 code table the token index looks up, ``bits |
+    nbits << 20``: the 256 literals, the 259 match-length rows, and a
+    zero for ``_NO_TOKEN`` (no bits, no length)."""
+    def packed(b, n):
+        return b.astype(jnp.uint32) | (
+            n.astype(jnp.uint32) << _TOKEN_VALUE_BITS
+        )
+
+    return jnp.concatenate(
+        [packed(lit_b, lit_n), packed(ml_b, ml_n), jnp.zeros(1, jnp.uint32)]
+    )
+
+
+@kernel("ompb_tokens")
+def _coded_tokens(tok: jax.Array, table: jax.Array):
+    """Per-position (bits, nbits) of the token indices ``tok`` under
+    ``table``: ONE gather a position, whatever the token's kind.
+    (The index is in 0..515 by construction; promising so buys nothing
+    on the v5e: 4.49 against 4.52 ms a lane, PERF.md PR 29.)"""
+    g = table[tok]
+    bits = g & jnp.uint32((1 << _TOKEN_VALUE_BITS) - 1)
+    return bits, (g >> _TOKEN_VALUE_BITS).astype(jnp.int32)
+
+
+@kernel("ompb_tokens")
+def _rle_tokens(payload: jax.Array):
+    """Per-position fixed-Huffman (bits, nbits) token arrays from the
+    Z_RLE decomposition."""
+    table = _token_table(_LIT_BITS, _LIT_NBITS, _MATCH_BITS, _MATCH_NBITS)
+    return _coded_tokens(_token_index(payload), table)
 
 
 def _tokens_below_edges(offs: jax.Array, nwords: int) -> jax.Array:
@@ -641,26 +687,29 @@ _CL_ORDER = (16, 17, 18, 0, 8, 7, 9, 6, 10, 5, 11, 4, 12, 3, 13, 2, 14, 1, 15)
 
 def _dyn_stats_lane(payload: jax.Array):
     """Pass 1 for one lane: (L,) uint8 -> ((286,) int32 literal/length
-    symbol counts, () int32 total match extra bits). Runs the same
-    run decomposition the emit pass reruns, so the counts describe
+    symbol counts, () int32 total match extra bits). Counts the same
+    token indices the emit pass looks up, so the counts describe
     exactly the tokens pass 2 will produce."""
-    return _symbol_counts(payload, *_run_decompose(payload))
+    return _symbol_counts(_token_index(payload))
+
+
+# match length -> its place among [EOB, the 29 length symbols]: the
+# constant map that folds the per-length bins into symbol counts
+_MLEN_FOLD = np.zeros((_MAX_MATCH + 1, _NUM_LITLEN - 256), np.int32)
+_MLEN_FOLD[np.arange(3, _MAX_MATCH + 1), _MLEN_SYM[3:] - 256] = 1
 
 
 @kernel("ompb_hist")
-def _symbol_counts(payload, is_lit, is_match, mlen):
-    """The histogram half of pass 1: one scatter-add of the decomposed
-    tokens' symbols, and the sum of the match extra bits."""
-    sym = jnp.where(
-        is_lit,
-        payload.astype(jnp.int32),
-        jnp.where(is_match, jnp.asarray(_MLEN_SYM)[mlen], _NUM_LITLEN),
-    )
-    counts = jnp.zeros(_NUM_LITLEN + 1, jnp.int32).at[sym].add(1)
-    extra = jnp.where(
-        is_match, jnp.asarray(_MLEN_EXTRA)[mlen], 0
-    ).sum(dtype=jnp.int32)
-    return counts[:_NUM_LITLEN], extra
+def _symbol_counts(tok: jax.Array):
+    """The histogram half of pass 1: one scatter-add of the token
+    indices into their 516 raw bins, no table looked up a position.
+    Literal bins are symbol counts as they stand; the 259 per-length
+    bins fold into the 29 length symbols, and weigh into the match
+    extra bits, by constant maps (EOB is not a payload token: 0)."""
+    raw = jnp.zeros(_TOKEN_KINDS, jnp.int32).at[tok].add(1)
+    by_len = raw[256:_NO_TOKEN]
+    counts = jnp.concatenate([raw[:256], by_len @ jnp.asarray(_MLEN_FOLD)])
+    return counts, by_len @ jnp.asarray(_MLEN_EXTRA)
 
 
 @jax.jit
@@ -888,15 +937,11 @@ def build_dynamic_tables(
 
 @kernel("ompb_tokens")
 def _dyn_lane_tokens(payload, lit_b, lit_n, ml_b, ml_n):
-    """Pass-2 body tokens for one lane through ITS code tables."""
-    is_lit, is_match, mlen = _run_decompose(payload)
-    bits = jnp.where(
-        is_lit, lit_b[payload], jnp.where(is_match, ml_b[mlen], 0)
+    """Pass-2 body tokens for one lane through ITS code tables, packed
+    on the device into one token table: one gather a position."""
+    return _coded_tokens(
+        _token_index(payload), _token_table(lit_b, lit_n, ml_b, ml_n)
     )
-    nbits = jnp.where(
-        is_lit, lit_n[payload], jnp.where(is_match, ml_n[mlen], 0)
-    )
-    return bits, nbits
 
 
 @kernel("ompb_tokens")

@@ -213,20 +213,278 @@ class TestBoundaryCount:
         np.testing.assert_array_equal(got, want)
 
 
-def _cell_payloads(lanes: int) -> np.ndarray:
-    """Up-filtered 512x512 uint16 scanlines, the cell's payloads
-    (L = 524800): noise over a ramp, and flat stretches of runs."""
-    from omero_ms_pixel_buffer_tpu.ops.png import filter_rows_np
-
+def _cell_tiles(lanes: int) -> np.ndarray:
+    """512x512 uint16 tiles as the cell crops them: gaussian noise
+    (sigma 120) over a smooth base, and flat stretches of runs."""
     r = np.random.default_rng(2700 + lanes)
     yy, xx = np.mgrid[0:512, 0:512]
     out = []
     for lane in range(lanes):
         tile = 2000 + 3 * xx + 2 * yy + r.normal(0, 120, (512, 512))
         tile[100 * lane : 100 * lane + 200] = 4095  # run-heavy rows
-        be = tile.clip(0, 65535).astype(">u2").view(np.uint8)
-        out.append(filter_rows_np(be.reshape(512, 1024), 2, "up").ravel())
+        out.append(tile.clip(0, 65535).astype(np.uint16))
     return np.stack(out)
+
+
+def _cell_payloads(lanes: int) -> np.ndarray:
+    """The Up-filtered scanlines of ``_cell_tiles``, the cell's
+    payloads (L = 524800)."""
+    from omero_ms_pixel_buffer_tpu.ops.png import filter_rows_np
+
+    return np.stack([
+        filter_rows_np(
+            tile.astype(">u2").view(np.uint8).reshape(512, 1024), 2, "up"
+        ).ravel()
+        for tile in _cell_tiles(lanes)
+    ])
+
+
+_RUN_L = 700
+
+
+def _no_run(n: int = _RUN_L) -> np.ndarray:
+    """No two neighbours equal, no zero byte; four values, so that a
+    dynamic code beats the fixed one and both beat stored blocks."""
+    return (np.arange(n) % 4 + 1).astype(np.uint8)
+
+
+def _one_run(run: int) -> np.ndarray:
+    """``_no_run`` with one run of exactly ``run`` zero bytes in it."""
+    payload = _no_run()
+    payload[40 : 40 + run] = 0
+    return payload
+
+
+# case -> (B, L) payloads. The runs sit where the decomposition turns:
+# 2 (head + one literal), 3 (head + two literals), 4 (the shortest
+# match), 258/259/260 (a match one short of full, full, full + a
+# literal tail), 517 (two full matches), 600 (two full and a third)
+_PINNED_PAYLOADS = {
+    **{f"run{r}": (lambda r=r: _one_run(r)[None])
+       for r in (2, 3, 4, 258, 259, 260, 517, 600)},
+    "constant": lambda: np.full((1, _RUN_L), 7, np.uint8),
+    "no_run": lambda: _no_run()[None],
+    "L1": lambda: np.array([[9]], np.uint8),
+    "cell_1lane": lambda: _cell_payloads(1),
+    "cell_2lanes": lambda: _cell_payloads(2),
+}
+
+# SHA-256 of every lane's stream (true length only), lanes joined:
+# recorded on the code before PR 29 changed the token lookup, so a
+# lookup that gives other tokens gives other bytes here
+_PINNED_SHA = {
+    "L1": {
+        "rle":
+            "b86a04326126d03e40d27cc79f526832b217a623d467405fe18a3dbf8bb8ae1f",
+        "dynamic":
+            "b86a04326126d03e40d27cc79f526832b217a623d467405fe18a3dbf8bb8ae1f",
+    },
+    "cell_1lane": {
+        "rle":
+            "9383b8bc2054e5335c0092d2a22bfb95400d6259ccda8630a7aca74eb44b9d8d",
+        "dynamic":
+            "7a7162eca620354cded020778cf564061ebf4dbfbfe05ed45b26872c4c095a03",
+    },
+    "cell_2lanes": {
+        "rle":
+            "b84a6f6b4e90d074be2d1842556140c2f9f8d4ee2b58f03528bb19664d2bcc77",
+        "dynamic":
+            "e9c06b2fc2a5226ad1744e444f4ad3af3fa1ec13eaedd122f165b6df64a3ca2a",
+    },
+    "constant": {
+        "rle":
+            "0e877d73b2183f548d3f3334a5662b4fabfc94fa241324015d97067ca11bca7c",
+        "dynamic":
+            "0e877d73b2183f548d3f3334a5662b4fabfc94fa241324015d97067ca11bca7c",
+    },
+    "no_run": {
+        "rle":
+            "fea80d8b626d9204250e2c724e1eda9a3bb6b27f93f695b2c7c0fd842eec9f78",
+        "dynamic":
+            "d09313d99a60b2be4dd442f61ba08154ac5b28a20d1fe07ef6c107ab6ca5b09c",
+    },
+    "run2": {
+        "rle":
+            "ca8b1ba08fc1515cff5bede6f96ec7d16d005036f1672e2f207f4b3aa0ce3272",
+        "dynamic":
+            "c6564052d9f7830fe45a1f335b1ea4d076aa8f9d73717a99eb34f1150abcc7f7",
+    },
+    "run258": {
+        "rle":
+            "a3bb49a4d95efd8fcfb182fa18385ff74f335dbb22babdc69154010115023494",
+        "dynamic":
+            "c3d1fd83e32a74054ee1108089361767814c4c53f5ad81d3ae77e060fdda2bc2",
+    },
+    "run259": {
+        "rle":
+            "3be24bb7ea7517730be65608b7f53e669ca8cfc09371625d98c98e074d5b8953",
+        "dynamic":
+            "749c7e25983d9c936fb7b6d2b3ed58e626bf8d61ca557608bc28c39b61209481",
+    },
+    "run260": {
+        "rle":
+            "73e8d1dc0dbf80dbf8612dc6066651fccf07ed7c37bf45e7d4f057391c42b316",
+        "dynamic":
+            "f460b708474983dcc4ae8ef473b4427eb2f9481c8a30afbbe371db9410fa6cf6",
+    },
+    "run3": {
+        "rle":
+            "fab2900fc783bf04085b7c084f34e3ba3713a8d14a70b8f53522e1d219615f22",
+        "dynamic":
+            "12746d24a9ad9324e6baf631ca8473b9551d45876c5707bc0c90b1e340a2ad5f",
+    },
+    "run4": {
+        "rle":
+            "5fdccffda809e714986ff82a8e73efc041c75aba1b161453d6b63b1f1701cf13",
+        "dynamic":
+            "2068fbeb680557edc9abe1932e6c0593d5474c06e06617cb478cd789cd61fde6",
+    },
+    "run517": {
+        "rle":
+            "5df9f34e773726ef2c20b1ff53e41449b6cb6bdd23be889a920be8c3b864fc7f",
+        "dynamic":
+            "9e495c840d48d059cfe7473a96cde25b1066d727a06960b5dcbfb1f46718b004",
+    },
+    "run600": {
+        "rle":
+            "db21dda0c3a314f1956d6dfb9dd02ff191e5dfe06b2ee2cdb6790e013a3690e3",
+        "dynamic":
+            "5b3e7012bdc50bb9575b5cd8cfee190485c9796ccb8bdb0f1631dc22ab64c916",
+    },
+}
+
+
+def _streams_sha(streams, lengths) -> str:
+    import hashlib
+
+    streams, lengths = np.asarray(streams), np.asarray(lengths)
+    return hashlib.sha256(b"".join(
+        bytes(s[:n]) for s, n in zip(streams, lengths)
+    )).hexdigest()
+
+
+class TestPinnedStreams:
+    """The streams are bytes a client may have cached under an ETag:
+    a faster lookup must give the same ones, in both modes."""
+
+    @pytest.mark.parametrize("mode", ["rle", "dynamic"])
+    @pytest.mark.parametrize("case", sorted(_PINNED_PAYLOADS))
+    def test_stream_bytes_are_pinned(self, case, mode):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            zlib_dynamic_batch,
+        )
+
+        payloads = _PINNED_PAYLOADS[case]()
+        encode = zlib_rle_batch if mode == "rle" else zlib_dynamic_batch
+        streams, lengths = (np.asarray(a) for a in encode(payloads))
+        for lane, payload in enumerate(payloads):
+            got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
+            assert got == payload.tobytes(), lane
+        assert _streams_sha(streams, lengths) == _PINNED_SHA[case][mode]
+
+
+def _symbols_of_the_numpy_twin(payload: np.ndarray):
+    """((286,) symbol counts, match extra bits) of the tokens
+    ``_rle_tokens_np`` decomposes ``payload`` into: each fixed-Huffman
+    (bits, nbits) pair names one literal or one match length."""
+    from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+    lengths = np.arange(3, 259)
+
+    def key(bits, nbits):
+        return np.asarray(bits, np.int64) | (np.asarray(nbits, np.int64) << 32)
+
+    keys = np.concatenate([
+        key(dd._LIT_BITS, dd._LIT_NBITS),
+        key(dd._MATCH_BITS[lengths], dd._MATCH_NBITS[lengths]),
+    ])
+    symbol = np.concatenate([np.arange(256), dd._MLEN_SYM[lengths]])
+    extra = np.concatenate([np.zeros(256, int), dd._MLEN_EXTRA[lengths]])
+    order = np.argsort(keys)
+    assert (np.diff(keys[order]) > 0).all()  # no two tokens share a code
+    bits, nbits = dd._rle_tokens_np(payload)
+    emitted = key(bits[nbits > 0], nbits[nbits > 0])
+    at = np.searchsorted(keys[order], emitted)
+    assert (keys[order][at] == emitted).all()
+    return (
+        np.bincount(symbol[order][at], minlength=286),
+        int(extra[order][at].sum()),
+    )
+
+
+# counts a host plan may be handed -> (286,) frequencies
+_PLAN_COUNTS = {
+    "uniform": lambda r: np.full(286, 1000),
+    "random": lambda r: r.integers(0, 5000, 286),
+    "sparse random": lambda r: r.integers(0, 3, 286) * r.integers(0, 900, 286),
+    "one literal": lambda r: np.bincount([65], minlength=286) * 524800,
+    "one literal, one long match": lambda r: (
+        np.bincount([0, 285], minlength=286) * np.r_[1, [2034] * 285]),
+    "nothing": lambda r: np.zeros(286, int),
+    # frequencies that double: the tree wants depth 285, the damping
+    # brings it to 15, and the deepest codes land on the length
+    # symbols with five extra bits (20-bit values, 21-bit tokens)
+    "doubling, rare long matches": lambda r: (
+        2 ** np.minimum(np.arange(286)[::-1] // 9, 30)),
+    "doubling, rare literals": lambda r: (
+        2 ** np.minimum(np.arange(286) // 9, 30)),
+}
+
+
+class TestTokenIndex:
+    """One index a position (0-255 literal, 256 + L match, 515 none)
+    feeds the histogram and the one table both emits look up."""
+
+    @pytest.mark.parametrize("case", sorted(_PINNED_PAYLOADS))
+    def test_counts_are_the_numpy_twins_symbols(self, case):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import _dyn_stats
+
+        payloads = _PINNED_PAYLOADS[case]()
+        counts, extras = (np.asarray(a) for a in _dyn_stats(payloads))
+        assert counts.shape == (len(payloads), 286)
+        for lane, payload in enumerate(payloads):
+            want, want_extra = _symbols_of_the_numpy_twin(payload)
+            np.testing.assert_array_equal(counts[lane], want)
+            assert extras[lane] == want_extra
+            assert counts[lane][256] == 0  # EOB is the plan's to add
+
+    @pytest.mark.parametrize("case", sorted(_PLAN_COUNTS))
+    def test_every_planned_code_fits_the_packed_table(self, case):
+        from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+        freq = np.asarray(_PLAN_COUNTS[case](np.random.default_rng(29)))
+        counts = np.stack([freq, freq[::-1], np.roll(freq, 143)])
+        counts[:, 256] = 0
+        extras = (counts[:, 257:] * np.asarray(dd._LEN_EXTRA)).sum(axis=1)
+        _, _, lit_b, lit_n, ml_b, ml_n, _, _ = dd.build_dynamic_tables(
+            counts, extras
+        )
+        for lane in range(len(counts)):
+            bits = np.concatenate([lit_b[lane], ml_b[lane], [0]])
+            nbits = np.concatenate([lit_n[lane], ml_n[lane], [0]])
+            assert bits.max() < 1 << dd._TOKEN_VALUE_BITS
+            assert 0 <= nbits.min() and nbits.max() <= dd._TOKEN_MAX_NBITS
+            table = dd._token_table(
+                lit_b[lane], lit_n[lane], ml_b[lane], ml_n[lane]
+            )
+            assert table.shape == (dd._TOKEN_KINDS,)
+            got_b, got_n = dd._coded_tokens(
+                np.arange(dd._TOKEN_KINDS, dtype=np.int32), table
+            )
+            np.testing.assert_array_equal(np.asarray(got_b), bits)
+            np.testing.assert_array_equal(np.asarray(got_n), nbits)
+
+    def test_the_fixed_table_is_the_fixed_code(self):
+        from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+        payload = np.concatenate([np.arange(256), np.zeros(700)]).astype(
+            np.uint8
+        )
+        bits, nbits = (np.asarray(a) for a in dd._rle_tokens(payload))
+        want_b, want_n = dd._rle_tokens_np(payload)
+        np.testing.assert_array_equal(bits, want_b)
+        np.testing.assert_array_equal(nbits, want_n)
+        assert bits.dtype == np.uint32 and nbits.dtype == np.int32
 
 
 class TestCellShape:
@@ -261,6 +519,20 @@ class TestCellShape:
             assert lengths[lane] <= stored_stream_len(524800)
             got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
             assert got == payloads[lane].tobytes(), lane
+
+    @pytest.mark.parametrize("lanes", [1, 2])
+    def test_the_fused_chain_gives_the_pinned_png_body(self, lanes):
+        """Filter and both passes from the uint16 tiles, as the served
+        path runs them: the IDAT bodies are the pinned streams."""
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            fused_filter_deflate_dynamic,
+        )
+
+        streams, lengths = fused_filter_deflate_dynamic(
+            _cell_tiles(lanes), 512, 1 + 512 * 2, 2
+        )
+        case = {1: "cell_1lane", 2: "cell_2lanes"}[lanes]
+        assert _streams_sha(streams, lengths) == _PINNED_SHA[case]["dynamic"]
 
 
 class TestPallasBitpack:

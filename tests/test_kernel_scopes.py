@@ -227,6 +227,37 @@ def test_the_packers_second_level_is_named_and_has_no_loop(lowered):
     assert " while(" not in hlo_text(lowered("_zlib_dynamic"))
 
 
+# program -> gathers it may hold whose result is as long as the payload
+_PAYLOAD_LONG_GATHERS = {
+    "_fused_filter_histogram": 0,
+    "_fused_filter_histogram_donated": 0,
+    "_zlib_dynamic": 1,
+    "_zlib_rle": 1,
+    "_fused_filter_deflate[rle]": 1,
+}
+
+
+@pytest.mark.parametrize("program", sorted(_PAYLOAD_LONG_GATHERS))
+def test_one_table_lookup_a_position_and_none_in_the_histogram(
+    lowered, program
+):
+    """A gather costs the chip 5-9 ns an element whatever the table's
+    size, and six of them over the payload were two thirds of its time
+    (PERF.md, PR 29). One token index a position: the histogram pass
+    scatters it and looks nothing up, an emit pass looks up one packed
+    (bits, nbits) table; neither may grow a gather, or a loop, back."""
+    text = hlo_text(lowered(program))
+    long_gathers = [
+        found.group(1)
+        for found in re.finditer(
+            r"= [a-z]+\d+\[([\d,]*)\]\S* gather\(", text
+        )
+        if str(ROWS * ROW_BYTES) in found.group(1).split(",")
+    ]
+    assert len(long_gathers) == _PAYLOAD_LONG_GATHERS[program], long_gathers
+    assert " while(" not in text
+
+
 def test_a_named_scope_alone_does_not_reach_the_cache_key():
     """Why `kernel` is an inner jit and not `jax.named_scope` alone:
     after strip-debuginfo the scoped and the unscoped program are the
