@@ -15,14 +15,11 @@ Prints ONE JSON line:
   structure on identical inputs.
 - extra keys: http_tiles_per_sec + p50_ms/p99_ms measured through the
   FULL stack (aiohttp client over a real socket -> session middleware
-  -> event bus -> batcher -> pipeline), and a `device` object with the
-  accelerator-engine sub-run.
+  -> event bus -> batcher -> pipeline).
 
-This is NOT the chip entry point: `python chip_smoke.py` is the proof
-that the served path runs on the chip, and ROADMAP S0 replaces this
-script's main() with the cells benchmark. A chip belongs to one
-process at a time, so the accelerator sub-run (`--device-sub`) runs
-alone, never from a parent that has initialised JAX.
+This is NOT the chip entry point and measures nothing on the chip:
+`python chip_smoke.py` is the proof that the served path runs there,
+and `benchmarks/` (BENCHMARK.json) measures it.
 
 All progress chatter goes to stderr; stdout carries only the JSON line.
 """
@@ -49,38 +46,6 @@ def jax_backend_info() -> dict:
     from omero_ms_pixel_buffer_tpu.runtime.device_probe import probe
 
     return dict(probe())
-
-
-def run_bounded(argv: list, timeout_s: float, env=None) -> dict:
-    """Run a child expected to print one JSON line; bound its runtime.
-    Returns the parsed JSON or {"error": ...}. Termination is graceful
-    first (SIGTERM, 10 s grace) so a chip-attached child can detach."""
-    try:
-        proc = subprocess.Popen(
-            argv, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-            env=env, text=True,
-        )
-    except OSError as e:
-        return {"error": f"spawn failed: {e}"}
-    try:
-        out, err = proc.communicate(timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        proc.terminate()
-        try:
-            proc.communicate(timeout=10)
-        except subprocess.TimeoutExpired:
-            proc.kill()
-            proc.communicate()
-        return {"error": f"timeout after {timeout_s:.0f}s"}
-    if proc.returncode != 0:
-        tail = (err or "").strip().splitlines()[-3:]
-        return {"error": f"rc={proc.returncode}: {' | '.join(tail)}"}
-    for line in reversed((out or "").strip().splitlines()):
-        try:
-            return json.loads(line)
-        except ValueError:
-            continue
-    return {"error": "no JSON in child output"}
 
 
 def build_fixture(root: str, size: int = 8192):
@@ -3116,147 +3081,6 @@ def bench_analysis(
     return out
 
 
-def bench_device(path: str, size: int, probe_info: dict) -> dict:
-    """Accelerator-engine sub-run in a bounded CHILD process
-    (`--device-sub`). A chip belongs to one process at a time, so this
-    refuses to start the child from a parent that has initialised a
-    JAX backend: run `python bench.py --device-sub` alone instead
-    (BENCH_FIXTURE / BENCH_IMAGE_SIZE in its environment)."""
-    from jax._src import xla_bridge
-
-    out = dict(probe_info)
-    if xla_bridge.backends_are_initialized():
-        out["error"] = (
-            "refused: this process has initialised a JAX backend and "
-            "holds the chip; run `python bench.py --device-sub` alone"
-        )
-        return out
-    env = dict(os.environ)
-    env["BENCH_FIXTURE"] = path
-    env["BENCH_IMAGE_SIZE"] = str(size)
-    timeout_s = float(os.environ.get("BENCH_DEVICE_TIMEOUT_S", "600"))
-    child = run_bounded(
-        [sys.executable, os.path.abspath(__file__), "--device-sub"],
-        timeout_s, env=env,
-    )
-    out.update(child)
-    return out
-
-
-def device_sub_main():
-    """Child-process entry for the device sub-run (see bench_device)."""
-    from omero_ms_pixel_buffer_tpu.io.pixels_service import (
-        ImageRegistry,
-        PixelsService,
-    )
-    from omero_ms_pixel_buffer_tpu.models.tile_pipeline import TilePipeline
-
-    path = os.environ["BENCH_FIXTURE"]
-    size = int(os.environ["BENCH_IMAGE_SIZE"])
-    n = int(os.environ.get("BENCH_DEVICE_REQUESTS", "64"))
-    registry = ImageRegistry()
-    registry.add(1, path)
-    service = PixelsService(registry)
-    out = {}
-    for label, plane_cache, dev_deflate in (
-        ("plane_cache", True, False),
-        ("bucket", False, False),
-        # on-device deflate: only compressed bytes cross the link back
-        ("bucket_devdeflate", False, True),
-        # plane staged once + compressed return: the minimal-transfer
-        # configuration
-        ("plane_devdeflate", True, True),
-    ):
-        try:
-            pipe = TilePipeline(
-                service, engine="device", buckets=(512,),
-                use_plane_cache=plane_cache, device_deflate=dev_deflate,
-            )
-            if plane_cache:
-                # the plane cache is the single-device HBM path; with
-                # >1 chip the auto-mesh would supersede it and this
-                # label would silently duplicate the bucket number
-                pipe.mesh = None
-            ctxs = make_ctxs(n, size, seed=23)
-            # warm with the RUN's batch size: device jit programs are
-            # per-(batch, shape), and a mismatched warmup would leave a
-            # tens-of-seconds compile inside the timed region
-            pipe.handle_batch(ctxs[:32])
-            tps = run_batched(pipe, ctxs, 32)
-            out[f"tiles_per_sec_{label}"] = round(tps, 2)
-            log(f"[device] {label} path: {tps:.1f} tiles/s")
-            if dev_deflate:
-                # steady-state queue health: cross-batch overlap is
-                # proven when the inter-group idle gap stays below one
-                # group's compute time (overlapped_fraction high)
-                queue = pipe.device_queue_snapshot()
-                if queue:
-                    out.setdefault("queue", {})[label] = queue
-                    log(f"[device] {label} queue: {queue}")
-        except Exception as e:
-            out[f"error_{label}"] = f"{type(e).__name__}: {e}"
-            log(f"[device] {label} path failed: {e!r}")
-    # rendered-tile lanes: the fused render->filter->deflate chain as
-    # ONE device dispatch per bucket group
-    try:
-        from omero_ms_pixel_buffer_tpu.render.model import RenderSpec
-        from omero_ms_pixel_buffer_tpu.tile_ctx import RegionDef, TileCtx
-
-        spec = RenderSpec.from_params({"c": "1|0:65535$FF0000"})
-        pipe = TilePipeline(
-            service, engine="device", buckets=(512,),
-            use_plane_cache=False, device_deflate=True,
-        )
-        pipe.mesh = None
-        rctxs = []
-        rng = np.random.default_rng(41)
-        for _ in range(n):
-            x = int(rng.integers(0, (size - 512) // 64)) * 64
-            y = int(rng.integers(0, (size - 512) // 64)) * 64
-            rctxs.append(TileCtx(
-                image_id=1, z=0, c=0, t=0,
-                region=RegionDef(x, y, 512, 512), format="png",
-                omero_session_key="bench", render=spec,
-            ))
-        pipe.handle_batch(rctxs[:32])
-        tps = run_batched(pipe, rctxs, 32)
-        out["tiles_per_sec_render"] = round(tps, 2)
-        log(f"[device] render path: {tps:.1f} tiles/s")
-    except Exception as e:
-        out["error_render"] = f"{type(e).__name__}: {e}"
-        log(f"[device] render path failed: {e!r}")
-    service.close()
-    # kernel-only compute metrics (device-resident inputs, compiles
-    # excluded). Shapes match the serving runs, so the jit cache warmed
-    # above is reused.
-    if os.environ.get("BENCH_MICRO", "1") != "0":
-        from omero_ms_pixel_buffer_tpu.runtime.microbench import (
-            run_microbench,
-        )
-
-        micro = None
-        try:
-            micro = run_microbench()
-            out["micro"] = micro
-            log(f"[device] microbench: {micro}")
-        except Exception as e:
-            out["micro"] = {"error": f"{type(e).__name__}: {e}"}
-            log(f"[device] microbench failed: {e!r}")
-        # the dynamic-Huffman ratio claim is PINNED, not prose: a
-        # regression past the acceptance bound is recorded as
-        # error_ratio (the headline record survives). An explicit
-        # check, not assert — python -O must not strip the gate.
-        ratio = (micro or {}).get("deflate_ratio_vs_host_dynamic")
-        if ratio is not None and ratio > 1.10:
-            msg = (
-                f"dynamic-Huffman deflate ratio regressed: {ratio} "
-                "(bound 1.10x host bytes on the rendered-RGB fixture)"
-            )
-            out["error_ratio"] = msg
-            log(f"[device] RATIO REGRESSION: {msg}")
-    print(json.dumps(out))
-
-
 def main():
     t_setup = time.perf_counter()
     from omero_ms_pixel_buffer_tpu.io.pixels_service import (
@@ -3523,16 +3347,6 @@ def main():
         except Exception as e:
             log(f"sub-benches failed: {e!r}")
 
-    # --- accelerator-engine sub-run (bounded child; refused while
-    # this process holds the chip — see bench_device) ------------------
-    device_stats: dict = {}
-    if os.environ.get("BENCH_DEVICE", "1") != "0":
-        try:
-            device_stats = bench_device(path, size, probe_info)
-        except Exception as e:
-            device_stats = {"error": f"{type(e).__name__}: {e}"}
-            log(f"device bench failed: {e!r}")
-
     record = {
         "metric": "tiles_per_sec_512x512_uint16_png",
         "value": round(tpu_tps, 2),
@@ -3572,17 +3386,12 @@ def main():
         record["supertile"] = supertile_stats
     if mesh_fusion_stats:
         record["mesh_fusion"] = mesh_fusion_stats
-    if device_stats:
-        record["device"] = device_stats
     # explicit host-vs-device table so the next round can read WHICH
     # engine/stage moved without diffing nested sections
     comparison = {
         "sequential_host": round(host_tps, 2),
         f"batched_{pipe.engine}": round(tpu_tps, 2),
     }
-    for k, v in device_stats.items():
-        if k.startswith("tiles_per_sec_"):
-            comparison["device_" + k[len("tiles_per_sec_"):]] = v
     for label, stats in render_stats.items():
         if isinstance(stats, dict) and "tiles_per_sec" in stats:
             comparison[f"render_{label}"] = stats["tiles_per_sec"]
@@ -3617,20 +3426,6 @@ def main():
         comparison["burst_programs_continuation_off"] = (
             burst_half["continuation_off_programs"]
         )
-    micro = device_stats.get("micro") or {}
-    for k in (
-        "deflate_gbps", "pack_gbps", "pack_speedup_vs_gather",
-        "deflate_ratio_vs_host_dynamic", "deflate_ratio_vs_host_rle_rgb",
-        "deflate_dynamic_gbps",
-    ):
-        if k in micro:
-            comparison[k] = micro[k]
-    if "emit_ops_per_token" in micro:
-        comparison["emit_ops_per_token"] = micro["emit_ops_per_token"]
-    if "stage_breakdown" in micro:
-        comparison["device_stage_breakdown"] = micro["stage_breakdown"]
-    if "queue" in device_stats:
-        comparison["device_queue"] = device_stats["queue"]
     if io_stats and "parallel" in io_stats:
         comparison["io_cold_sequential_tiles_per_sec"] = (
             io_stats["sequential"]["tiles_per_sec"]
@@ -3836,7 +3631,4 @@ def sub_benches(pipe, service, size, cache_dir):
 
 
 if __name__ == "__main__":
-    if "--device-sub" in sys.argv:
-        device_sub_main()
-        sys.exit(0)
     main()

@@ -209,12 +209,10 @@ class DeviceEncodeDispatcher:
         self,
         dd_cap: Dict[Tuple[int, int], int],
         mesh_manager=None,
-        packer: Optional[str] = None,
         queue_depth: int = 2,
     ):
         self._dd_cap = dd_cap
         self.mesh_manager = mesh_manager
-        self._packer = packer
         self.queue_depth = max(1, int(queue_depth))
         # ONE submit thread: groups stage + launch in FIFO order across
         # batches; ONE readback worker: readback order == submission
@@ -633,7 +631,6 @@ class DeviceEncodeDispatcher:
         streams, lengths = fused_filter_deflate_batch(
             batch_dev, rows, row_bytes, bpp,
             filter_mode=filter_mode, mode=deflate_mode,
-            packer=self._packer,
             donate=self._donate_ok(),
         )
         compute = self._stage("compute", gid, n)  # launch -> ready
@@ -679,7 +676,7 @@ class DeviceEncodeDispatcher:
         streams, lengths = fused_render_filter_deflate_batch(
             batch_dev, index_tables, color_luts, rows, row_bytes,
             filter_mode=filter_mode, mode=deflate_mode,
-            packer=self._packer, mask=mask_dev,
+            mask=mask_dev,
         )
         compute = self._stage("compute", gid, n)  # launch -> ready
         self._note_launch(compute.t0, len(lanes))
@@ -735,8 +732,7 @@ class DeviceEncodeDispatcher:
             out = sharded_render_filter_deflate(
                 mesh, sharded, index_tables, color_luts, rows,
                 row_bytes, filter_mode=filter_mode,
-                deflate_mode=deflate_mode, packer=self._packer,
-                mask=mask_sh,
+                deflate_mode=deflate_mode, mask=mask_sh,
             )
             return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
 
@@ -796,7 +792,6 @@ class DeviceEncodeDispatcher:
             out = sharded_filter_deflate(
                 mesh, sharded, rows, row_bytes, bpp,
                 filter_mode=filter_mode, deflate_mode=deflate_mode,
-                packer=self._packer,
             )
             # block INSIDE the managed dispatch: a mid-compute chip
             # failure must raise here, where MeshManager probes and
@@ -872,9 +867,7 @@ class DeviceEncodeDispatcher:
             counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the dynamic host hop (pass-1 counts, a few KB)
             stamps["hist"] = marks.next("emit")
             tables = build_dynamic_tables(counts_np, extras_np, real=b)
-            out = sharded_dynamic_emit(
-                mesh, flat, tables, packer=self._packer
-            )
+            out = sharded_dynamic_emit(mesh, flat, tables)
             return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
 
         try:
@@ -982,7 +975,7 @@ class DeviceEncodeDispatcher:
             out = sharded_supertile_carve_deflate(
                 mesh, sub_dev, index_tables, color_luts, coords_dev,
                 tile_h, tile_w, filter_mode=filter_mode,
-                deflate_mode=deflate_mode, packer=self._packer,
+                deflate_mode=deflate_mode,
             )
             out = jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
             return out, rows_map
@@ -1100,15 +1093,12 @@ class DeviceEncodeDispatcher:
                     tables = build_dynamic_tables(
                         counts_np, extras_np, real=0
                     )
-                    out = sharded_dynamic_emit(
-                        mesh, flat, tables, packer=self._packer
-                    )
+                    out = sharded_dynamic_emit(mesh, flat, tables)
                 else:
                     out = sharded_filter_deflate(
                         mesh, sharded, rows, row_bytes, bpp,
                         filter_mode=filter_mode,
                         deflate_mode=deflate_mode,
-                        packer=self._packer,
                     )
                 jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- background warmup thread: compiles ahead of the serving path
                 with self._warm_lock:
@@ -1140,8 +1130,7 @@ class DeviceEncodeDispatcher:
             counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion (pass-1 counts, a few KB)
         with self._stage("emit", gid, hist.lanes) as emit:
             streams, lengths = dynamic_emit_batch(
-                flat, counts_np, extras_np, packer=self._packer,
-                real=real_b,
+                flat, counts_np, extras_np, real=real_b,
             )
             jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
         self._note_compute_done(emit.t1, emit.t1 - hist.t0)

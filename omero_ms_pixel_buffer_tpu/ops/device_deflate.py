@@ -22,14 +22,10 @@ built on device** with static shapes, in two modes:
   word boundary (``_tokens_below_edges``: one scatter of each word's
   last token and one running maximum over the words), two monotone
   gathers. O(tokens + words) work with no loop, no sort and no wide
-  gather windows; the previous per-bit window packer (kept as
-  ``_pack_bits_gather`` for pinned comparison benches) cost an argsort
-  plus a 24-wide token window per 128-bit chunk and measured 0.006
-  GB/s on TPU. Two Pallas emit
-  kernels exist beside it (ops/pallas/bitpack.py, per-block
-  token->VMEM emit): interpret mode pins them bit-exact on CPU, but
-  the TPU compiler refuses both today, so the scan packer is what a
-  TPU runs. Up-filtered microscopy tiles are run-heavy, so this genuinely
+  gather windows. It is the one packer: plain XLA, the same program
+  on every backend (``_pack_bits_scan_np`` is its numpy twin, the
+  tests' reference). Up-filtered microscopy tiles are run-heavy, so
+  this genuinely
   compresses (typically 2-4x) while leaving the host only PNG chunk
   framing. **Per lane**, if the RLE stream would come out larger than
   the stored-block encoding (pathological no-run payloads expand past
@@ -203,7 +199,8 @@ def stored_stream_len(payload_len: int) -> int:
 
 def _packing_maxbits(payload_len: int) -> int:
     """Worst-case deflate bits (all-literal at 9 bits/byte + 3 header
-    + 7 EOB), rounded up so the chunked packer tiles it exactly."""
+    + 7 EOB), rounded up to a multiple of 1024: the capacity of every
+    stream buffer."""
     raw = 3 + 9 * payload_len + 7
     return ((raw + 1023) // 1024) * 1024
 
@@ -319,8 +316,8 @@ def _token_index(payload: jax.Array) -> jax.Array:
 # emits rev(code) | extra<<n with n <= 8 and extra < 2^5 (13 bits); a
 # DYNAMIC match can reach 15-bit codes + 5 extra (20 bits). BIT COUNTS
 # additionally include the distance code (5 bits fixed / 1 bit
-# dynamic), whose bits are zero (symbol 0 reverses to 0). The packers
-# only require value < 2^32 and a <= 2-word span, which 20-bit values
+# dynamic), whose bits are zero (symbol 0 reverses to 0). The packer
+# only requires value < 2^32 and a <= 2-word span, which 20-bit values
 # satisfy at any alignment; and a value and its count share one uint32
 # of the token table (20 + 12 bits).
 _TOKEN_VALUE_BITS = 20
@@ -429,69 +426,6 @@ def _pack_bits_scan(bits: jax.Array, nbits: jax.Array, maxbits: int):
     return packed.reshape(-1), total_bits
 
 
-# Bit-packing geometry of the LEGACY packer (kept only as the pinned
-# reference point for comparison benches/tests — the scan packer above
-# replaced it): output bits are cut into chunks; each chunk's covering
-# tokens come from a fixed-size window starting at the last token at
-# or before the chunk start (merge-path partitioning — both sides are
-# sorted). Real tokens are >= 7 bits (header 3, literal 8/9, match
-# >= 12), so a 128-bit chunk intersects at most ~19 tokens; 24 gives
-# margin.
-_CHUNK_BITS = 128
-_WIN = 24
-
-
-def _pack_bits_gather(bits: jax.Array, nbits: jax.Array, maxbits: int):
-    """LEGACY packer: token (bits, nbits) arrays -> LSB-first packed
-    byte array via an argsort compaction + per-128-bit-chunk token
-    window + dense one-hot reduce. O(maxbits * WIN) work plus a full
-    argsort per lane — measured 0.006 GB/s on TPU, which is why
-    ``_pack_bits_scan`` exists. Kept so the speedup stays measurable
-    (runtime/microbench.py pins scan-vs-gather).
-    """
-    ntok = bits.shape[0]
-    order = jnp.argsort(nbits == 0, stable=True)  # real tokens first
-    bits_c = bits[order].astype(jnp.int32)
-    nbits_c = nbits[order]
-    offs_c = jnp.cumsum(nbits_c) - nbits_c  # exclusive; sorted
-    total_bits = offs_c[-1] + nbits_c[-1]
-    nchunks = maxbits // _CHUNK_BITS
-    chunk_starts = jnp.arange(nchunks, dtype=jnp.int32) * _CHUNK_BITS
-    first = (
-        jnp.searchsorted(offs_c, chunk_starts, side="right") - 1
-    ).astype(jnp.int32)
-    win = jnp.clip(
-        jnp.maximum(first, 0)[:, None]
-        + jnp.arange(_WIN, dtype=jnp.int32)[None, :],
-        0, ntok - 1,
-    )  # (C, W) token indices
-    wo = offs_c[win]
-    wb = bits_c[win]
-    wn = nbits_c[win]
-    jg = (
-        chunk_starts[:, None]
-        + jnp.arange(_CHUNK_BITS, dtype=jnp.int32)[None, :]
-    )  # (C, CB) global bit positions
-    # prefix-true per (chunk, bit) row: window offsets ascend, so the
-    # covering token is the LAST w with wo <= j
-    cmp = wo[:, None, :] <= jg[:, :, None]  # (C, CB, W)
-    last = cmp & ~jnp.concatenate(
-        [cmp[:, :, 1:], jnp.zeros_like(cmp[:, :, :1])], axis=2
-    )
-    onehot = last.astype(jnp.int32)
-    sel_b = (onehot * wb[:, None, :]).sum(2)
-    sel_n = (onehot * wn[:, None, :]).sum(2)
-    shift = (onehot * (jg[:, :, None] - wo[:, None, :])).sum(2)
-    bit = jnp.where(
-        shift < sel_n, (sel_b >> jnp.clip(shift, 0, 31)) & 1, 0
-    )
-    weights = 1 << jnp.arange(8, dtype=jnp.int32)  # LSB-first
-    packed = (
-        (bit.reshape(-1, 8) * weights).sum(axis=1).astype(jnp.uint8)
-    )
-    return packed, total_bits
-
-
 @kernel("ompb_tokens")
 def _lane_tokens(payload: jax.Array) -> tuple:
     """(L,) payload -> (L+1,) (bits, nbits) token arrays including the
@@ -554,10 +488,8 @@ def _frame_lane(payload: jax.Array, packed: jax.Array, body_bits,
     return out, length.astype(jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("packer", "interpret"))
-def _zlib_rle(
-    payloads: jax.Array, packer: str = "scan", interpret: bool = False
-) -> tuple:
+@jax.jit
+def _zlib_rle(payloads: jax.Array) -> tuple:
     # vmap, not lax.map: the scan packer is scans, one scatter and
     # monotone gathers, so batching lanes costs no extra residency.
     # Compiling is what costs: on the v5e the packer alone takes
@@ -565,45 +497,16 @@ def _zlib_rle(
     # (PERF.md §6, PR 27)
     bits, nbits = jax.vmap(_lane_tokens)(payloads)
     maxbits = _packing_maxbits(payloads.shape[1])
-    packed, body_bits = _pack_dispatch(bits, nbits, maxbits, packer, interpret)
+    packed, body_bits = _pack_dispatch(bits, nbits, maxbits)
     return jax.vmap(_frame_lane)(payloads, packed, body_bits)
 
 
-@kernel("ompb_pack", static_argnames=("maxbits", "packer", "interpret"))
-def _pack_dispatch(bits, nbits, maxbits: int, packer: str, interpret: bool):
-    """Route batched token arrays through the selected packer."""
-    if packer == "pallas":
-        from .pallas.bitpack import pack_tokens_sp
-
-        return pack_tokens_sp(bits, nbits, maxbits, interpret=interpret)
-    if packer == "pallas_dense":
-        from .pallas.bitpack import pack_tokens
-
-        return pack_tokens(bits, nbits, maxbits, interpret=interpret)
-    if packer == "gather":
-        return jax.vmap(
-            lambda b, nb: _pack_bits_gather(b, nb, maxbits)
-        )(bits, nbits)
+@kernel("ompb_pack", static_argnames=("maxbits",))
+def _pack_dispatch(bits, nbits, maxbits: int):
+    """Pack a batch's token arrays, a lane at a time under vmap."""
     return jax.vmap(
         lambda b, nb: _pack_bits_scan(b, nb, maxbits)
     )(bits, nbits)
-
-
-_PACKERS = ("scan", "pallas", "pallas_dense", "gather")
-
-
-def default_packer() -> str:
-    """'scan' (the XLA prefix-sum packer) on every backend: it is the
-    one packer the TPU compiler accepts today. OMPB_BITPACK names
-    another of scan|pallas|pallas_dense|gather explicitly; the two
-    Pallas kernels do not lower on the chip (KNOWN_GAPS), so there
-    they raise at compile time and the caller counts the fallback."""
-    import os
-
-    forced = os.environ.get("OMPB_BITPACK")
-    if forced in _PACKERS:
-        return forced
-    return "scan"
 
 
 # ---------------------------------------------------------------------------
@@ -656,7 +559,7 @@ def zlib_stored_batch(payloads) -> jax.Array:
     return _zlib_stored(payloads)
 
 
-def zlib_rle_batch(payloads, packer: Optional[str] = None) -> tuple:
+def zlib_rle_batch(payloads) -> tuple:
     """Compressive zlib streams (Z_RLE match policy, fixed Huffman,
     per-lane stored fallback) for a batch of equal-length payloads,
     built on device. (B, L) uint8 -> ((B, max_stream_len(L)) uint8,
@@ -666,8 +569,7 @@ def zlib_rle_batch(payloads, packer: Optional[str] = None) -> tuple:
         raise ValueError("payloads must be (B, L)")
     if payloads.shape[1] == 0:
         raise ValueError("empty payload")
-    packer = packer or default_packer()
-    return _zlib_rle(payloads, packer, _interpret_for(packer))
+    return _zlib_rle(payloads)
 
 
 # ---------------------------------------------------------------------------
@@ -958,8 +860,7 @@ def _dyn_tokens(payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n):
 
 
 def dynamic_emit_local(
-    payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n,
-    packer: str = "scan", interpret: bool = False,
+    payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
 ):
     """Un-jitted pass-2 core: emit header ++ body ++ explicit EOB
     through the per-lane tables and pack. Traceable under jit, vmap,
@@ -974,25 +875,17 @@ def dynamic_emit_local(
     bits, nbits = _dyn_tokens(
         payloads, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
     )
-    if packer == "gather":
-        # the legacy window packer assumes >= 7-bit real tokens (its
-        # WIN sizing); dynamic codes can be 1 bit, so route to scan
-        packer = "scan"
     maxbits = _packing_maxbits(payloads.shape[1])
-    packed, body_bits = _pack_dispatch(bits, nbits, maxbits, packer, interpret)
+    packed, body_bits = _pack_dispatch(bits, nbits, maxbits)
     return jax.vmap(partial(_frame_lane, eob_bits=0))(
         payloads, packed, body_bits
     )
 
 
-_zlib_dynamic = partial(jax.jit, static_argnames=("packer", "interpret"))(
-    dynamic_emit_local
-)
+_zlib_dynamic = jax.jit(dynamic_emit_local)
 
 
-def zlib_dynamic_batch(
-    payloads, packer: Optional[str] = None, real: Optional[int] = None,
-) -> tuple:
+def zlib_dynamic_batch(payloads, real: Optional[int] = None) -> tuple:
     """Canonical dynamic-Huffman zlib streams (Z_RLE match policy,
     per-lane two-pass code construction, per-lane min(dynamic, fixed,
     stored) selection) for a batch of equal-length payloads. (B, L)
@@ -1006,35 +899,20 @@ def zlib_dynamic_batch(
         raise ValueError("payloads must be (B, L)")
     if payloads.shape[1] == 0:
         raise ValueError("empty payload")
-    packer = packer or default_packer()
     counts, extras = _dyn_stats(payloads)
     counts_np, extras_np = jax.device_get((counts, extras))
     tables = build_dynamic_tables(counts_np, extras_np, real=real)
-    return _zlib_dynamic(
-        payloads, *tables, packer=packer, interpret=_interpret_for(packer)
-    )
+    return _zlib_dynamic(payloads, *tables)
 
 
-def _interpret_for(packer: str) -> bool:
-    """Interpret mode is the CPU backend's way to run a Pallas kernel
-    (tests pin bit-exactness through exactly this path). On the TPU
-    backend it is always False: the kernel compiles for real or the
-    compiler's refusal surfaces."""
-    if not packer.startswith("pallas"):
-        return False
-    return jax.default_backend() != "tpu"
-
-
-def _streams_core(
-    flat: jax.Array, mode: str, packer: str, interpret: bool
-):
+def _streams_core(flat: jax.Array, mode: str):
     if mode == "stored":
         streams = _zlib_stored(flat)
         lengths = jnp.full(
             flat.shape[0], stored_stream_len(flat.shape[1]), jnp.int32
         )
         return streams, lengths
-    return _zlib_rle(flat, packer, interpret)
+    return _zlib_rle(flat)
 
 
 @kernel("ompb_filter", static_argnames=("rows", "row_bytes"))
@@ -1062,13 +940,12 @@ def _filter_flat(tiles, rows: int, row_bytes: int, bpp: int, filter_mode: str):
     return _flatten_rows(filtered, rows, row_bytes)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+@partial(jax.jit, static_argnums=(1, 2, 3))
 def _filtered_to_streams(
-    filtered: jax.Array, rows: int, row_bytes: int, mode: str,
-    packer: str, interpret: bool,
+    filtered: jax.Array, rows: int, row_bytes: int, mode: str
 ):
     flat = _flatten_rows(filtered, rows, row_bytes)
-    return _streams_core(flat, mode, packer, interpret)
+    return _streams_core(flat, mode)
 
 
 def _pad_pow2_lanes(arr: jax.Array):
@@ -1089,8 +966,7 @@ _filtered_to_flat = jax.jit(_flatten_rows, static_argnums=(1, 2))
 
 
 def deflate_filtered_batch(
-    filtered: jax.Array, rows: int, row_bytes: int, mode: str = "rle",
-    packer: Optional[str] = None,
+    filtered: jax.Array, rows: int, row_bytes: int, mode: str = "rle"
 ) -> tuple:
     """Fuse the payload flatten with the stream build: filtered
     scanlines (B, H, 1 + W*itemsize) (device-resident, possibly
@@ -1100,14 +976,13 @@ def deflate_filtered_batch(
     (device histogram, host code build, device emit)."""
     if mode not in ("rle", "stored", "dynamic"):
         raise ValueError(f"Unknown device deflate mode: {mode}")
-    packer = packer or default_packer()
     filtered, b = _pad_pow2_lanes(filtered)
     if mode == "dynamic":
         flat = _filtered_to_flat(filtered, rows, row_bytes)
-        streams, lengths = zlib_dynamic_batch(flat, packer=packer, real=b)
+        streams, lengths = zlib_dynamic_batch(flat, real=b)
     else:
         streams, lengths = _filtered_to_streams(
-            filtered, rows, row_bytes, mode, packer, _interpret_for(packer)
+            filtered, rows, row_bytes, mode
         )
     return streams[:b], lengths[:b]
 
@@ -1119,44 +994,39 @@ def deflate_filtered_batch(
 
 def filter_deflate_local(
     tiles: jax.Array, rows: int, row_bytes: int, bpp: int,
-    filter_mode: str, mode: str, packer: str, interpret: bool,
+    filter_mode: str, mode: str,
 ):
     """Un-jitted fused core: native-dtype tiles (B, H, W[, S]) ->
     (streams, lengths). Traceable under jit, vmap, and shard_map —
     parallel/sharding.py maps exactly this over the mesh, which is
     what makes multi-chip bytes identical to single-device bytes."""
     flat = _filter_flat(tiles, rows, row_bytes, bpp, filter_mode)
-    return _streams_core(flat, mode, packer, interpret)
+    return _streams_core(flat, mode)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _fused_filter_deflate(
-    tiles, rows, row_bytes, bpp, filter_mode, mode, packer, interpret
-):
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _fused_filter_deflate(tiles, rows, row_bytes, bpp, filter_mode, mode):
     return filter_deflate_local(
-        tiles, rows, row_bytes, bpp, filter_mode, mode, packer, interpret
+        tiles, rows, row_bytes, bpp, filter_mode, mode
     )
 
 
-@partial(
-    jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7), donate_argnums=(0,)
-)
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5), donate_argnums=(0,))
 def _fused_filter_deflate_donated(
-    tiles, rows, row_bytes, bpp, filter_mode, mode, packer, interpret
+    tiles, rows, row_bytes, bpp, filter_mode, mode
 ):
     # identical program; the staged input buffer is donated so the
     # filter's big-endian intermediate reuses it instead of doubling
     # HBM residency per in-flight bucket (the double-buffered
     # dispatcher keeps two buckets in flight)
     return filter_deflate_local(
-        tiles, rows, row_bytes, bpp, filter_mode, mode, packer, interpret
+        tiles, rows, row_bytes, bpp, filter_mode, mode
     )
 
 
 def fused_filter_deflate_batch(
     tiles: jax.Array, rows: int, row_bytes: int, bpp: int,
-    filter_mode: str = "up", mode: str = "rle",
-    packer: Optional[str] = None, donate: bool = False,
+    filter_mode: str = "up", mode: str = "rle", donate: bool = False,
 ) -> tuple:
     """The device encode chain as ONE dispatched program: byteswap +
     PNG scanline filter + deflate, nothing surfacing between stages.
@@ -1170,17 +1040,13 @@ def fused_filter_deflate_batch(
     if mode == "dynamic":
         return fused_filter_deflate_dynamic(
             tiles, rows, row_bytes, bpp, filter_mode=filter_mode,
-            packer=packer, donate=donate,
+            donate=donate,
         )
     if mode not in ("rle", "stored"):
         raise ValueError(f"Unknown device deflate mode: {mode}")
-    packer = packer or default_packer()
     tiles, b = _pad_pow2_lanes(tiles)
     fn = _fused_filter_deflate_donated if donate else _fused_filter_deflate
-    streams, lengths = fn(
-        tiles, rows, row_bytes, bpp, filter_mode, mode, packer,
-        _interpret_for(packer),
-    )
+    streams, lengths = fn(tiles, rows, row_bytes, bpp, filter_mode, mode)
     return streams[:b], lengths[:b]
 
 
@@ -1222,18 +1088,15 @@ def fused_filter_histogram_batch(
 
 def dynamic_emit_batch(
     flat: jax.Array, counts_np: np.ndarray, extras_np: np.ndarray,
-    packer: Optional[str] = None, real: Optional[int] = None,
+    real: Optional[int] = None,
 ) -> tuple:
     """Pass 2: host code/table build from the pulled counts, then the
     single emit dispatch. ``real`` bounds the host plan work to the
     real lanes AND slices the pow2 padding back off the outputs."""
-    packer = packer or default_packer()
     tables = build_dynamic_tables(
         np.asarray(counts_np), np.asarray(extras_np), real=real
     )
-    streams, lengths = _zlib_dynamic(
-        flat, *tables, packer=packer, interpret=_interpret_for(packer)
-    )
+    streams, lengths = _zlib_dynamic(flat, *tables)
     if real is not None:
         return streams[:real], lengths[:real]
     return streams, lengths
@@ -1241,17 +1104,16 @@ def dynamic_emit_batch(
 
 def fused_filter_deflate_dynamic(
     tiles: jax.Array, rows: int, row_bytes: int, bpp: int,
-    filter_mode: str = "up", packer: Optional[str] = None,
-    donate: bool = False,
+    filter_mode: str = "up", donate: bool = False,
 ) -> tuple:
-    """Both passes back to back (tests, microbench, non-streamed
-    callers): pass 1, ONE small host pull of the counts, pass 2."""
+    """Both passes back to back (tests, non-streamed callers): pass
+    1, ONE small host pull of the counts, pass 2."""
     flat, counts, extras, b = fused_filter_histogram_batch(
         tiles, rows, row_bytes, bpp, filter_mode=filter_mode,
         donate=donate,
     )
     counts_np, extras_np = jax.device_get((counts, extras))
-    return dynamic_emit_batch(flat, counts_np, extras_np, packer, real=b)
+    return dynamic_emit_batch(flat, counts_np, extras_np, real=b)
 
 
 # ---------------------------------------------------------------------------
@@ -1295,8 +1157,7 @@ def _rle_tokens_np(payload: np.ndarray):
 def _pack_bits_scan_np(bits: np.ndarray, nbits: np.ndarray, maxbits: int):
     """Numpy port of the carry-free prefix-sum packer: identical word
     math on wrapping uint32 cumsums, so the packed bytes are identical
-    to the device packer's (and, transitively, to the Pallas kernel's,
-    which is pinned bit-exact against the scan packer)."""
+    to the device packer's."""
     offs = np.cumsum(nbits) - nbits
     total_bits = int(offs[-1] + nbits[-1])
     s = (offs & 31).astype(np.uint32)

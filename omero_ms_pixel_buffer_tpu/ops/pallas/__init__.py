@@ -1,4 +1,3 @@
 """Pallas TPU kernels for the tile hot path."""
 
-from .bitpack import pack_tokens  # noqa: F401
 from .filter import filter_tiles, supports  # noqa: F401

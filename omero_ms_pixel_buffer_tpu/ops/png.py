@@ -14,7 +14,7 @@ TPU-first split:
   filtered scanlines in one fused XLA kernel).
 - **Deflate + chunk framing** — the serial half — runs on host zlib
   (releases the GIL, so the executor overlaps it with device compute),
-  until the Pallas fixed-Huffman encoder (ops/pallas) takes over.
+  or on the device too (ops/device_deflate.py, ``png.device-deflate``).
 
 Correctness contract is *decoded-pixel equality*, not byte equality:
 any compliant PNG stream is acceptable (viewers and the reference's

@@ -24,7 +24,6 @@ exactly the collectives written here and nothing else.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -130,18 +129,15 @@ def distributed_filter_plane(
     return _distributed_filter(mesh, plane, mode, axis)
 
 
-@partial(jax.jit, static_argnums=(0, 2, 3, 4, 5, 6, 7, 8))
+@partial(jax.jit, static_argnums=(0, 2, 3, 4, 5, 6, 7))
 def _sharded_filter_deflate(
-    mesh, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
-    packer, axis,
+    mesh, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode, axis
 ):
-    from ..ops.device_deflate import _interpret_for, filter_deflate_local
+    from ..ops.device_deflate import filter_deflate_local
 
-    interpret = _interpret_for(packer)
     fn = shard_map(
         lambda blk: filter_deflate_local(
-            blk, rows, row_bytes, bpp, filter_mode, deflate_mode,
-            packer, interpret,
+            blk, rows, row_bytes, bpp, filter_mode, deflate_mode
         ),
         mesh=mesh,
         in_specs=P(axis),
@@ -158,7 +154,6 @@ def sharded_filter_deflate(
     bpp: int,
     filter_mode: str = "up",
     deflate_mode: str = "rle",
-    packer: Optional[str] = None,
     axis: str = "data",
 ) -> tuple:
     """The REAL multi-chip encode dispatch: the fused byteswap +
@@ -172,28 +167,21 @@ def sharded_filter_deflate(
     tiles (B, H, W[, S]) with B divisible by the mesh axis (pad with
     ``pad_batch``) -> ((B, cap) uint8 streams, (B,) int32 lengths),
     both batch-sharded."""
-    from ..ops.device_deflate import default_packer
-
-    packer = packer or default_packer()
     return _sharded_filter_deflate(
-        mesh, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
-        packer, axis,
+        mesh, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode, axis
     )
 
 
-@partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8, 9))
+@partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8))
 def _sharded_render_filter_deflate(
     mesh, planes, index_tables, color_luts, rows, row_bytes,
-    filter_mode, deflate_mode, packer, axis,
+    filter_mode, deflate_mode, axis,
 ):
-    from ..ops.device_deflate import _interpret_for
     from ..render.engine import render_filter_deflate_local
 
-    interpret = _interpret_for(packer)
     fn = shard_map(
         lambda blk, tab, lut: render_filter_deflate_local(
-            blk, tab, lut, rows, row_bytes, filter_mode, deflate_mode,
-            packer, interpret,
+            blk, tab, lut, rows, row_bytes, filter_mode, deflate_mode
         ),
         mesh=mesh,
         in_specs=(P(axis), P(), P()),  # tables replicate to every chip
@@ -202,19 +190,17 @@ def _sharded_render_filter_deflate(
     return fn(planes, index_tables, color_luts)
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6, 7, 8, 9, 10))
+@partial(jax.jit, static_argnums=(0, 5, 6, 7, 8, 9))
 def _sharded_render_filter_deflate_masked(
     mesh, planes, index_tables, color_luts, mask, rows, row_bytes,
-    filter_mode, deflate_mode, packer, axis,
+    filter_mode, deflate_mode, axis,
 ):
-    from ..ops.device_deflate import _interpret_for
     from ..render.engine import render_filter_deflate_local
 
-    interpret = _interpret_for(packer)
     fn = shard_map(
         lambda blk, tab, lut, msk: render_filter_deflate_local(
             blk, tab, lut, rows, row_bytes, filter_mode, deflate_mode,
-            packer, interpret, mask=msk,
+            mask=msk,
         ),
         mesh=mesh,
         # the (B, H, W) ROI mask batch shards WITH its lanes; only the
@@ -234,7 +220,6 @@ def sharded_render_filter_deflate(
     row_bytes: int,
     filter_mode: str = "up",
     deflate_mode: str = "rle",
-    packer: Optional[str] = None,
     axis: str = "data",
     mask=None,
 ) -> tuple:
@@ -252,19 +237,16 @@ def sharded_render_filter_deflate(
     is pointwise int, so masked mesh bytes stay identical to the
     single-device and host-mirror bytes (masked groups no longer
     split to one chip)."""
-    from ..ops.device_deflate import default_packer
-
-    packer = packer or default_packer()
     if mask is not None:
         return _sharded_render_filter_deflate_masked(
             mesh, planes, jnp.asarray(index_tables),
             jnp.asarray(color_luts), jnp.asarray(mask), rows,
-            row_bytes, filter_mode, deflate_mode, packer, axis,
+            row_bytes, filter_mode, deflate_mode, axis,
         )
     return _sharded_render_filter_deflate(
         mesh, planes, jnp.asarray(index_tables),
         jnp.asarray(color_luts), rows, row_bytes, filter_mode,
-        deflate_mode, packer, axis,
+        deflate_mode, axis,
     )
 
 
@@ -311,18 +293,15 @@ def sharded_filter_histogram(
     )
 
 
-@partial(jax.jit, static_argnums=(0, 10, 11))
+@partial(jax.jit, static_argnums=(0, 10))
 def _sharded_dynamic_emit(
     mesh, flat, hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n,
-    packer, axis,
+    axis,
 ):
-    from ..ops.device_deflate import _interpret_for, dynamic_emit_local
+    from ..ops.device_deflate import dynamic_emit_local
 
-    interpret = _interpret_for(packer)
     fn = shard_map(
-        lambda p, hb, hn, lb, ln, mb, mn, eb, en: dynamic_emit_local(
-            p, hb, hn, lb, ln, mb, mn, eb, en, packer, interpret
-        ),
+        dynamic_emit_local,
         mesh=mesh,
         # every emit table is (B, ...)-shaped along the lane axis, so
         # each chip carries ITS lanes' codes — no replication at all
@@ -336,7 +315,6 @@ def sharded_dynamic_emit(
     mesh: Mesh,
     flat: jax.Array,
     tables: tuple,
-    packer: Optional[str] = None,
     axis: str = "data",
 ) -> tuple:
     """Dynamic pass 2 over the mesh: the per-lane-table emit
@@ -345,15 +323,10 @@ def sharded_dynamic_emit(
     lanes. Per-lane math is chip-independent, so mesh dynamic bytes
     are identical to the single-device two-pass bytes on the same
     lanes."""
-    from ..ops.device_deflate import default_packer
-
-    packer = packer or default_packer()
     table_dev = tuple(
         jax.device_put(t, NamedSharding(mesh, P(axis))) for t in tables
     )
-    return _sharded_dynamic_emit(
-        mesh, flat, *table_dev, packer, axis
-    )
+    return _sharded_dynamic_emit(mesh, flat, *table_dev, axis)
 
 
 # ---------------------------------------------------------------------------
@@ -362,18 +335,16 @@ def sharded_dynamic_emit(
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnums=(0, 5, 6, 7, 8, 9, 10, 11, 12))
+@partial(jax.jit, static_argnums=(0, 5, 6, 7, 8, 9, 10, 11))
 def _sharded_supertile_carve_deflate(
     mesh, sub_stacks, index_tables, color_luts, coords, bh, bw,
-    rows, row_bytes, filter_mode, deflate_mode, packer, axis,
+    rows, row_bytes, filter_mode, deflate_mode, axis,
 ):
     from jax import lax
 
-    from ..ops.device_deflate import _interpret_for, _streams_core
+    from ..ops.device_deflate import _streams_core
     from ..ops.png import _filter_batch
     from ..render.engine import render_local
-
-    interpret = _interpret_for(packer)
 
     def local(blk, coords_blk, tab, lut):
         # blk: (1, C, Hs, Ws) — this chip's overlapped sub-rect of the
@@ -394,7 +365,7 @@ def _sharded_supertile_carve_deflate(
         flat = filtered[:, :rows, :row_bytes].reshape(
             filtered.shape[0], -1
         )
-        return _streams_core(flat, deflate_mode, packer, interpret)
+        return _streams_core(flat, deflate_mode)
 
     fn = shard_map(
         local,
@@ -415,7 +386,6 @@ def sharded_supertile_carve_deflate(
     bw: int,
     filter_mode: str = "up",
     deflate_mode: str = "rle",
-    packer: Optional[str] = None,
     axis: str = "data",
 ) -> tuple:
     """The mesh-fused super-tile chain: each chip composites ITS
@@ -438,13 +408,10 @@ def sharded_supertile_carve_deflate(
     sub-rect rendered it), PNG filters reference only up/left inside
     the carved tile, and the stream consumes exactly the tile's
     sliced scanline bytes."""
-    from ..ops.device_deflate import default_packer
-
-    packer = packer or default_packer()
     return _sharded_supertile_carve_deflate(
         mesh, sub_stacks, jnp.asarray(index_tables),
         jnp.asarray(color_luts), coords, bh, bw, bh, 1 + bw * 3,
-        filter_mode, deflate_mode, packer, axis,
+        filter_mode, deflate_mode, axis,
     )
 
 

@@ -41,10 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.device_deflate import (
-    _interpret_for,
     _pad_pow2_lanes,
     _streams_core,
-    default_packer,
     zlib_rle_np,
 )
 from ..ops.png import _filter_batch, filter_rows_np, frame_png
@@ -314,8 +312,6 @@ def render_filter_deflate_local(
     row_bytes: int,
     filter_mode: str,
     mode: str,
-    packer: str,
-    interpret: bool,
     mask: Optional[jax.Array] = None,
 ):
     """Un-jitted fused core: unsigned channel planes (B, C, H, W) ->
@@ -329,17 +325,17 @@ def render_filter_deflate_local(
     scanrows = rgb.reshape(b, h, -1)
     filtered = _filter_batch(scanrows, 3, filter_mode)
     flat = filtered[:, :rows, :row_bytes].reshape(b, -1)
-    return _streams_core(flat, mode, packer, interpret)
+    return _streams_core(flat, mode)
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _fused_render_filter_deflate(
     planes, index_tables, color_luts, rows, row_bytes, filter_mode,
-    mode, packer, interpret, mask,
+    mode, mask,
 ):
     return render_filter_deflate_local(
         planes, index_tables, color_luts, rows, row_bytes,
-        filter_mode, mode, packer, interpret, mask,
+        filter_mode, mode, mask,
     )
 
 
@@ -351,7 +347,6 @@ def fused_render_filter_deflate_batch(
     row_bytes: int,
     filter_mode: str = "up",
     mode: str = "rle",
-    packer: Optional[str] = None,
     mask=None,
 ) -> tuple:
     """The render serving chain as ONE dispatched program. planes
@@ -363,7 +358,6 @@ def fused_render_filter_deflate_batch(
     (compile-specialization cap)."""
     if mode not in ("rle", "stored"):
         raise ValueError(f"Unknown device deflate mode: {mode}")
-    packer = packer or default_packer()
     planes, b = _pad_pow2_lanes(jnp.asarray(planes))
     if mask is not None:
         # pad the mask's lane axis identically (pad lanes mask to 0 —
@@ -371,8 +365,7 @@ def fused_render_filter_deflate_batch(
         mask, _ = _pad_pow2_lanes(jnp.asarray(mask))
     streams, lengths = _fused_render_filter_deflate(
         planes, jnp.asarray(index_tables), jnp.asarray(color_luts),
-        rows, row_bytes, filter_mode, mode, packer,
-        _interpret_for(packer), mask,
+        rows, row_bytes, filter_mode, mode, mask,
     )
     return streams[:b], lengths[:b]
 

@@ -79,10 +79,10 @@ def test_pallas_filter_kernel_lowers(one_chip, shape, dtype):
     assert _is_kernel(compiled)
 
 
-# -- the encode chain the chip serves: filter + deflate, packer "scan" --
+# -- the encode chain the chip serves: filter + deflate ----------------
 
 
-def test_fused_filter_deflate_rle_scan(one_chip):
+def test_fused_filter_deflate_rle(one_chip):
     from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
         filter_deflate_local,
     )
@@ -91,7 +91,7 @@ def test_fused_filter_deflate_rle_scan(one_chip):
         filter_deflate_local,
         _shape(one_chip, (LANES, 512, 512), jnp.uint16),
         rows=512, row_bytes=1 + 512 * 2, bpp=2, filter_mode="up",
-        mode="rle", packer="scan", interpret=False,
+        mode="rle",
     )
 
 
@@ -122,15 +122,12 @@ def _dynamic_emit_args(one_chip, lanes=LANES, payload=512 * (1 + 512 * 2)):
     ]
 
 
-def test_dynamic_pass2_emit_scan(one_chip):
+def test_dynamic_pass2_emit(one_chip):
     from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
         dynamic_emit_local,
     )
 
-    _compile(
-        dynamic_emit_local, *_dynamic_emit_args(one_chip),
-        packer="scan", interpret=False,
-    )
+    _compile(dynamic_emit_local, *_dynamic_emit_args(one_chip))
 
 
 # -- /render: the fused composite program and the super-tile carve ------
@@ -148,7 +145,6 @@ def test_render_composite_filter_deflate(one_chip):
         _shape(one_chip, (channels, 65536), jnp.uint8),
         _shape(one_chip, (channels, 256, 3), jnp.uint8),
         rows=512, row_bytes=1 + 512 * 3, filter_mode="up", mode="rle",
-        packer="scan", interpret=False,
     )
 
 
@@ -168,52 +164,4 @@ def test_supertile_composite_carve(one_chip):
         _shape(one_chip, (channels, 256, 3), jnp.uint8),
         _shape(one_chip, (tiles, 2), jnp.int32),
         512, 512,
-    ).compile()
-
-
-# -- the two Pallas bit packers do not lower on the v5e today -----------
-# strict xfail: the day one lowers, the test says so (ROADMAP S1).
-
-
-def _token_args(one_chip, lanes=LANES, ntok=512 * (1 + 512 * 2) + 8):
-    return (
-        _shape(one_chip, (lanes, ntok), jnp.uint32),
-        _shape(one_chip, (lanes, ntok), jnp.int32),
-    )
-
-
-@pytest.mark.xfail(
-    strict=True,
-    reason="Mosaic refuses pack_tokens_sp: block (1, 256) on a (B, ntok) "
-    "array (last two block dims must divide by 8x128 or equal the "
-    "array's); past that, in-kernel jnp.take ('Only 2D gather is "
-    "supported') and the dynamic pl.ds(wstart, 170) strip",
-)
-def test_pallas_scalar_prefetch_packer_lowers(one_chip):
-    from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-        _packing_maxbits,
-    )
-    from omero_ms_pixel_buffer_tpu.ops.pallas.bitpack import pack_tokens_sp
-
-    pack_tokens_sp.lower(
-        *_token_args(one_chip),
-        maxbits=_packing_maxbits(512 * (1 + 512 * 2)), interpret=False,
-    ).compile()
-
-
-@pytest.mark.xfail(
-    strict=True,
-    reason="Mosaic refuses pack_tokens: the same (1, 256) block-shape "
-    "refusal; past it, 'cannot statically prove that index in dimension "
-    "2 is a multiple of 128' on the pl.ds(wstart, _SPAN) load",
-)
-def test_pallas_dense_packer_lowers(one_chip):
-    from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-        _packing_maxbits,
-    )
-    from omero_ms_pixel_buffer_tpu.ops.pallas.bitpack import pack_tokens
-
-    pack_tokens.lower(
-        *_token_args(one_chip),
-        maxbits=_packing_maxbits(512 * (1 + 512 * 2)), interpret=False,
     ).compile()

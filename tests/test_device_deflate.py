@@ -28,6 +28,33 @@ from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
 rng = np.random.default_rng(41)
 
 
+def _synth_tiles(b: int, h: int, w: int, seed: int) -> np.ndarray:
+    """Microscopy-like uint16 content: a smooth field + sensor noise."""
+    r = np.random.default_rng(seed)
+    yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
+    base = 2000 + 1500 * np.sin(xx / 97.0) + 1500 * np.cos(yy / 131.0)
+    return (base[None] + r.normal(0, 120.0, (b, h, w))).clip(
+        0, 65535
+    ).astype(np.uint16)
+
+
+def _synth_rgb_tiles(b: int, h: int, w: int, seed: int) -> np.ndarray:
+    """Rendered-RGB-like content (three smooth composited channels +
+    light noise, what /render emits after window/LUT compositing):
+    far less run-heavy than raw greyscale planes, which is where the
+    fixed-Huffman stream paid 1.38x of the host's bytes."""
+    r = np.random.default_rng(seed)
+    yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
+    chans = [
+        120 + 60 * np.sin(xx / fx + ph) + 50 * np.cos(yy / fy)
+        for ph, (fx, fy) in enumerate(
+            ((97.0, 131.0), (61.0, 89.0), (151.0, 47.0))
+        )
+    ]
+    img = np.stack(chans, -1)[None] + r.normal(0, 6.0, (b, h, w, 3))
+    return img.clip(0, 255).astype(np.uint8)
+
+
 def _payload_families(n: int = 1500):
     """The payload shapes that break packers: runs, noise, no-runs,
     constants, run/match boundary tails."""
@@ -142,22 +169,27 @@ class TestMinStreamSelection:
         assert (lengths < stored_stream_len(payloads.shape[1]) // 2).all()
 
 
-class TestPackerEquivalence:
-    """The scan packer replaced the gather packer; both must emit
-    byte-identical streams (same zero padding, same framing)."""
+class TestRleStreamsAreTheNumpyTwins:
+    """The fixed-Huffman stream of every payload family is the numpy
+    twin's byte for byte (``zlib_rle_np``: the same word math, its
+    boundaries from ``np.searchsorted``) and inflates to its payload.
+    The sizes are the union of what the comparisons with the removed
+    packers used."""
 
-    @pytest.mark.parametrize("n", [1, 258, 777, 4096])
-    def test_scan_matches_gather(self, n):
+    @pytest.mark.parametrize(
+        "n", [1, 5, 17, 256, 258, 777, 1000, 1500, 2048, 4096, 5000, 70000]
+    )
+    def test_stream_bytes(self, n):
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import zlib_rle_np
+
         payloads = _payload_families(n)
-        s1, l1 = (
-            np.asarray(a) for a in zlib_rle_batch(payloads, packer="scan")
-        )
-        s2, l2 = (
-            np.asarray(a)
-            for a in zlib_rle_batch(payloads, packer="gather")
-        )
-        np.testing.assert_array_equal(l1, l2)
-        np.testing.assert_array_equal(s1, s2)
+        streams, lengths = (np.asarray(a) for a in zlib_rle_batch(payloads))
+        bound = stored_stream_len(n)
+        for lane in range(payloads.shape[0]):
+            assert lengths[lane] <= bound
+            got = bytes(streams[lane][: lengths[lane]])
+            assert got == zlib_rle_np(payloads[lane]), f"lane {lane}"
+            assert zlib.decompress(got) == payloads[lane].tobytes()
 
 
 def _cell_constant_nbits():
@@ -211,6 +243,65 @@ class TestBoundaryCount:
         got = np.asarray(_tokens_below_edges(offs.astype(np.int32), nwords))
         assert got.dtype == np.int32 and got.shape == (nwords,)
         np.testing.assert_array_equal(got, want)
+
+
+class TestPackerWords:
+    """``_pack_bits_scan`` against ``_pack_bits_scan_np``: the packed
+    words and the bit total, where ``TestBoundaryCount`` holds one step
+    of it."""
+
+    @staticmethod
+    def _both(bits, nbits, maxbits):
+        """A batch through the programs' own entry, lane by lane
+        against the numpy packer."""
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            _pack_bits_scan_np,
+            _pack_dispatch,
+        )
+
+        packed, totals = (
+            np.asarray(a) for a in _pack_dispatch(bits, nbits, maxbits)
+        )
+        for lane in range(bits.shape[0]):
+            want, want_total = _pack_bits_scan_np(
+                bits[lane], nbits[lane].astype(np.int64), maxbits
+            )
+            assert totals[lane] == want_total, lane
+            assert packed[lane].tobytes() == want, lane
+
+    @pytest.mark.parametrize("case", sorted(_BOUNDARY_CASES))
+    def test_boundary_cases(self, case):
+        nbits, nwords = _BOUNDARY_CASES[case]()
+        # a token's value has at most 20 significant bits, and a
+        # zero-length token carries none
+        r = np.random.default_rng(len(case))
+        bits = (
+            r.integers(0, 1 << 20, nbits.shape[0])
+            & ((1 << np.minimum(nbits, 20)) - 1)
+        ).astype(np.uint32)
+        self._both(bits[None], nbits.astype(np.int32)[None], nwords * 32)
+
+    @pytest.mark.parametrize("n", [17, 258, 1200, 5000])
+    def test_dynamic_token_arrays(self, n):
+        """Pass 2's tokens: header ++ body through per-lane dynamic
+        codes (1 to 20 bits where a fixed token has 7 or more) ++ an
+        explicit EOB."""
+        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
+            _dyn_stats,
+            _dyn_tokens,
+            _packing_maxbits,
+            build_dynamic_tables,
+        )
+
+        payloads = _dyn_corpus(n)
+        counts, extras = (np.asarray(a) for a in _dyn_stats(payloads))
+        tables = build_dynamic_tables(counts, extras)
+        bits, nbits = (
+            np.asarray(a) for a in _dyn_tokens(payloads, *tables)
+        )
+        body = nbits[:, tables[0].shape[1] : -1]
+        assert ((body > 0) & (body < 7)).any()
+        self._both(bits, nbits, _packing_maxbits(n))
 
 
 def _cell_tiles(lanes: int) -> np.ndarray:
@@ -535,54 +626,6 @@ class TestCellShape:
         assert _streams_sha(streams, lengths) == _PINNED_SHA[case]["dynamic"]
 
 
-class TestPallasBitpack:
-    """The Pallas per-block VMEM-emit kernel, interpret mode on CPU:
-    streams must decompress to the input AND be bit-exact against the
-    XLA scan packer (identical zero padding included)."""
-
-    @pytest.mark.parametrize("n", [1, 5, 258, 1500, 70000])
-    def test_bit_exact_lanes(self, n):
-        payloads = _payload_families(n)
-        ps, pl_ = (
-            np.asarray(a)
-            for a in zlib_rle_batch(payloads, packer="pallas")
-        )
-        ss, sl = (
-            np.asarray(a) for a in zlib_rle_batch(payloads, packer="scan")
-        )
-        np.testing.assert_array_equal(pl_, sl)
-        np.testing.assert_array_equal(ps, ss)
-        bound = stored_stream_len(n)
-        for lane in range(payloads.shape[0]):
-            assert pl_[lane] <= bound
-            got = zlib.decompress(bytes(ps[lane][: pl_[lane]]))
-            assert got == payloads[lane].tobytes(), f"lane {lane}"
-
-    def test_fused_chain_with_pallas_packer(self):
-        import jax.numpy as jnp
-
-        tiles = rng.integers(0, 60000, (3, 48, 48), dtype=np.uint16)
-        streams, lengths = (
-            np.asarray(a)
-            for a in fused_filter_deflate_batch(
-                jnp.asarray(tiles), 48, 1 + 48 * 2, 2, packer="pallas"
-            )
-        )
-        from omero_ms_pixel_buffer_tpu.ops.convert import (
-            to_big_endian_bytes,
-        )
-        from omero_ms_pixel_buffer_tpu.ops.png import filter_batch
-
-        ref = np.asarray(
-            filter_batch(to_big_endian_bytes(jnp.asarray(tiles)), 2, "up")
-        )
-        for lane in range(3):
-            got = zlib.decompress(
-                bytes(streams[lane][: lengths[lane]])
-            )
-            assert got == ref[lane].tobytes()
-
-
 class TestStoredStreams:
     @pytest.mark.parametrize("n", [1, 100, 65535, 65536, 70000, 131071])
     def test_roundtrip(self, n):
@@ -606,17 +649,21 @@ class TestDeflateFiltered:
         rows = to_big_endian_bytes(jnp.asarray(tiles))
         return filter_batch(rows, tiles.dtype.itemsize, mode)
 
+    @staticmethod
+    def _assert_inflates_to(out, want):
+        streams, lengths = (np.asarray(a) for a in out)
+        want = np.asarray(want)
+        assert streams.shape[0] == want.shape[0]
+        for lane in range(want.shape[0]):
+            got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
+            assert got == want[lane].tobytes(), lane
+
     def test_matches_host_payload(self):
         tiles = rng.integers(0, 60000, (4, 64, 64), dtype=np.uint16)
         filtered = self._filtered(tiles)
-        streams, lengths = (
-            np.asarray(a)
-            for a in deflate_filtered_batch(filtered, 64, 1 + 64 * 2)
+        self._assert_inflates_to(
+            deflate_filtered_batch(filtered, 64, 1 + 64 * 2), filtered
         )
-        host = np.asarray(filtered)
-        for lane in range(4):
-            got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
-            assert got == host[lane].tobytes()
 
     def test_bucket_padding_sliced_away(self):
         # real region 40x30 inside a 64x64 bucket: the stream must cover
@@ -624,14 +671,10 @@ class TestDeflateFiltered:
         tiles = np.zeros((2, 64, 64), np.uint16)
         tiles[:, :30, :40] = rng.integers(0, 60000, (2, 30, 40))
         filtered = self._filtered(tiles)
-        streams, lengths = (
-            np.asarray(a)
-            for a in deflate_filtered_batch(filtered, 30, 1 + 40 * 2)
+        self._assert_inflates_to(
+            deflate_filtered_batch(filtered, 30, 1 + 40 * 2),
+            np.asarray(filtered)[:, :30, : 1 + 40 * 2],
         )
-        host = np.asarray(filtered)[:, :30, : 1 + 40 * 2]
-        for lane in range(2):
-            got = zlib.decompress(bytes(streams[lane][: lengths[lane]]))
-            assert got == host[lane].tobytes()
 
     def test_stored_mode(self):
         tiles = rng.integers(0, 255, (2, 32, 32), dtype=np.uint8)
@@ -652,6 +695,41 @@ class TestDeflateFiltered:
         with pytest.raises(ValueError):
             deflate_filtered_batch(np.zeros((1, 8, 8), np.uint8), 8, 8,
                                    mode="huffman")
+
+    def test_pallas_filtered_microscopy_tiles_decode(self):
+        # smooth field + sensor noise through the Pallas filter kernel
+        from omero_ms_pixel_buffer_tpu.ops.pallas.filter import (
+            filter_tiles,
+        )
+
+        filtered = filter_tiles(_synth_tiles(4, 32, 32, seed=5), "up")
+        self._assert_inflates_to(
+            deflate_filtered_batch(filtered, 32, 1 + 64),
+            np.asarray(filtered)[:, :32, : 1 + 64],
+        )
+
+    def test_compression_on_run_heavy_content(self):
+        # noisy 16-bit content defeats RLE at tiny tiles; run-heavy
+        # content must compress
+        from omero_ms_pixel_buffer_tpu.ops.pallas.filter import (
+            filter_tiles,
+        )
+
+        tiles = np.full((4, 32, 32), 777, np.uint16)  # flat field
+        filtered = filter_tiles(tiles, "up")
+        _, lengths = deflate_filtered_batch(filtered, 32, 1 + 64)
+        assert np.asarray(lengths).mean() < 0.2 * 32 * (1 + 64)
+
+    def test_fused_chain_inflates_to_the_filtered_rows(self):
+        import jax.numpy as jnp
+
+        tiles = rng.integers(0, 60000, (3, 48, 48), dtype=np.uint16)
+        self._assert_inflates_to(
+            fused_filter_deflate_batch(
+                jnp.asarray(tiles), 48, 1 + 48 * 2, 2
+            ),
+            self._filtered(tiles),
+        )
 
 
 class TestPipelineDeviceDeflate:
@@ -1140,24 +1218,22 @@ class TestDynamicHuffman:
     corpus, the per-lane min(dynamic, fixed, stored) guarantee, and the
     ratio win on low-run content it exists for."""
 
-    def test_randomized_corpus_decodes_exact(self):
+    # incl. single-byte + >64K
+    @pytest.mark.parametrize("n", [1, 2, 3, 257, 1500, 70000])
+    def test_randomized_corpus_decodes_exact(self, n):
         from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
             zlib_dynamic_batch,
         )
 
-        for n in (1, 2, 3, 257, 1500, 70000):  # incl. single-byte + >64K
-            batch = _dyn_corpus(1500)[:, :n] if n <= 1500 else np.stack(
-                [
-                    np.resize(lane, n)
-                    for lane in _dyn_corpus(1500)
-                ]
-            )
-            streams, lengths = (
-                np.asarray(a) for a in zlib_dynamic_batch(batch)
-            )
-            for i in range(batch.shape[0]):
-                got = zlib.decompress(bytes(streams[i][: lengths[i]]))
-                assert got == batch[i].tobytes(), (n, i)
+        batch = _dyn_corpus(1500)[:, :n] if n <= 1500 else np.stack(
+            [np.resize(lane, n) for lane in _dyn_corpus(1500)]
+        )
+        streams, lengths = (
+            np.asarray(a) for a in zlib_dynamic_batch(batch)
+        )
+        for i in range(batch.shape[0]):
+            got = zlib.decompress(bytes(streams[i][: lengths[i]]))
+            assert got == batch[i].tobytes(), i
 
     def test_selection_never_exceeds_stored_bound(self):
         from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
@@ -1200,12 +1276,9 @@ class TestDynamicHuffman:
             fused_filter_deflate_dynamic,
         )
         from omero_ms_pixel_buffer_tpu.ops.png import filter_batch
-        from omero_ms_pixel_buffer_tpu.runtime.microbench import (
-            synth_rgb_tiles,
-        )
 
         b, tile = 4, 128
-        rgb = synth_rgb_tiles(b, tile, tile, seed=5)
+        rgb = _synth_rgb_tiles(b, tile, tile, seed=5)
         rows = 1 + tile * 3
         _, lengths = fused_filter_deflate_dynamic(rgb, tile, rows, 3)
         filt = np.asarray(filter_batch(
@@ -1237,117 +1310,6 @@ class TestDynamicHuffman:
         for i in range(3):
             got = zlib.decompress(bytes(streams[i][: lengths[i]]))
             assert got == payloads[i].tobytes()
-
-    def test_packers_bit_exact_for_dynamic_tokens(self):
-        """The Pallas kernels must agree with the scan packer on
-        DYNAMIC token streams too (1..20-bit codes, explicit EOB)."""
-        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-            zlib_dynamic_batch,
-        )
-
-        batch = _dyn_corpus(1200)
-        s0, l0 = (np.asarray(a) for a in zlib_dynamic_batch(
-            batch, packer="scan"
-        ))
-        for packer in ("pallas", "pallas_dense"):
-            s1, l1 = (np.asarray(a) for a in zlib_dynamic_batch(
-                batch, packer=packer
-            ))
-            assert (l0 == l1).all(), packer
-            assert (s0 == s1).all(), packer
-
-
-class TestScalarPrefetchEmit:
-    """The r12 PrefetchScalarGridSpec kernel: bit-exact against the
-    XLA scan packer in interpret mode, with the op-count reduction
-    pinned analytically (not timed — CI boxes are noisy)."""
-
-    @pytest.mark.parametrize("n", [17, 256, 1000, 5000])
-    def test_bit_exact_vs_scan(self, n):
-        payloads = _payload_families(n)
-        s0, l0 = (np.asarray(a) for a in zlib_rle_batch(
-            payloads, packer="scan"
-        ))
-        s1, l1 = (np.asarray(a) for a in zlib_rle_batch(
-            payloads, packer="pallas"
-        ))
-        assert (l0 == l1).all()
-        assert (s0 == s1).all()
-
-    def test_matches_dense_kernel(self, ):
-        payloads = _payload_families(2048)
-        s0, l0 = (np.asarray(a) for a in zlib_rle_batch(
-            payloads, packer="pallas_dense"
-        ))
-        s1, l1 = (np.asarray(a) for a in zlib_rle_batch(
-            payloads, packer="pallas"
-        ))
-        assert (l0 == l1).all()
-        assert (s0 == s1).all()
-
-    def test_op_count_reduction_pinned(self):
-        from omero_ms_pixel_buffer_tpu.ops.pallas.bitpack import (
-            emit_ops_per_token,
-        )
-
-        dense = emit_ops_per_token("dense")
-        sp = emit_ops_per_token("sp")
-        assert sp * 4 < dense, (
-            f"scalar-prefetch emit ({sp:.0f} ops/token) must cut the "
-            f"dense emit ({dense:.0f}) by >= 4x"
-        )
-
-    def test_default_packer_names(self):
-        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-            default_packer,
-        )
-
-        for name in ("scan", "pallas", "pallas_dense", "gather"):
-            os.environ["OMPB_BITPACK"] = name
-            try:
-                assert default_packer() == name
-            finally:
-                del os.environ["OMPB_BITPACK"]
-
-    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
-    def test_default_packer_is_scan_on_every_backend(
-        self, monkeypatch, backend
-    ):
-        """The scan packer is the one the chip's compiler accepts; the
-        Pallas ones are reachable by explicit name only."""
-        import jax
-
-        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-            default_packer,
-        )
-
-        monkeypatch.delenv("OMPB_BITPACK", raising=False)
-        monkeypatch.setattr(jax, "default_backend", lambda: backend)
-        assert default_packer() == "scan"
-
-    def test_interpret_is_cpu_only_and_never_a_default(self, monkeypatch):
-        """Interpret mode is how the CPU backend runs a Pallas kernel;
-        on tpu it is always False, and a backend that cannot be asked
-        is an error, not a quiet interpreter."""
-        import jax
-
-        from omero_ms_pixel_buffer_tpu.ops.device_deflate import (
-            _interpret_for,
-        )
-
-        assert _interpret_for("pallas") is True  # CPU backend here
-        assert _interpret_for("scan") is False
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        for packer in ("pallas", "pallas_dense", "scan", "gather"):
-            assert _interpret_for(packer) is False
-
-        def broken():
-            raise RuntimeError("backend init failed")
-
-        monkeypatch.setattr(jax, "default_backend", broken)
-        with pytest.raises(RuntimeError, match="backend init failed"):
-            _interpret_for("pallas")
-
 
 # ---------------------------------------------------------------------------
 # Streaming cross-batch encode queue (r12)

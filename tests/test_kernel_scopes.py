@@ -155,22 +155,20 @@ PROGRAMS = {
             _tiles(), ROWS, ROW_BYTES, 2, "up"))),
     "_fused_filter_deflate[rle]": ({FILTER, TOKENS, PACK, FRAME}, lambda: (
         dd._fused_filter_deflate.lower(
-            _tiles(), ROWS, ROW_BYTES, 2, "up", "rle", "scan", False))),
+            _tiles(), ROWS, ROW_BYTES, 2, "up", "rle"))),
     "_fused_filter_deflate_donated[rle]": (
         {FILTER, TOKENS, PACK, FRAME}, lambda: (
             dd._fused_filter_deflate_donated.lower(
-                _tiles(), ROWS, ROW_BYTES, 2, "up", "rle", "scan", False))),
+                _tiles(), ROWS, ROW_BYTES, 2, "up", "rle"))),
     "_fused_filter_deflate[stored]": ({FILTER, FRAME}, lambda: (
         dd._fused_filter_deflate.lower(
-            _tiles(), ROWS, ROW_BYTES, 2, "up", "stored", "scan", False))),
+            _tiles(), ROWS, ROW_BYTES, 2, "up", "stored"))),
     "_zlib_dynamic": ({TOKENS, PACK, FRAME}, lambda: (
-        dd._zlib_dynamic.lower(
-            _flat(), *_tables(), packer="scan", interpret=False))),
+        dd._zlib_dynamic.lower(_flat(), *_tables()))),
     "_zlib_rle": ({TOKENS, PACK, FRAME}, lambda: (
-        dd._zlib_rle.lower(_flat(), "scan", False))),
+        dd._zlib_rle.lower(_flat()))),
     "_filtered_to_streams[rle]": ({FILTER, TOKENS, PACK, FRAME}, lambda: (
-        dd._filtered_to_streams.lower(
-            _rows(), ROWS, ROW_BYTES, "rle", "scan", False))),
+        dd._filtered_to_streams.lower(_rows(), ROWS, ROW_BYTES, "rle"))),
     "_filtered_to_flat": ({FILTER}, lambda: (
         dd._filtered_to_flat.lower(_rows(), ROWS, ROW_BYTES))),
     "_zlib_stored": ({FRAME}, lambda: dd._zlib_stored.lower(_flat())),

@@ -621,10 +621,6 @@ _JAX_JIT_SCOPE = _JAX_SYNC_SCOPE + (
     "omero_ms_pixel_buffer_tpu/parallel/",
     "omero_ms_pixel_buffer_tpu/io/jpeg.py",
 )
-_JAX_ALLOWLIST = (
-    "omero_ms_pixel_buffer_tpu/runtime/microbench.py",
-)
-
 # calls whose results live on the device
 _DEVICE_PRODUCER_BASES = {"jnp", "jax", "lax"}
 _DEVICE_PRODUCER_NAMES = {
@@ -902,14 +898,14 @@ def check_jax_hotpath(
 
     sync_fns: List[FunctionInfo] = []
     for sf in project.files:
-        if sf.tree is None or sf.path in _JAX_ALLOWLIST:
+        if sf.tree is None:
             continue
         if project.in_scope(sf, "jax-hotpath", _JAX_SYNC_SCOPE):
             sync_fns.extend(indexes[sf.path].functions)
     seeds, device_returns = _device_param_lattice(graph, sync_fns)
 
     for sf in project.files:
-        if sf.tree is None or sf.path in _JAX_ALLOWLIST:
+        if sf.tree is None:
             continue
         in_sync_scope = project.in_scope(sf, "jax-hotpath", _JAX_SYNC_SCOPE)
         in_jit_scope = project.in_scope(sf, "jax-hotpath", _JAX_JIT_SCOPE)
@@ -926,8 +922,8 @@ def check_jax_hotpath(
                             f"block_until_ready in '{fn.name}' "
                             "stalls the host on device completion — "
                             "serving code should stay async to the "
-                            "device (benchmarks belong in "
-                            "runtime/microbench.py)",
+                            "device (measurements belong in "
+                            "benchmarks/)",
                         ))
 
                 def extra_producer(call_node, _fn=fn):

@@ -1,8 +1,7 @@
 #!/bin/sh
 # What a builder sends through the chip tool (one process on the chip
 # at a time; everything that shares a compile goes into one call):
-#   tools/chip_calls.sh smoke   cold run, then warm-cache run, then the
-#                               OMPB_BITPACK=pallas refusal (must fail)
+#   tools/chip_calls.sh smoke   cold run, then warm-cache run
 #   tools/chip_calls.sh mesh    chip_smoke.py --mesh (four chips)
 # Output too long for the tool's tail lands in chiprun_out/.
 set -u
@@ -19,8 +18,6 @@ case "${1:-smoke}" in
 smoke)
     run smoke_cold python3 chip_smoke.py
     run smoke_warm python3 chip_smoke.py
-    run smoke_pallas env OMPB_BITPACK=pallas python3 chip_smoke.py
-    grep -h "Error" chiprun_out/smoke_pallas.out | sort | uniq -c
     ;;
 mesh)
     run smoke_mesh python3 chip_smoke.py --mesh
