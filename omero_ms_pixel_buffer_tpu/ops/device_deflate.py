@@ -13,7 +13,9 @@ built on device** with static shapes, in two modes:
   instead of a serial scan; every position gets ONE token index
   (0-255 a literal, 256 + L a match of length L, 515 none) and looks
   up one packed ``bits | nbits << 20`` table of the fixed-Huffman
-  code with it, one gather a position; token bit offsets are
+  code with it, not by a gather (one element at a time on the chip)
+  but densely: the table's byte columns times the one-hot of the index
+  over the 516 kinds, on the MXU; token bit offsets are
   an exclusive cumsum; and the bitstream is packed by the **carry-free
   prefix-sum packer** (``_pack_bits_scan``): because tokens occupy
   disjoint bit ranges, the sum of their word-aligned contributions has
@@ -35,17 +37,18 @@ built on device** with static shapes, in two modes:
   dynamic-Huffman encode. Pass 1 runs ON DEVICE fused with the PNG
   filter (``fused_filter_histogram_batch``): the same Z_RLE run
   decomposition, but instead of emitting code bits it histograms the
-  token indices per lane (one scatter-add into 516 bins, no table
-  looked up a position) and folds the 259 per-length bins into the 29
-  length symbols and the match extra-bits by constant maps — only
-  ``(B, 286)`` counts cross the link.
+  token indices per lane (a dense count: the index in two digits, the
+  516 bins as the product of the digits' one-hots over the positions;
+  nothing scattered, no table looked up a position) and folds the 259
+  per-length bins into the 29 length symbols and the match extra-bits
+  by constant maps — only ``(B, 286)`` counts cross the link.
   The HOST then builds per-lane length-limited (15) canonical Huffman
   codes from the counts (heap build + frequency damping, the same
   algorithm as native/fast_deflate.cc), the RFC 1951 §3.2.7 dynamic
   block header (code-length tree, CL 16/17/18 run coding) as a
   zero-padded token array, and per-lane code TABLES. Pass 2 re-runs
   the decomposition on device, packs each lane's four tables into one
-  token table there and emits through it, again one gather a
+  token table there and emits through it, again one dense lookup a
   position — header tokens ++ body tokens ++ explicit EOB — into the
   same carry-free packer. Per lane the host picks min(dynamic, fixed)
   analytically from the counts BEFORE emitting (a fixed-winning lane
@@ -299,6 +302,30 @@ def _run_decompose(payload: jax.Array):
 _NO_TOKEN = 256 + _MAX_MATCH + 1
 _TOKEN_KINDS = _NO_TOKEN + 1
 
+# An indexed operation over the 516 kinds is a contraction with the
+# one-hot of the token index: the chip runs a scatter-add or a gather
+# one element at a time (5-10 ns each whatever the table, half of its
+# busy time at PR 30) and a compare against every kind as dense work on
+# the vector unit and the MXU. 0 / 1 and a byte are exact in bfloat16,
+# one product a position is non-zero, and float32 sums stay exact below
+# 2^24, so both contractions are integer-exact. A payload is cut into
+# chunks whose tail is padded with _NO_TOKEN (a zero table entry, a bin
+# that is thrown away); the chip's compiler fuses the compare into the
+# product, so no one-hot reaches HBM.
+_COUNT_CHUNK = 1 << 16  # positions a product of the count: < 2^24
+_LOOKUP_CHUNK = 1 << 15  # positions an iteration of the lookup holds
+_KIND_LO = 32  # the count's two digits: tok = 32 * hi + lo
+_KIND_HI = -(-_TOKEN_KINDS // _KIND_LO)
+
+
+def _token_chunks(tok: jax.Array, chunk: int) -> jax.Array:
+    """(L,) token indices -> (ceil(L / chunk), chunk), the tail padded
+    with ``_NO_TOKEN``."""
+    pad = (-tok.shape[0]) % chunk
+    return jnp.pad(tok, (0, pad), constant_values=_NO_TOKEN).reshape(
+        -1, chunk
+    )
+
 
 @kernel("ompb_tokens")
 def _token_index(payload: jax.Array) -> jax.Array:
@@ -342,10 +369,26 @@ def _token_table(lit_b, lit_n, ml_b, ml_n) -> jax.Array:
 @kernel("ompb_tokens")
 def _coded_tokens(tok: jax.Array, table: jax.Array):
     """Per-position (bits, nbits) of the token indices ``tok`` under
-    ``table``: ONE gather a position, whatever the token's kind.
-    (The index is in 0..515 by construction; promising so buys nothing
-    on the v5e: 4.49 against 4.52 ms a lane, PERF.md PR 29.)"""
-    g = table[tok]
+    ``table``, as a dense lookup: the table's four byte columns (they
+    carry all 32 bits of ``bits | nbits << 20``) times the one-hot of
+    ``tok`` over the 516 kinds, on the MXU, the uint32 put together
+    after; ``_LOOKUP_CHUNK`` positions an iteration, so the byte rows
+    of one chunk are all it holds whatever the payload's length."""
+    n = tok.shape[0]
+    kinds = jnp.arange(_TOKEN_KINDS, dtype=jnp.int32)
+    shifts = jnp.arange(4, dtype=jnp.uint32) * 8
+    columns = ((table[None, :] >> shifts[:, None]) & 0xFF).astype(
+        jnp.bfloat16
+    )  # (4, 516)
+
+    def lookup(chunk):
+        one_hot = (chunk[None, :] == kinds[:, None]).astype(jnp.bfloat16)
+        b = jnp.dot(
+            columns, one_hot, preferred_element_type=jnp.float32
+        ).astype(jnp.uint32)  # (4, chunk): exact bytes
+        return b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
+
+    g = lax.map(lookup, _token_chunks(tok, _LOOKUP_CHUNK)).reshape(-1)[:n]
     bits = g & jnp.uint32((1 << _TOKEN_VALUE_BITS) - 1)
     return bits, (g >> _TOKEN_VALUE_BITS).astype(jnp.int32)
 
@@ -603,12 +646,28 @@ _MLEN_FOLD[np.arange(3, _MAX_MATCH + 1), _MLEN_SYM[3:] - 256] = 1
 
 @kernel("ompb_hist")
 def _symbol_counts(tok: jax.Array):
-    """The histogram half of pass 1: one scatter-add of the token
-    indices into their 516 raw bins, no table looked up a position.
-    Literal bins are symbol counts as they stand; the 259 per-length
-    bins fold into the 29 length symbols, and weigh into the match
-    extra bits, by constant maps (EOB is not a payload token: 0)."""
-    raw = jnp.zeros(_TOKEN_KINDS, jnp.int32).at[tok].add(1)
+    """The histogram half of pass 1 as a dense count: the token index
+    in two digits, ``tok = 32 * hi + lo``, and the (17, 32) table of
+    raw bins as the product ``one_hot(hi) . one_hot(lo)^T`` over the
+    positions, on the MXU (float32 a chunk, int32 across chunks); no
+    table looked up a position, nothing scattered. Literal bins are
+    symbol counts as they stand; the 259 per-length bins fold into the
+    29 length symbols, and weigh into the match extra bits, by
+    constant maps (EOB is not a payload token: 0)."""
+    chunks = _token_chunks(tok, _COUNT_CHUNK)
+
+    def one_hot(digit, kinds):
+        return (
+            digit[:, None, :] == jnp.arange(kinds, dtype=jnp.int32)[:, None]
+        ).astype(jnp.bfloat16)  # (chunks, kinds, chunk)
+
+    part = lax.dot_general(
+        one_hot(chunks // _KIND_LO, _KIND_HI),
+        one_hot(chunks % _KIND_LO, _KIND_LO),
+        (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )  # (chunks, 17, 32)
+    raw = part.astype(jnp.int32).sum(axis=0).reshape(-1)
     by_len = raw[256:_NO_TOKEN]
     counts = jnp.concatenate([raw[:256], by_len @ jnp.asarray(_MLEN_FOLD)])
     return counts, by_len @ jnp.asarray(_MLEN_EXTRA)
@@ -840,7 +899,7 @@ def build_dynamic_tables(
 @kernel("ompb_tokens")
 def _dyn_lane_tokens(payload, lit_b, lit_n, ml_b, ml_n):
     """Pass-2 body tokens for one lane through ITS code tables, packed
-    on the device into one token table: one gather a position."""
+    on the device into one token table: one lookup a position."""
     return _coded_tokens(
         _token_index(payload), _token_table(lit_b, lit_n, ml_b, ml_n)
     )

@@ -578,6 +578,109 @@ class TestTokenIndex:
         assert bits.dtype == np.uint32 and nbits.dtype == np.int32
 
 
+def _token_cases():
+    """case -> a function giving (L,) int32 token indices: run-heavy,
+    noisy and constant payloads through ``_token_index``, the shortest
+    payloads, a length one short of and one past each chunk edge of
+    the dense forms, and stretches that hold nothing but
+    ``_NO_TOKEN``."""
+    from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+    kinds = dd._TOKEN_KINDS
+
+    def random(n):
+        return lambda: np.random.default_rng(n).integers(0, kinds, n)
+
+    cases = {f"random L={n}": random(n) for n in (1, 5, 17)}
+    for edge in sorted({dd._COUNT_CHUNK, dd._LOOKUP_CHUNK}):
+        for n in (edge - 1, edge + 1):
+            cases[f"chunk edge L={n}"] = random(n)
+    cases["a no-token stretch"] = lambda: np.concatenate([
+        random(300)(), np.full(2000, dd._NO_TOKEN), random(301)(),
+    ])
+    cases["no token at all"] = lambda: np.full(777, dd._NO_TOKEN)
+    for lane, name in enumerate(
+        ("zeros", "noise", "runs of 20", "alternating", "constant")
+    ):
+        cases[f"family {name}"] = lambda lane=lane: dd._token_index(
+            _payload_families(1500)[lane]
+        )
+    return cases
+
+
+_TOKEN_CASES = _token_cases()
+
+
+def _token_lanes(case: str, lanes: int) -> np.ndarray:
+    """``lanes`` different lanes of a case: each rolled a little."""
+    tok = np.asarray(_TOKEN_CASES[case](), np.int32)
+    return np.stack([np.roll(tok, 7 * lane) for lane in range(lanes)])
+
+
+class TestDenseKinds:
+    """The count and the lookup over the 516 token kinds are
+    contractions with a one-hot (PR 31), not a scatter-add and a
+    gather: the same numbers as numpy's, at every length around a
+    chunk edge, under vmap and called outside any jit."""
+
+    @pytest.mark.parametrize("lanes", [1, 2, 4])
+    @pytest.mark.parametrize("case", sorted(_TOKEN_CASES))
+    def test_the_count_is_numpys_bincount(self, case, lanes):
+        import jax
+
+        from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+        tok = _token_lanes(case, lanes)
+        counts, extras = (
+            np.asarray(a) for a in jax.vmap(dd._symbol_counts)(tok)
+        )
+        assert counts.shape == (lanes, 286) and counts.dtype == np.int32
+        for lane in range(lanes):
+            raw = np.bincount(tok[lane], minlength=dd._TOKEN_KINDS)
+            by_len = raw[256 : dd._NO_TOKEN]
+            np.testing.assert_array_equal(
+                counts[lane],
+                np.concatenate([raw[:256], by_len @ dd._MLEN_FOLD]),
+            )
+            assert extras[lane] == by_len @ dd._MLEN_EXTRA
+        alone, alone_extra = dd._symbol_counts(tok[0])  # outside any jit
+        np.testing.assert_array_equal(np.asarray(alone), counts[0])
+        assert int(alone_extra) == extras[0]
+
+    @pytest.mark.parametrize("lanes", [1, 2, 4])
+    @pytest.mark.parametrize("case", sorted(_TOKEN_CASES))
+    def test_the_lookup_is_numpys_take(self, case, lanes):
+        """Each lane under its own table, whose entries reach the top
+        of both fields: 20-bit values and counts up to 31 (bits 0-24
+        of the packed word all set in entry 0 and in ``_NO_TOKEN - 1``)."""
+        import jax
+
+        from omero_ms_pixel_buffer_tpu.ops import device_deflate as dd
+
+        tok = _token_lanes(case, lanes)
+        r = np.random.default_rng(len(case) + lanes)
+        table = r.integers(0, 1 << 25, (lanes, dd._TOKEN_KINDS)).astype(
+            np.uint32
+        )
+        table[:, [0, dd._NO_TOKEN - 1]] = (1 << 25) - 1
+        table[:, dd._NO_TOKEN] = 0
+        bits, nbits = (
+            np.asarray(a) for a in jax.vmap(dd._coded_tokens)(tok, table)
+        )
+        assert bits.dtype == np.uint32 and nbits.dtype == np.int32
+        for lane in range(lanes):
+            want = table[lane][tok[lane]]
+            np.testing.assert_array_equal(
+                bits[lane], want & ((1 << dd._TOKEN_VALUE_BITS) - 1)
+            )
+            np.testing.assert_array_equal(
+                nbits[lane], want >> dd._TOKEN_VALUE_BITS
+            )
+        alone_b, alone_n = dd._coded_tokens(tok[0], table[0])
+        np.testing.assert_array_equal(np.asarray(alone_b), bits[0])
+        np.testing.assert_array_equal(np.asarray(alone_n), nbits[0])
+
+
 class TestCellShape:
     """The benchmark cell's shape, 1 and 2 lanes: the fixed-Huffman
     stream is the numpy twin's byte for byte (the twin still finds its
