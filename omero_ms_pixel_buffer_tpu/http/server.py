@@ -734,6 +734,9 @@ class PixelBufferApp:
             # mesh; `supertile.mesh: false` is the escape hatch back
             # to the per-lane sharded preference
             supertile_mesh=config.supertile.mesh,
+            # the lane counts a chip compiles before its first HBM
+            # plane counts as resident (hosts with several chips)
+            max_batch=batching.max_batch,
         )
         if config.render.enabled:
             # build the LUT registry NOW (directory scan + file reads,
@@ -979,6 +982,10 @@ class PixelBufferApp:
         # opens: `device` fails start-up when it finds no chip, `auto`
         # measures the link once and says what it chose (/healthz)
         self._engine_info = self.pipeline.resolve_engine()
+        # a chip's share of backend.plane-cache-mb above the chip's
+        # memory is said now (log, /healthz cache.device_planes.error),
+        # not by the first staging that does not fit
+        self.pipeline.check_plane_budget()
 
     def make_app(self) -> web.Application:
         middlewares = [
@@ -1390,6 +1397,8 @@ class PixelBufferApp:
             any(b["state"] == "open" for b in breakers.values())
             or admission["inflight"] >= admission["max_inflight"]
             or loop_health.get("blocked", False)
+            # a plane budget the chips cannot hold (start-up check)
+            or bool((planes or {}).get("error"))
         )
         obs_health = (
             self.recorder.snapshot()

@@ -37,6 +37,26 @@ histogram, one program), the readback worker pulls the (B, 286) counts
 launches pass 2 (emit), and blocks on it; other groups' passes
 interleave on device between the two.
 
+On a host with several chips a group whose input already lives on one
+of them (a crop of an HBM-resident plane, ``submit(..., device=)``)
+runs on that chip: its programs follow its data. What orders and
+bounds such groups is one more pipe, as wide as there are chips: that
+many submit threads, that many times ``queue_depth`` slots and that
+many readback workers behind ONE queue, so a group that blocks on
+chip 0's pass 2 keeps no other chip from being launched. The width is
+the host's and no worker is a chip's own: the groups are taken up in
+the order they came, whatever their chip. (A pipe a chip was built
+first and measured on the v5e host, PERF.md §6 PR 33: a plane's chip
+is fixed, so a closed loop of viewers piles its requests up behind
+whichever pipe the host happens to serve slowest, and the latencies
+spread like a random-order queue's: p50 340 ms, p95 1236. The chips
+idle 70% of the time; it is the host's work that has to be handed out
+in order.)
+The process keeps what is one whatever the chip count: the group ids,
+the pull-size guesses, the counters, and the pipe of groups that name
+no device (host-staged and mesh groups, and every group of a one-chip
+host), which is the queue as it always was.
+
 Failure contract (unchanged from r9, now chaos-pinned): any failure in
 staging, dispatch, or readback resolves THAT group's future with the
 exception — the pipeline degrades those lanes to the host encoder —
@@ -94,15 +114,19 @@ def _observe_wait(duration: float, where: str) -> None:
     DEVICE_QUEUE_WAIT_SECONDS.observe(duration, where=where)
 
 
-def _annotation(what: str, gid: int, lanes: int):
+def _annotation(what: str, gid: int, lanes: int, chip=None):
     """``jax.profiler.TraceAnnotation`` ``ompb.queue.<what>``, started
     here and ended by its ``__exit__`` (on any thread). It lands on the
     host plane of the profiler's trace, on the clock of the device's
     operations, so a device idle gap names the stage of the group the
-    host was in; with no profiler running it is an atomic flag test."""
+    host was in; with no profiler running it is an atomic flag test.
+    A group sent to a named chip carries the stat ``chip``."""
     from jax.profiler import TraceAnnotation
 
-    return TraceAnnotation(f"ompb.queue.{what}", group=gid, lanes=lanes)
+    stats = {"group": gid, "lanes": lanes}
+    if chip is not None:
+        stats["chip"] = chip
+    return TraceAnnotation(f"ompb.queue.{what}", **stats)
 
 
 class _Span:
@@ -118,11 +142,13 @@ class _Span:
     __slots__ = ("gid", "lanes", "t0", "t1", "_observe", "_note")
 
     def __init__(self, observe, what: Optional[str] = None,
-                 gid: int = 0, lanes: int = 0):
+                 gid: int = 0, lanes: int = 0, chip=None):
         self.gid, self.lanes = gid, lanes
         self._observe = observe
         # no `what`: the histogram alone (a wait)
-        self._note = None if what is None else _annotation(what, gid, lanes)
+        self._note = (
+            None if what is None else _annotation(what, gid, lanes, chip)
+        )
         self.t0 = time.perf_counter()
         self.t1: Optional[float] = None
 
@@ -194,6 +220,44 @@ def _mesh_padded_lanes(b: int, width: int) -> int:
     return -(-_pow2_lanes(b) // width) * width
 
 
+class _Pipe:
+    """What orders and bounds a stream of groups: submit threads
+    (groups stage + launch in the order they came, across batches),
+    readback workers (taken up in submission order; with one worker
+    group k's D2H never competes with group k+1's: the pipe stays a
+    pipe) and ``queue_depth`` in-flight slots a worker. The process's
+    pipe has one worker of each kind; the pipe of the groups that name
+    their chip has one a chip."""
+
+    __slots__ = (
+        "workers", "submit_pool", "readback", "slots", "inflight",
+        "last_compute_done",
+    )
+
+    def __init__(self, tag: str, queue_depth: int, workers: int = 1):
+        self.workers = workers
+        self.submit_pool = concurrent.futures.ThreadPoolExecutor(
+            max_workers=workers, thread_name_prefix=f"devenc-submit{tag}"
+        )
+        self.readback = concurrent.futures.ThreadPoolExecutor(
+            max_workers=workers, thread_name_prefix=f"devenc-readback{tag}"
+        )
+        self.slots = threading.Semaphore(queue_depth * workers)
+        self.inflight = 0
+        self.last_compute_done: Optional[float] = None
+
+
+class _Route:
+    """Where one group runs: the pipe that orders it and, where it
+    names one, the id of the chip its arrays live on (the stat `chip`
+    of its annotations)."""
+
+    __slots__ = ("pipe", "chip")
+
+    def __init__(self, pipe: _Pipe, chip=None):
+        self.pipe, self.chip = pipe, chip
+
+
 class DeviceEncodeDispatcher:
     """Submit encode groups into the persistent queue; collect
     per-group futures.
@@ -210,21 +274,20 @@ class DeviceEncodeDispatcher:
         dd_cap: Dict[Tuple[int, int], int],
         mesh_manager=None,
         queue_depth: int = 2,
+        chips: int = 1,
     ):
         self._dd_cap = dd_cap
         self.mesh_manager = mesh_manager
         self.queue_depth = max(1, int(queue_depth))
-        # ONE submit thread: groups stage + launch in FIFO order across
-        # batches; ONE readback worker: readback order == submission
-        # order, so group k's D2H never competes with group k+1's (the
-        # pipe stays a pipe)
-        self._submit_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="devenc-submit"
-        )
-        self._readback = concurrent.futures.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="devenc-readback"
-        )
-        self._slots = threading.Semaphore(self.queue_depth)
+        # the process's pipe carries every group that names no chip;
+        # those that do share one more, `chips` workers wide, built
+        # when the first such group arrives
+        self._pipes_lock = threading.Lock()
+        self._pipe = _Pipe("", self.queue_depth)
+        self._chip_pipe: Optional[_Pipe] = None
+        self._chips = max(1, int(chips))
+        self._chip_groups: Dict[int, int] = {}  # chip id -> groups
+        self._readback = self._pipe.readback  # the mesh groups'
         # per-dispatcher group ids: every stage annotation, the waits
         # and the submitting request's flight record carry one
         self._gids = itertools.count(1)
@@ -247,7 +310,6 @@ class DeviceEncodeDispatcher:
         self._overlapped = 0
         self._compute_sum = 0.0
         self._computes = 0
-        self._last_compute_done: Optional[float] = None
         # mesh warmup state: recently-seen raw-tile group shapes +
         # widths already warmed (tests read _warmed)
         self._seen_mesh: Dict[tuple, None] = {}
@@ -269,7 +331,12 @@ class DeviceEncodeDispatcher:
         host-fall-back) and the stuck worker threads are abandoned.
         Idempotent; TilePipeline.close() calls it."""
         self._closed = True
-        self._submit_pool.shutdown(wait=False)
+        with self._pipes_lock:
+            pipes = [self._pipe]
+            if self._chip_pipe is not None:
+                pipes.append(self._chip_pipe)
+        for pipe in pipes:
+            pipe.submit_pool.shutdown(wait=False)
         with self._pending_lock:
             pending = list(self._pending)
         _, not_done = concurrent.futures.wait(
@@ -282,7 +349,8 @@ class DeviceEncodeDispatcher:
                 )
             except concurrent.futures.InvalidStateError:
                 pass  # resolved in the race window: nothing to do
-        self._readback.shutdown(wait=not not_done)
+        for pipe in pipes:
+            pipe.readback.shutdown(wait=not not_done)
         if not_done:
             log.warning(
                 "device encode queue: %d group(s) unresolved after "
@@ -303,13 +371,25 @@ class DeviceEncodeDispatcher:
     # -- queue telemetry ------------------------------------------------
 
     @staticmethod
-    def _stage(stage: str, gid: int, lanes: int) -> _Span:
+    def _stage(stage: str, gid: int, lanes: int, chip=None) -> _Span:
         """``with self._stage("hist", gid, n):`` observes
         ``device_stage_seconds{stage=...}`` and holds the annotation
         ``ompb.queue.<stage>`` over the same interval."""
         return _Span(
-            lambda dt: _observe_stage(dt, stage), stage, gid, lanes
+            lambda dt: _observe_stage(dt, stage), stage, gid, lanes, chip
         )
+
+    def _route_for(self, device) -> _Route:
+        """The process's pipe for a group that names no chip, else the
+        pipe of those that do."""
+        if device is None:
+            return _Route(self._pipe)
+        with self._pipes_lock:
+            if self._chip_pipe is None:
+                self._chip_pipe = _Pipe(
+                    "-chips", self.queue_depth, workers=self._chips
+                )
+            return _Route(self._chip_pipe, device.id)
 
     @staticmethod
     def _wait(where: str) -> _Span:
@@ -323,16 +403,23 @@ class DeviceEncodeDispatcher:
         that was running."""
         return _Span(lambda dt: _observe_wait(dt, where))
 
-    def _note_launch(self, t_launch: float, lanes: int) -> None:
+    def _note_launch(
+        self, t_launch: float, lanes: int, route: Optional[_Route] = None
+    ) -> None:
         """Called as a group's device program is dispatched: counts
         the group and its real lanes, samples occupancy and classifies
-        the launch as overlapped (the device was still computing the
-        previous group) or post-idle-gap."""
+        the launch as overlapped (its pipe's previous group was still
+        computing) or post-idle-gap."""
+        pipe = self._pipe if route is None else route.pipe
         DEVICE_GROUP_LANES.observe(lanes)
         with self._stats_lock:
             self._groups += 1
+            if route is not None and route.chip is not None:
+                self._chip_groups[route.chip] = (
+                    self._chip_groups.get(route.chip, 0) + 1
+                )
             self._occupancy_sum += self._inflight
-            last = self._last_compute_done
+            last = pipe.last_compute_done
             if last is None:
                 return
             gap = t_launch - last
@@ -345,9 +432,12 @@ class DeviceEncodeDispatcher:
                 self._idle_gap_max = max(self._idle_gap_max, gap)
                 DEVICE_QUEUE_IDLE_SECONDS.observe(gap)
 
-    def _note_compute_done(self, t_done: float, dt: float) -> None:
+    def _note_compute_done(
+        self, t_done: float, dt: float, route: Optional[_Route] = None
+    ) -> None:
         with self._stats_lock:
-            self._last_compute_done = t_done
+            pipe = self._pipe if route is None else route.pipe
+            pipe.last_compute_done = t_done
             self._compute_sum += dt
             self._computes += 1
 
@@ -385,6 +475,15 @@ class DeviceEncodeDispatcher:
                     if self._computes else None
                 ),
             }
+            if self._chip_pipe is not None:  # groups name their chip
+                out["chips"] = [
+                    {"chip": chip, "groups": n}
+                    for chip, n in sorted(self._chip_groups.items())
+                ]
+                out["chip_pipe"] = {
+                    "workers": self._chip_pipe.workers,
+                    "inflight": self._chip_pipe.inflight,
+                }
         return out
 
     # -- submission -----------------------------------------------------
@@ -402,13 +501,17 @@ class DeviceEncodeDispatcher:
         bit_depth: int,
         color_type: int,
         staged: bool = False,
+        device=None,
     ) -> "concurrent.futures.Future":
         """Enqueue one encode group; returns a Future resolving to
         {lane_index: png_bytes}. ``tiles`` is either a host ndarray
         (bucket path — staged H2D on the submit thread) or an already
         device-resident batch the caller gives up (plane-cache crops,
         ``staged=True``: donated to the program like a host-staged one;
-        its lane axis may be padded beyond ``lanes``).
+        its lane axis may be padded beyond ``lanes``). ``device`` names
+        the chip a staged batch lives on where the host has several:
+        the group runs through the pipe of such groups (None: the
+        process's pipe).
         All lanes in a group share one real (w, h) — ``rows``/
         ``row_bytes`` describe it — but ``sizes`` still rides along
         for framing. Returns immediately: staging happens on the
@@ -417,6 +520,7 @@ class DeviceEncodeDispatcher:
             self._stage_group,
             tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
             lanes, sizes, bit_depth, color_type, staged,
+            route=self._route_for(device),
         )
 
     def submit_render(
@@ -453,9 +557,12 @@ class DeviceEncodeDispatcher:
             filter_mode, deflate_mode, lanes, sizes, mask, staged,
         )
 
-    def _enqueue(self, stage_fn, *args) -> "concurrent.futures.Future":
+    def _enqueue(
+        self, stage_fn, *args, route: Optional[_Route] = None
+    ) -> "concurrent.futures.Future":
         if self._closed:
             raise RuntimeError("device encode queue is closed")
+        route = route or self._route_for(None)
         fut: "concurrent.futures.Future" = concurrent.futures.Future()
         with self._pending_lock:
             self._pending.add(fut)
@@ -470,8 +577,8 @@ class DeviceEncodeDispatcher:
         # the wait for the submit thread starts here and ends there
         pool = self._wait("pool")
         try:
-            self._submit_pool.submit(
-                self._run_stage, stage_fn, fut, args, rec, gid, pool
+            route.pipe.submit_pool.submit(
+                self._run_stage, stage_fn, fut, args, rec, gid, pool, route
             )
         except RuntimeError as e:
             # close() raced the _closed check and shut the pool down:
@@ -511,7 +618,8 @@ class DeviceEncodeDispatcher:
             pass
 
     def _run_stage(
-        self, stage_fn, fut, args, rec, gid: int, pool: _Span
+        self, stage_fn, fut, args, rec, gid: int, pool: _Span,
+        route: _Route,
     ) -> None:
         """Submit-thread trampoline: acquire an in-flight slot, stage +
         launch, chain the readback future into the caller's. Any
@@ -523,6 +631,7 @@ class DeviceEncodeDispatcher:
         from ..resilience.faultinject import INJECTOR
 
         pool.end()
+        pipe = route.pipe
         acquired = False
         try:
             INJECTOR.fire("device.encode-group")
@@ -530,30 +639,32 @@ class DeviceEncodeDispatcher:
             # submit thread), keeping callers non-blocking and the
             # device at most queue_depth groups ahead of readback
             with self._wait("slot"):
-                self._slots.acquire()
+                pipe.slots.acquire()
             acquired = True
             with self._stats_lock:
                 self._inflight += 1
+                pipe.inflight += 1
             with record_scope(rec):
-                rfut = stage_fn(gid, *args)
+                rfut = stage_fn(gid, *args, route)
         except Exception as e:
             # resolve the caller's future instead of raising into the
             # executor: the pipeline host-falls-back this group
             if acquired:
-                self._release_slot()
+                self._release_slot(pipe)
             self._resolve_exc(fut, e)
             return
         rfut.add_done_callback(
-            lambda rf: self._finish_group(fut, rf)
+            lambda rf: self._finish_group(fut, rf, pipe)
         )
 
-    def _release_slot(self) -> None:
+    def _release_slot(self, pipe: _Pipe) -> None:
         with self._stats_lock:
             self._inflight -= 1
-        self._slots.release()
+            pipe.inflight -= 1
+        pipe.slots.release()
 
-    def _finish_group(self, fut, rfut) -> None:
-        self._release_slot()
+    def _finish_group(self, fut, rfut, pipe: _Pipe) -> None:
+        self._release_slot(pipe)
         exc = rfut.exception()
         if exc is not None:
             self._resolve_exc(fut, exc)
@@ -567,11 +678,12 @@ class DeviceEncodeDispatcher:
 
     def _stage_group(
         self, gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
-        lanes, sizes, bit_depth, color_type, staged,
+        lanes, sizes, bit_depth, color_type, staged, route,
     ):
         import jax
 
         n = len(lanes)
+        pipe, chip = route.pipe, route.chip
         mesh_mgr = self.mesh_manager
         if mesh_mgr is not None and not staged:
             # sharded groups run ENTIRELY on the readback worker: the
@@ -599,7 +711,7 @@ class DeviceEncodeDispatcher:
                 gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
                 lanes, sizes, bit_depth, color_type,
             )
-        with self._stage("h2d", gid, n):
+        with self._stage("h2d", gid, n, chip):
             if staged:
                 batch_dev = tiles
             else:
@@ -616,15 +728,15 @@ class DeviceEncodeDispatcher:
             )
             # the stage starts at the launch, here; the readback
             # worker ends it when it has pulled the counts
-            hist = self._stage("hist", gid, n)
-            self._note_launch(hist.t0, len(lanes))
+            hist = self._stage("hist", gid, n, chip)
+            self._note_launch(hist.t0, len(lanes), route)
             # a plane-cache group arrives with its lane axis already
             # padded: the host plans only the real lanes, as it does
             # for a group it padded itself
-            return self._readback.submit(
+            return pipe.readback.submit(
                 self._tid_bound(self._dynamic_readback_group),
                 flat, counts, extras, min(real_b, n), hist, lanes, sizes,
-                bit_depth, color_type,
+                bit_depth, color_type, route,
             )
         from ..ops.device_deflate import fused_filter_deflate_batch
 
@@ -633,18 +745,18 @@ class DeviceEncodeDispatcher:
             filter_mode=filter_mode, mode=deflate_mode,
             donate=self._donate_ok(),
         )
-        compute = self._stage("compute", gid, n)  # launch -> ready
-        self._note_launch(compute.t0, len(lanes))
-        return self._readback.submit(
+        compute = self._stage("compute", gid, n, chip)  # launch -> ready
+        self._note_launch(compute.t0, len(lanes), route)
+        return pipe.readback.submit(
             self._tid_bound(self._readback_group),
             streams, lengths, compute, lanes, sizes,
-            bit_depth, color_type,
+            bit_depth, color_type, route,
         )
 
     def _stage_render_group(
         self, gid, planes, index_tables, color_luts, rows, row_bytes,
         filter_mode, deflate_mode, lanes, sizes, mask=None,
-        staged=False,
+        staged=False, route=None,
     ):
         import jax
 
@@ -920,7 +1032,7 @@ class DeviceEncodeDispatcher:
 
     def _stage_supertile_group(
         self, gid, stack, index_tables, color_luts, rel_rects,
-        tile_w, tile_h, filter_mode, deflate_mode, lanes,
+        tile_w, tile_h, filter_mode, deflate_mode, lanes, route=None,
     ):
         # mesh-only entry point (the pipeline routes single-device
         # groups through composite_carve_batch + submit instead);
@@ -1114,7 +1226,7 @@ class DeviceEncodeDispatcher:
 
     def _dynamic_readback_group(
         self, flat, counts, extras, real_b, hist: _Span, lanes, sizes,
-        bit_depth, color_type,
+        bit_depth, color_type, route: Optional[_Route] = None,
     ) -> Dict[int, bytes]:
         """Dynamic mode pass 2 on the readback worker: pull the pass-1
         counts (absorbing the histogram program's wait), build the
@@ -1126,22 +1238,23 @@ class DeviceEncodeDispatcher:
         from ..ops.device_deflate import dynamic_emit_batch
 
         gid = hist.gid
+        chip = None if route is None else route.chip
         with hist:  # started at the launch, on the submit thread
             counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion (pass-1 counts, a few KB)
-        with self._stage("emit", gid, hist.lanes) as emit:
+        with self._stage("emit", gid, hist.lanes, chip) as emit:
             streams, lengths = dynamic_emit_batch(
                 flat, counts_np, extras_np, real=real_b,
             )
             jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        self._note_compute_done(emit.t1, emit.t1 - hist.t0)
+        self._note_compute_done(emit.t1, emit.t1 - hist.t0, route)
         return self._pull_and_frame(
             streams, lengths, gid, lanes, sizes, bit_depth,
-            color_type,
+            color_type, chip,
         )
 
     def _readback_group(
         self, streams, lengths, compute: _Span, lanes, sizes,
-        bit_depth, color_type,
+        bit_depth, color_type, route: Optional[_Route] = None,
     ) -> Dict[int, bytes]:
         """Runs on the readback worker: wait for the device, pull the
         compressed bytes in ONE sync, frame the PNGs."""
@@ -1152,53 +1265,74 @@ class DeviceEncodeDispatcher:
         gid = compute.gid
         with compute:  # started at the launch, on the submit thread
             jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        self._note_compute_done(compute.t1, compute.t1 - compute.t0)
+        self._note_compute_done(compute.t1, compute.t1 - compute.t0, route)
         return self._pull_and_frame(
             streams, lengths, gid, lanes, sizes, bit_depth,
-            color_type,
+            color_type, None if route is None else route.chip,
         )
 
     def _pull_and_frame(
         self, streams, lengths, gid, lanes, sizes, bit_depth,
-        color_type,
+        color_type, chip=None,
     ) -> Dict[int, bytes]:
-        """Shared tail: pull the compressed bytes in ONE sync (the
-        adaptive pow2 cap), frame the PNGs on the host."""
+        """Shared tail: pull the compressed bytes in ONE sync, frame
+        the PNGs on the host. A group that names its chip pulls its
+        lanes' whole rows; the others cut to the adaptive size guess
+        first (`_pull_to_guess`). The guess follows the data, so the
+        slice that cuts to it is a program of its own a size (and a
+        lane count, and a device), which no warm-up can have compiled
+        on every chip beforehand; a row is 0.6 MB, and on a named chip
+        nothing compiles while serving."""
         import jax
 
         from ..ops.png import frame_png
 
         real = len(lanes)
-        with self._stage("d2h", gid, real):
-            w, h = sizes[0]
-            full_cap = streams.shape[1]
-            # _dd_cap is shared with host-fallback paths on other
-            # threads; the stats lock makes the read-update pair
-            # coherent (r14 lock-discipline burndown — was a documented
-            # KNOWN_GAPS item)
-            with self._stats_lock:
-                cap_hint = self._dd_cap.get(
-                    (w, h), 1 << max(full_cap // 4, 64).bit_length()
-                )
-            guess = min(cap_hint, full_cap)
-            lengths_np, streams_np = jax.device_get(
-                (lengths[:real], streams[:real, :guess])
-            )
-            max_len = int(lengths_np.max()) if real else 0
-            if max_len > guess:
-                cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
-                # guess overflow: one extra pull, rare by construction
-                # (the cap tracks the running max)
-                streams_np = np.asarray(streams[:real, :cap])  # ompb-lint: disable=jax-hotpath -- guess-overflow path: a second bounded pull, not a per-lane sync
-            with self._stats_lock:
-                self._dd_cap[(w, h)] = min(
-                    full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
+        with self._stage("d2h", gid, real, chip):
+            if chip is not None:
+                lengths_np, streams_np = jax.device_get((lengths, streams))  # ompb-lint: disable=jax-hotpath -- readback worker: the one pull of the group
+            else:
+                lengths_np, streams_np = self._pull_to_guess(
+                    streams, lengths, real, sizes[0]
                 )
         out: Dict[int, bytes] = {}
-        with self._stage("frame", gid, real):
+        with self._stage("frame", gid, real, chip):
             for j, lane in enumerate(lanes):
                 out[lane] = frame_png(
                     streams_np[j, : int(lengths_np[j])].tobytes(),
                     sizes[j][0], sizes[j][1], bit_depth, color_type,
                 )
         return out
+
+    def _pull_to_guess(self, streams, lengths, real: int, size):
+        """(lengths, streams) of the real lanes on the host, the
+        streams cut on the device to the adaptive pow2 cap of their
+        (w, h) first: one pull, a second only when a stream outgrew
+        the guess."""
+        import jax
+
+        w, h = size
+        full_cap = streams.shape[1]
+        # _dd_cap is shared with host-fallback paths on other
+        # threads; the stats lock makes the read-update pair
+        # coherent (r14 lock-discipline burndown — was a documented
+        # KNOWN_GAPS item)
+        with self._stats_lock:
+            cap_hint = self._dd_cap.get(
+                (w, h), 1 << max(full_cap // 4, 64).bit_length()
+            )
+        guess = min(cap_hint, full_cap)
+        lengths_np, streams_np = jax.device_get(
+            (lengths[:real], streams[:real, :guess])
+        )
+        max_len = int(lengths_np.max()) if real else 0
+        if max_len > guess:
+            cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
+            # guess overflow: one extra pull, rare by construction
+            # (the cap tracks the running max)
+            streams_np = np.asarray(streams[:real, :cap])  # ompb-lint: disable=jax-hotpath -- guess-overflow path: a second bounded pull, not a per-lane sync
+        with self._stats_lock:
+            self._dd_cap[(w, h)] = min(
+                full_cap, 1 << max(2 * max_len - 1, 0).bit_length()
+            )
+        return lengths_np, streams_np

@@ -28,6 +28,7 @@ import logging
 import os
 import threading
 import time
+from types import SimpleNamespace
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -58,6 +59,7 @@ from ..runtime.native import get_engine
 from ..tile_ctx import TileCtx
 from ..utils.metrics import REGISTRY
 from ..utils.tracing import TRACER
+from .device_cache import device_of
 
 log = logging.getLogger("omero_ms_pixel_buffer_tpu.pipeline")
 
@@ -210,6 +212,7 @@ class TilePipeline:
         compilation_cache_dir: Optional[str] = None,
         lut_dir: Optional[str] = None,
         supertile_mesh: bool = True,
+        max_batch: int = 8,
     ):
         self.pixels_service = pixels_service
         self.png_filter = png_filter
@@ -268,6 +271,17 @@ class TilePipeline:
         # `backend.plane-cache-mb`); None = the cache's default
         self.plane_cache_bytes = plane_cache_bytes
         self._plane_cache = None  # built lazily on first device batch
+        # the most lanes one batch brings (config
+        # `backend.batching.max-batch`, which the server passes): on a
+        # host with several chips every lane count up to it is
+        # compiled on a chip before the chip's first plane counts as
+        # resident (_warm_plane_chip)
+        self.max_batch = max(1, int(max_batch))
+        # (device id, bh, bw, w, h, dtype): the lane classes whose
+        # programs a chip already holds, and a lock a chip so planes
+        # staged side by side onto one cold chip compile once
+        self._warm_chips: set = set()
+        self._warm_chip_locks: Dict[int, threading.Lock] = {}
         # serving mesh: "auto" -> built on first device batch when >1
         # accelerator is visible (tests inject one via `pipeline.mesh =
         # make_mesh(...)`, or force single-device with `= None`)
@@ -363,15 +377,31 @@ class TilePipeline:
             block_cache.purge_ns(ns)
 
     def _get_plane_cache(self):
-        """The HBM plane cache, built on the first device batch."""
+        """The HBM plane cache, built on the first device batch (the
+        server builds it at start-up, for `check_plane_budget`). Where
+        a serving mesh is up its planes spread over the mesh's chips,
+        an even share of the budget each; on one device it is the
+        cache it always was."""
         with self._state_lock:
             if self._plane_cache is None:
                 from .device_cache import DevicePlaneCache
 
+                mesh = self._get_mesh()
                 self._plane_cache = DevicePlaneCache(
-                    max_bytes=self.plane_cache_bytes
+                    max_bytes=self.plane_cache_bytes,
+                    devices=(
+                        None if mesh is None else list(mesh.devices.flat)
+                    ),
                 )
             return self._plane_cache
+
+    def check_plane_budget(self) -> Optional[str]:
+        """Start-up check of `backend.plane-cache-mb` against the
+        chips' memory (the device engine only): the error, also kept
+        for /healthz `cache.device_planes.error`, or None."""
+        if not (self.use_device and self.use_plane_cache):
+            return None
+        return self._get_plane_cache().check_budget()
 
     def plane_cache_snapshot(self) -> Optional[dict]:
         """/healthz view of the HBM plane tier; None when the device
@@ -580,6 +610,9 @@ class TilePipeline:
                 self._dispatcher = DeviceEncodeDispatcher(
                     self._dd_cap, mesh_manager=mgr,
                     queue_depth=self.queue_depth,
+                    # the groups of resident planes name their chip:
+                    # their pipe is a worker a chip wide
+                    chips=1 if mesh is None else mesh.devices.size,
                 )
             return self._dispatcher
 
@@ -914,11 +947,13 @@ class TilePipeline:
         # HBM-resident path: lanes whose plane is (or becomes) device-
         # resident skip the host read entirely — crop + filter happen
         # on the accelerator and only filtered bytes come back. With a
-        # multi-chip mesh the DP-sharded bucket path supersedes it:
-        # single-chip HBM residency would idle the other n-1 chips.
+        # multi-chip mesh a plane lives on one of its chips and its
+        # lanes run there, a group a plane; what is not eligible (edge
+        # lanes, degraded lanes, a cold plane) shards over the mesh
+        # below, as it does without the cache.
         plane_groups: Dict[Tuple, List[int]] = {}
         plane_handles: Dict[Tuple, object] = {}
-        if use_device and self.use_plane_cache and mesh is None:
+        if use_device and self.use_plane_cache:
             plane_groups, plane_handles = self._stage_plane_lanes(
                 ctxs, resolved
             )
@@ -1129,7 +1164,7 @@ class TilePipeline:
                     group = fut.result()  # ompb-lint: disable=loop-block -- executor-thread wait on a different pool
                 for i, png in group.items():
                     results[i] = png
-                TILE_DEVICE_LANES.inc(len(group))
+                self._count_device_lanes(fut, len(group))
             except Exception:
                 _host_fallback(
                     "encode_group", len(idxs), "device encode group failed"
@@ -1230,13 +1265,25 @@ class TilePipeline:
                     len(group), path="device", format="png"
                 )
             else:
-                TILE_DEVICE_LANES.inc(len(group))
+                self._count_device_lanes(gfut, len(group))
             for i in idxs:
                 lf = lane_futs[i]
                 if not lf.done():
                     lf.set_result(group.get(i))
 
         fut.add_done_callback(deliver)
+
+    def _count_device_lanes(self, fut, lanes: int) -> None:
+        """`tile_device_lanes_total` for one delivered group; a group
+        of a resident plane (`_submit_plane_groups` leaves the plane on
+        its future) also counts on the plane's chip: `per_chip` on
+        /healthz, and the `chip` label where the host has several."""
+        plane = getattr(fut, "plane", None)
+        labels = (
+            {} if plane is None
+            else self._get_plane_cache().note_lanes(plane, lanes)
+        )
+        TILE_DEVICE_LANES.inc(lanes, **labels)
 
     def _deferred_fallback(
         self, idxs, lane_futs, tiles, resolved, ctxs, _stacks
@@ -1448,6 +1495,10 @@ class TilePipeline:
                     want_dev = (
                         use_hbm
                         and use_fused
+                        # a lane's channels may live on different
+                        # chips: only a one-chip cache keeps the crops
+                        # resident through the composite
+                        and not self._get_plane_cache().spread
                         and not _q
                         and ctxs[i].render.format == "png"
                         and not ctxs[i].render.masks
@@ -2334,6 +2385,9 @@ class TilePipeline:
         # one admission touch per PLANE per batch (a plane serves every
         # bucket group; keying attempts on the group would double-touch)
         wanted: Dict[Tuple, Tuple] = {}
+        # per plane, the lane classes (bh, bw, w, h, dtype) this batch
+        # asks of it: what a chip compiles before the plane is resident
+        classes: Dict[Tuple, set] = {}
         for i, (ctx, rt) in enumerate(zip(ctxs, resolved)):
             if rt is None or ctx.format != "png" or ctx.render is not None:
                 # render lanes (format is also "png") have their own
@@ -2362,6 +2416,9 @@ class TilePipeline:
             wanted.setdefault(
                 plane_key, (rt.buffer, rt.level, ctx.z, ctx.c, ctx.t)
             )
+            classes.setdefault(plane_key, set()).add(
+                (bh, bw, rt.w, rt.h, meta_dtype.str)
+            )
             eligible.append((i, plane_key + (bh, bw, meta_dtype.str)))
         groups: Dict[Tuple, List[int]] = {}
         handles: Dict[Tuple, object] = {}
@@ -2373,9 +2430,21 @@ class TilePipeline:
             log.error("plane staging failed; host path", exc_info=exc)
             TILE_DEVICE_FALLBACK.inc(site="plane_staging")
 
+        warm = None
+        if cache.spread:
+            # a program is compiled once a device: on one chip the
+            # first requests compile as they always did, on several a
+            # plane is resident only once its chip can serve it
+            by_n = [classes[key] for key in wanted]
+
+            def warm(n, plane):
+                self._warm_plane_chip(plane, by_n[n])
+
         planes = dict(zip(
             wanted,
-            cache.get_planes(list(wanted.values()), on_error=staging_failed),
+            cache.get_planes(
+                list(wanted.values()), on_error=staging_failed, warm=warm
+            ),
         ))
         for i, key in eligible:
             plane = planes[key[:-3]]
@@ -2384,6 +2453,46 @@ class TilePipeline:
             handles[key] = plane
             groups.setdefault(key, []).append(i)
         return groups, handles
+
+    def _warm_plane_chip(self, plane, classes) -> None:
+        """Compile (or load from the persistent cache), on the chip
+        that holds ``plane``, every program a lane of ``classes`` can
+        run there, by running the serving path itself on crops at the
+        plane's origin: groups of 1 to ``max_batch`` lanes, so the
+        crop, both encode passes and every slice of the pull exist at
+        each lane count before the plane counts as resident. A lane of
+        a class that first shows later warms its chip the same way,
+        when it is staged."""
+        chip = device_of(plane).id
+        with self._state_lock:
+            lock = self._warm_chip_locks.setdefault(chip, threading.Lock())
+        for cls in sorted(classes):
+            with lock:
+                if (chip,) + cls in self._warm_chips:
+                    continue
+                t0 = time.perf_counter()
+                self._run_plane_lanes(plane, cls)
+                self._warm_chips.add((chip,) + cls)
+                log.info(
+                    "chip %d warm for %s lanes %s in %.1f s",
+                    chip, cls[4], cls[:4], time.perf_counter() - t0,
+                )
+
+    def _run_plane_lanes(self, plane, cls) -> None:
+        bh, bw, w, h, dtype_str = cls
+        dtype = np.dtype(dtype_str)
+        origin = SimpleNamespace(x=0, y=0, w=w, h=h)
+        for n in range(1, self.max_batch + 1):
+            lanes, resolved = list(range(n)), [origin] * n
+            if self.device_deflate:
+                for _, fut in self._submit_plane_groups(
+                    plane, lanes, resolved, bh, bw, dtype
+                ):
+                    fut.result()  # ompb-lint: disable=loop-block -- a stager thread (or the batch's own) waits on the dispatcher's pools
+            else:
+                self._device_plane_png_lanes(
+                    plane, lanes, resolved, None, [None] * n, bh, bw, dtype
+                )
 
     def _device_plane_png_lanes(
         self, plane, lanes, resolved, ctxs, results, bh, bw, dtype
@@ -2526,6 +2635,9 @@ class TilePipeline:
         self._log_device_deflate()
         disp = self._get_dispatcher()
         cache = self._get_plane_cache()
+        # where the planes spread over chips the group names its
+        # plane's: it runs there, through the queue's pipe of such groups
+        device = device_of(plane) if cache.spread else None
         itemsize = dtype.itemsize
         groups: Dict[Tuple[int, int], List[int]] = {}
         for i in lanes:
@@ -2540,8 +2652,9 @@ class TilePipeline:
                         h, 1 + w * itemsize, itemsize,
                         self.png_filter, self.device_deflate_mode, idxs,
                         [(w, h)] * len(idxs), itemsize * 8, 0,
-                        staged=True,
+                        staged=True, device=device,
                     )
+                    fut.plane = plane  # _count_device_lanes: its chip
                 except Exception as e:
                     # same per-group degradation as the bucket path
                     fut = concurrent.futures.Future()

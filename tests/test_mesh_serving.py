@@ -101,15 +101,19 @@ class TestMeshServing:
             png, IMG[0, 0, 0, 32:112, 128:228]
         )
 
-    def test_plane_cache_superseded_by_mesh(self, service):
-        """With a mesh the DP bucket path must serve lanes the plane
-        cache would otherwise claim (single-chip residency would idle
-        the other chips)."""
+    def test_plane_cache_beside_the_mesh(self, service):
+        """With a mesh the plane cache spreads over the mesh's chips;
+        a plane below its admission threshold takes the mesh path as
+        it did when the mesh superseded the cache."""
         multi = TilePipeline(service, engine="device", use_plane_cache=True)
         assert multi._get_mesh() is not None
         out = multi.handle_batch([_ctx(x=0, y=0, w=64, h=64)])
-        assert out[0] is not None
-        assert multi._plane_cache is None  # never built
+        png = np.array(Image.open(io.BytesIO(out[0])))
+        np.testing.assert_array_equal(png, IMG[0, 0, 0, :64, :64])
+        cache = multi._plane_cache
+        assert cache.spread and len(cache._chips) == 8
+        assert cache.chip_max_bytes == cache.max_bytes // 8
+        assert cache.snapshot()["planes"] == 0  # first touch: not admitted
 
     def test_odd_batch_padding(self, pipes):
         """Lane counts not divisible by the mesh size pad and slice."""
@@ -254,3 +258,162 @@ class TestBackgroundMeshProbe:
         finally:
             INJECTOR.clear()
             BOARD.reset()
+
+
+# ---------------------------------------------------------------------------
+# The plane cache a chip, beside the mesh (four of the virtual devices)
+# ---------------------------------------------------------------------------
+
+Z, C, SIDE, TILE = 8, 3, 96, 32
+STACK = np.random.default_rng(3302).integers(
+    0, 4000, (1, C, Z, SIDE, SIDE), dtype=np.uint16
+)
+SWEEP = [(z, c) for z in range(Z) for c in range(C)]  # z outer, c inner
+
+
+def _zc(z, c, x, y, w=TILE, h=TILE):
+    return TileCtx(
+        image_id=1, z=z, c=c, t=0, region=RegionDef(x, y, w, h),
+        format="png", omero_session_key="k",
+    )
+
+
+@pytest.fixture(scope="module")
+def stack_service(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("mesh-stack") / "stack.ome.tiff")
+    write_ome_tiff(path, STACK, tile_size=(TILE, TILE))
+    registry = ImageRegistry()
+    registry.add(1, path)
+    svc = PixelsService(registry)
+    yield svc
+    svc.close()
+
+
+@pytest.fixture(scope="module")
+def stack_pipes(stack_service):
+    """(four chips, one device), both with the device deflate the
+    deployment runs; the four-chip one has served the whole sweep
+    twice, so all 24 planes are resident, six a chip."""
+    import jax
+
+    from omero_ms_pixel_buffer_tpu.parallel.mesh import make_mesh
+
+    def build():
+        return TilePipeline(
+            stack_service, engine="device", use_pallas=False,
+            buckets=(TILE,), device_deflate=True,
+            device_deflate_mode="dynamic", max_batch=8,
+        )
+
+    four, one = build(), build()
+    four.mesh = make_mesh(("data",), devices=jax.devices()[:4])
+    one.mesh = None
+    for _ in range(2):
+        for start in range(0, len(SWEEP), 8):
+            four.handle_batch(
+                [_zc(z, c, 32, 64) for z, c in SWEEP[start:start + 8]]
+            )
+    yield four, one
+    four.close()
+    one.close()
+
+
+class TestPlaneCacheOnFourChips:
+    def test_the_stack_is_resident_six_planes_a_chip(self, stack_pipes):
+        four, _ = stack_pipes
+        snap = four.plane_cache_snapshot()
+        assert snap["planes"] == 24 and snap["evictions"] == 0
+        assert [row["planes"] for row in snap["per_chip"]] == [6, 6, 6, 6]
+        assert snap["devices"] == [0, 1, 2, 3]
+
+    @pytest.mark.parametrize("x, y", [(0, 0), (32, 64), (17, 5), (64, 64)])
+    def test_a_z_sweep_is_the_numpy_crop_and_the_one_device_bytes(
+        self, stack_pipes, x, y
+    ):
+        four, one = stack_pipes
+        before = four.plane_cache_snapshot()
+        lanes_before = [row["lanes"] for row in before["per_chip"]]
+        for start in range(0, len(SWEEP), 8):  # a batch: 8 lanes, 8 planes
+            ctxs = [_zc(z, c, x, y) for z, c in SWEEP[start:start + 8]]
+            out_four = four.handle_batch(list(ctxs))
+            out_one = one.handle_batch(list(ctxs))
+            for (z, c), got, ref in zip(SWEEP[start:], out_four, out_one):
+                np.testing.assert_array_equal(
+                    np.array(Image.open(io.BytesIO(got))),
+                    STACK[0, c, z, y:y + TILE, x:x + TILE],
+                )
+                assert got == ref  # the stream's bytes, not only pixels
+        after = four.plane_cache_snapshot()
+        assert after["misses"] == before["misses"]  # every lane a hit
+        assert after["hits"] - before["hits"] == 24
+        # every lane was cropped on its plane's chip: six a chip
+        assert [
+            row["lanes"] - was
+            for row, was in zip(after["per_chip"], lanes_before)
+        ] == [6, 6, 6, 6]
+
+    def test_groups_name_their_chip_and_share_one_pipe(
+        self, stack_pipes
+    ):
+        four, _ = stack_pipes
+        queue = four.device_queue_snapshot()
+        assert [row["chip"] for row in queue["chips"]] == [0, 1, 2, 3]
+        assert all(row["groups"] > 0 for row in queue["chips"])
+        assert sum(r["groups"] for r in queue["chips"]) <= queue["groups"]
+        assert queue["chip_pipe"] == {"workers": 4, "inflight": 0}
+
+    def test_an_edge_lane_beside_resident_ones_takes_the_mesh_path(
+        self, stack_pipes
+    ):
+        """A lane whose bucket would clamp at the plane's edge is not
+        eligible for the crop; it shards over the mesh as before, in
+        the same batch as lanes served from resident planes."""
+        four, one = stack_pipes
+        ctxs = [
+            _zc(0, 0, 8, 8),
+            _zc(0, 1, 8, 8),
+            _zc(0, 0, 80, 72, w=16, h=24),  # 80 + 32 > 96: the edge
+            _zc(3, 2, 40, 40),
+        ]
+        lanes = [r["lanes"] for r in four.plane_cache_snapshot()["per_chip"]]
+        mesh_before = four.last_mesh_dispatch
+        out_four = four.handle_batch(list(ctxs))
+        out_one = one.handle_batch(list(ctxs))
+        for ctx, got, ref in zip(ctxs, out_four, out_one):
+            r = ctx.region
+            np.testing.assert_array_equal(
+                np.array(Image.open(io.BytesIO(got))),
+                STACK[0, ctx.c, ctx.z, r.y:r.y + r.height,
+                      r.x:r.x + r.width],
+            )
+            assert got == ref
+        served = [
+            row["lanes"] - was for row, was in zip(
+                four.plane_cache_snapshot()["per_chip"], lanes)
+        ]
+        assert sum(served) == 3  # the three eligible lanes only
+        mesh_after = four.last_mesh_dispatch
+        assert mesh_after is not None and mesh_after is not mesh_before
+        assert mesh_after["n_devices"] == 4
+
+    def test_no_lane_fell_to_the_host(self, stack_pipes):
+        from omero_ms_pixel_buffer_tpu.models import tile_pipeline
+
+        fallen = dict(tile_pipeline.TILE_DEVICE_FALLBACK._values)
+        four, _ = stack_pipes
+        four.handle_batch([_zc(z, c, 48, 16) for z, c in SWEEP[:8]])
+        assert dict(tile_pipeline.TILE_DEVICE_FALLBACK._values) == fallen
+
+    def test_the_series_name_the_chip(self, stack_pipes):
+        from omero_ms_pixel_buffer_tpu.models import (
+            device_cache,
+            tile_pipeline,
+        )
+
+        for chip in "0123":
+            key = (("chip", chip),)
+            assert device_cache.PLANE_ADMISSIONS._values[key] >= 6
+            assert device_cache.PLANE_BYTES._values[key] == 6 * SIDE * SIDE * 2
+            assert tile_pipeline.TILE_DEVICE_LANES._values[key] >= 6
+            assert (("chip", chip), ("stage", "h2d")) in (
+                device_cache.PLANE_STAGE_SECONDS._sums)
