@@ -16,33 +16,46 @@ framing, applied to the encode pipe):
   submit thread moves straight on to the next group, INCLUDING groups
   of a batch that arrived while the previous batch was still in
   flight;
-- a READBACK worker blocks on each group's device completion in
-  submission order, pulls lengths + streams in one host sync, and
-  frames the PNGs — overlapping group k's D2H + framing with group
-  k+1's (and batch N+1's) compute;
-- a semaphore bounds the in-flight groups to ``queue_depth`` (config
-  ``backend.png.queue-depth``, default 2 = the classic double buffer);
-  staging backpressures on the SUBMIT thread, never on callers.
+- a PULL worker blocks on each group's device completion in the order
+  the groups' last programs were launched, pulls lengths + streams in
+  one host sync, and frames the PNGs — overlapping group k's D2H +
+  framing with group k+1's (and batch N+1's) compute;
+- a semaphore bounds the groups the DEVICE holds to ``queue_depth``
+  (config ``backend.png.queue-depth``, default 2 = the classic double
+  buffer): a slot is taken before a group is staged and given back
+  when the pull worker sees the group's last device program done, not
+  after the frame, so the host's pull and framing of group k keep no
+  later group off the device. Staging backpressures on the SUBMIT
+  thread, never on callers.
 
-The queue records, per group, whether its launch OVERLAPPED the
-previous group's compute (launch before the previous compute-done
-stamp) or left a device idle gap — ``snapshot()`` reports steady-state
-occupancy, the idle-gap distribution, and mean compute time so BENCH
-can assert the cross-batch overlap instead of describing it.
+Dynamic-Huffman groups (deflate mode "dynamic") run two programs with
+a host hop between, and a third worker keeps that hop off the chip's
+critical path: the submit thread launches pass 1 (filter + histogram,
+one program); a PLAN worker pulls the (B, 286) counts — absorbing pass
+1's wait — builds the canonical code tables on host, launches pass 2
+(emit) and does NOT wait for it: it hands the group to the pull worker
+and takes up group k+1, whose emit is then queued on the device behind
+group k's. Nothing the chip would wait for needs the chip: the plan of
+k+1 needs only k+1's counts, the pull of k only k's emit. (With the
+plan, the wait and the frame on one worker the chip idled 44-50% of
+the time on the v5e, PERF.md §6 PR 31 and PR 34.) Single-pass groups
+(``rle``, ``stored``, render) have no plan and go from the submit
+thread straight to the pull worker.
 
-Dynamic-Huffman groups (deflate mode "dynamic") pipeline their two
-passes across the threads: the submit thread launches pass 1 (filter +
-histogram, one program), the readback worker pulls the (B, 286) counts
-— absorbing pass 1's wait — builds the canonical code tables on host,
-launches pass 2 (emit), and blocks on it; other groups' passes
-interleave on device between the two.
+The queue counts, per group, whether its LAST program was launched
+while the pipe's device still held an earlier group's last program
+(launched, not yet seen done: ``overlapped``) or found none
+(``idle_gaps``, with the time since the last one was seen done) —
+``snapshot()`` reports steady-state occupancy, the idle-gap
+distribution, and mean compute time so BENCH can assert the overlap
+instead of describing it.
 
 On a host with several chips a group whose input already lives on one
 of them (a crop of an HBM-resident plane, ``submit(..., device=)``)
 runs on that chip: its programs follow its data. What orders and
 bounds such groups is one more pipe, as wide as there are chips: that
 many submit threads, that many times ``queue_depth`` slots and that
-many readback workers behind ONE queue, so a group that blocks on
+many plan and pull workers behind ONE queue, so a group that blocks on
 chip 0's pass 2 keeps no other chip from being launched. The width is
 the host's and no worker is a chip's own: the groups are taken up in
 the order they came, whatever their chip. (A pipe a chip was built
@@ -58,11 +71,12 @@ no device (host-staged and mesh groups, and every group of a one-chip
 host), which is the queue as it always was.
 
 Failure contract (unchanged from r9, now chaos-pinned): any failure in
-staging, dispatch, or readback resolves THAT group's future with the
-exception — the pipeline degrades those lanes to the host encoder —
-and never stalls or reorders other groups; the ``device.encode-group``
-fault point injects exactly that. With a serving mesh, groups run
-blocking on the readback worker through ``parallel.mesh.MeshManager``
+staging, plan, dispatch, wait, pull or frame resolves THAT group's
+future with the exception — the pipeline degrades those lanes to the
+host encoder — gives its slot back exactly once, and never stalls or
+reorders other groups; the ``device.encode-group`` fault point injects
+exactly that. With a serving mesh, groups run whole and
+blocking on the pull worker through ``parallel.mesh.MeshManager``
 (per-chip breakers, probe-shrink-retry), and the dispatcher pre-warms
 jit specializations for recently-seen group shapes on a background
 thread whenever the healthy mesh WIDTH changes, so the first dispatch
@@ -88,7 +102,7 @@ log = logging.getLogger("omero_ms_pixel_buffer_tpu.device_dispatch")
 DEVICE_STAGE_SECONDS = REGISTRY.histogram(
     "device_stage_seconds",
     "Device encode pipeline stage durations "
-    "(stage=h2d|compute|hist|emit|d2h|frame)",
+    "(stage=h2d|compute|hist|plan|emit|d2h|frame)",
 )
 # a family of its own on purpose: a wait is no stage, and a new label
 # on device_stage_seconds would add itself to every sum over the family
@@ -103,7 +117,7 @@ DEVICE_QUEUE_WAIT_SECONDS = REGISTRY.histogram(
 def _observe_stage(duration: float, stage: str) -> None:
     """Stage histogram + deferred trace exemplar: the submitting
     request's record is scoped onto the queue's worker threads per
-    group (``record_scope`` in ``_run_stage`` and the readback wrap),
+    group (``record_scope`` in ``_run_stage`` and ``_on_worker``),
     and the exemplar only lands if the tail sampler keeps the trace —
     a device-stage spike in a dashboard pivots to a citable trace."""
     DEVICE_STAGE_SECONDS.observe(duration, stage=stage)
@@ -134,8 +148,9 @@ class _Span:
     ``observe``) on the host's ``perf_counter`` and a profiler
     annotation over the same interval. Both start at construction, so
     a stage that starts on one thread and ends on another (the launch
-    on the submit thread, the wait on the readback worker) is built
-    where it starts and entered, ``with span:``, where it ends. An
+    on the submit or the plan worker, the wait on the plan or the pull
+    worker) is built where it starts and entered, ``with span:``,
+    where it ends. An
     exception ends the annotation and observes nothing, like the
     stamp pairs this replaced."""
 
@@ -199,8 +214,9 @@ DEVICE_GROUP_LANES = REGISTRY.histogram(
 
 DEVICE_QUEUE_IDLE_SECONDS = REGISTRY.histogram(
     "device_queue_idle_seconds",
-    "Device idle gap between one encode group's compute finishing and "
-    "the next group's launch (0-bucketed when the launch overlapped)",
+    "Device idle gap between the pipe's last in-flight group being "
+    "seen done and the next launch of a group's last program "
+    "(0-bucketed when that launch found one still in flight)",
 )
 
 # how many distinct mesh group shapes the width-change warmup replays
@@ -223,39 +239,66 @@ def _mesh_padded_lanes(b: int, width: int) -> int:
 class _Pipe:
     """What orders and bounds a stream of groups: submit threads
     (groups stage + launch in the order they came, across batches),
-    readback workers (taken up in submission order; with one worker
-    group k's D2H never competes with group k+1's: the pipe stays a
-    pipe) and ``queue_depth`` in-flight slots a worker. The process's
-    pipe has one worker of each kind; the pipe of the groups that name
-    their chip has one a chip."""
+    plan workers (dynamic groups only, taken up in submission order:
+    the counts, the host's Huffman plan, the emit's launch, no wait),
+    pull workers (taken up in the order the last programs were
+    launched: the wait, the pull, the frame; with one worker group
+    k's D2H never competes with group k+1's: the pipe stays a pipe)
+    and ``queue_depth`` slots a worker for the groups the device
+    holds. ``emitting`` counts the groups whose last program is
+    launched and not yet seen done, ``idle_since`` stamps the moment
+    it last fell to none. The process's pipe has one worker of each
+    kind; the pipe of the groups that name their chip has one a
+    chip."""
 
     __slots__ = (
-        "workers", "submit_pool", "readback", "slots", "inflight",
-        "last_compute_done",
+        "workers", "submit_pool", "plan", "pull", "slots", "inflight",
+        "emitting", "idle_since",
     )
 
     def __init__(self, tag: str, queue_depth: int, workers: int = 1):
         self.workers = workers
-        self.submit_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=workers, thread_name_prefix=f"devenc-submit{tag}"
-        )
-        self.readback = concurrent.futures.ThreadPoolExecutor(
-            max_workers=workers, thread_name_prefix=f"devenc-readback{tag}"
+        self.submit_pool, self.plan, self.pull = (
+            concurrent.futures.ThreadPoolExecutor(
+                max_workers=workers,
+                thread_name_prefix=f"devenc-{stage}{tag}",
+            )
+            for stage in ("submit", "plan", "pull")
         )
         self.slots = threading.Semaphore(queue_depth * workers)
         self.inflight = 0
-        self.last_compute_done: Optional[float] = None
+        self.emitting = 0
+        self.idle_since: Optional[float] = None
+
+    def seen_done(self, t_done: float) -> None:
+        """One emitting group less (under the dispatcher's stats
+        lock)."""
+        self.emitting -= 1
+        if not self.emitting:
+            self.idle_since = t_done
 
 
-class _Route:
-    """Where one group runs: the pipe that orders it and, where it
-    names one, the id of the chip its arrays live on (the stat `chip`
-    of its annotations)."""
+class _Group:
+    """One group on its way through the queue: its id, the caller's
+    future, the submitting request's flight record, the pipe that
+    orders it and, where it names one, the id of the chip its arrays
+    live on (the stat `chip` of its annotations). ``t_launch`` stamps
+    its first program's launch. ``holds_slot`` and ``emitting`` are
+    what the group has taken of its pipe and not yet given back (a
+    slot; a place among the groups whose last program the device
+    holds): `_device_done` gives both back, once, whichever way the
+    group leaves."""
 
-    __slots__ = ("pipe", "chip")
+    __slots__ = (
+        "gid", "fut", "rec", "pipe", "chip", "t_launch", "holds_slot",
+        "emitting",
+    )
 
-    def __init__(self, pipe: _Pipe, chip=None):
+    def __init__(self, gid: int, fut, rec, pipe: _Pipe, chip=None):
+        self.gid, self.fut, self.rec = gid, fut, rec
         self.pipe, self.chip = pipe, chip
+        self.t_launch = 0.0
+        self.holds_slot = self.emitting = False
 
 
 class DeviceEncodeDispatcher:
@@ -263,10 +306,10 @@ class DeviceEncodeDispatcher:
     per-group futures.
 
     One dispatcher per TilePipeline; ``dd_cap`` is the pipeline's
-    shared adaptive compressed-size guess keyed (w, h) — the readback
-    thread both consumes and trains it. ``mesh_manager`` (optional)
+    shared adaptive compressed-size guess keyed (w, h) — the pull
+    worker both consumes and trains it. ``mesh_manager`` (optional)
     switches group dispatch to the sharded multi-chip path.
-    ``queue_depth`` bounds concurrently in-flight groups.
+    ``queue_depth`` bounds the groups the device holds at once.
     """
 
     def __init__(
@@ -287,7 +330,6 @@ class DeviceEncodeDispatcher:
         self._chip_pipe: Optional[_Pipe] = None
         self._chips = max(1, int(chips))
         self._chip_groups: Dict[int, int] = {}  # chip id -> groups
-        self._readback = self._pipe.readback  # the mesh groups'
         # per-dispatcher group ids: every stage annotation, the waits
         # and the submitting request's flight record carry one
         self._gids = itertools.count(1)
@@ -350,7 +392,10 @@ class DeviceEncodeDispatcher:
             except concurrent.futures.InvalidStateError:
                 pass  # resolved in the race window: nothing to do
         for pipe in pipes:
-            pipe.readback.shutdown(wait=not not_done)
+            # in the order a group passes them: a plan worker that is
+            # still winding down may hand its group on
+            pipe.plan.shutdown(wait=not not_done)
+            pipe.pull.shutdown(wait=not not_done)
         if not_done:
             log.warning(
                 "device encode queue: %d group(s) unresolved after "
@@ -379,17 +424,17 @@ class DeviceEncodeDispatcher:
             lambda dt: _observe_stage(dt, stage), stage, gid, lanes, chip
         )
 
-    def _route_for(self, device) -> _Route:
-        """The process's pipe for a group that names no chip, else the
-        pipe of those that do."""
+    def _pipe_for(self, device) -> tuple:
+        """(pipe, chip id): the process's pipe for a group that names
+        no chip, else the pipe of those that do."""
         if device is None:
-            return _Route(self._pipe)
+            return self._pipe, None
         with self._pipes_lock:
             if self._chip_pipe is None:
                 self._chip_pipe = _Pipe(
                     "-chips", self.queue_depth, workers=self._chips
                 )
-            return _Route(self._chip_pipe, device.id)
+            return self._chip_pipe, device.id
 
     @staticmethod
     def _wait(where: str) -> _Span:
@@ -403,43 +448,89 @@ class DeviceEncodeDispatcher:
         that was running."""
         return _Span(lambda dt: _observe_wait(dt, where))
 
-    def _note_launch(
-        self, t_launch: float, lanes: int, route: Optional[_Route] = None
+    def _note_group(
+        self, t_launch: float, lanes: int, group: Optional[_Group]
     ) -> None:
-        """Called as a group's device program is dispatched: counts
-        the group and its real lanes, samples occupancy and classifies
-        the launch as overlapped (its pipe's previous group was still
-        computing) or post-idle-gap."""
-        pipe = self._pipe if route is None else route.pipe
+        """Called as a group's first device program is dispatched:
+        counts the group and its real lanes and samples occupancy."""
         DEVICE_GROUP_LANES.observe(lanes)
         with self._stats_lock:
             self._groups += 1
-            if route is not None and route.chip is not None:
-                self._chip_groups[route.chip] = (
-                    self._chip_groups.get(route.chip, 0) + 1
-                )
             self._occupancy_sum += self._inflight
-            last = pipe.last_compute_done
-            if last is None:
+            if group is None:
                 return
-            gap = t_launch - last
-            if gap <= 0:
+            group.t_launch = t_launch
+            if group.chip is not None:
+                self._chip_groups[group.chip] = (
+                    self._chip_groups.get(group.chip, 0) + 1
+                )
+
+    def _note_last_launch(
+        self, t_launch: float, group: Optional[_Group] = None
+    ) -> None:
+        """Called as a group's LAST device program is dispatched (the
+        emit of a dynamic group, the one program of any other):
+        classifies the launch as overlapped (its pipe's device still
+        held an earlier group's last program: launched, not yet seen
+        done) or post-idle-gap, and counts the group among those the
+        device holds until `_device_done`. A mesh method, which notes
+        its launch once its blocking dispatch is back, names no group:
+        the process's pipe."""
+        pipe = self._pipe if group is None else group.pipe
+        with self._stats_lock:
+            if pipe.emitting:
                 self._overlapped += 1
                 DEVICE_QUEUE_IDLE_SECONDS.observe(0.0)
-            else:
+            elif pipe.idle_since is not None:
+                gap = max(t_launch - pipe.idle_since, 0.0)
                 self._idle_gaps += 1
                 self._idle_gap_sum += gap
                 self._idle_gap_max = max(self._idle_gap_max, gap)
                 DEVICE_QUEUE_IDLE_SECONDS.observe(gap)
+            pipe.emitting += 1
+            if group is not None:
+                group.emitting = True
 
-    def _note_compute_done(
-        self, t_done: float, dt: float, route: Optional[_Route] = None
+    def _note_launch(
+        self, t_launch: float, lanes: int, group: Optional[_Group] = None
     ) -> None:
+        """A group whose one program is its first and its last."""
+        self._note_group(t_launch, lanes, group)
+        self._note_last_launch(t_launch, group)
+
+    def _note_compute_done(self, t_done: float, dt: float) -> None:
+        """A mesh method's blocking dispatch is back (the process's
+        pipe; its slot stays until the method returns)."""
         with self._stats_lock:
-            pipe = self._pipe if route is None else route.pipe
-            pipe.last_compute_done = t_done
             self._compute_sum += dt
             self._computes += 1
+            self._pipe.seen_done(t_done)
+
+    def _device_done(
+        self, group: _Group, t_done: Optional[float] = None
+    ) -> None:
+        """The device is done with the group: its last program was
+        seen done (at ``t_done``) or the group failed or resolved,
+        wherever. Gives back what the group holds of its pipe, each
+        exactly once: its place among the emitting and its slot — so
+        the slot follows the chip, not the host's pull and frame.
+        Idempotent."""
+        pipe = group.pipe
+        with self._stats_lock:
+            if t_done is not None:
+                self._compute_sum += t_done - group.t_launch
+                self._computes += 1
+            if group.emitting:
+                group.emitting = False
+                pipe.seen_done(
+                    time.perf_counter() if t_done is None else t_done
+                )
+            if not group.holds_slot:
+                return
+            group.holds_slot = False
+            self._inflight -= 1
+            pipe.inflight -= 1
+        pipe.slots.release()
 
     def snapshot(self) -> dict:
         """Steady-state queue health for /healthz and BENCH: occupancy,
@@ -520,7 +611,7 @@ class DeviceEncodeDispatcher:
             self._stage_group,
             tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
             lanes, sizes, bit_depth, color_type, staged,
-            route=self._route_for(device),
+            device=device,
         )
 
     def submit_render(
@@ -545,7 +636,7 @@ class DeviceEncodeDispatcher:
         composite on device (the r19 mask queue wiring — masked lanes
         no longer detour to the host mirror). The fused composite +
         filter + deflate program runs as ONE dispatch and the
-        readback worker frames RGB8 PNGs. Same queue semantics as
+        pull worker frames RGB8 PNGs. Same queue semantics as
         ``submit``; with a serving mesh the group shards across chips
         through ``sharded_render_filter_deflate`` instead — masks
         included, as a sharded operand (only staged device-resident
@@ -558,135 +649,117 @@ class DeviceEncodeDispatcher:
         )
 
     def _enqueue(
-        self, stage_fn, *args, route: Optional[_Route] = None
+        self, stage_fn, *args, device=None
     ) -> "concurrent.futures.Future":
         if self._closed:
             raise RuntimeError("device encode queue is closed")
-        route = route or self._route_for(None)
         fut: "concurrent.futures.Future" = concurrent.futures.Future()
         with self._pending_lock:
             self._pending.add(fut)
         fut.add_done_callback(self._discard_pending)
-        gid = next(self._gids)
         # capture the submitting request's flight record NOW (the
         # caller runs inside the batcher's record scope); the queue's
         # worker threads re-scope it per group for deferred exemplars
         rec = current_record()
+        group = _Group(next(self._gids), fut, rec, *self._pipe_for(device))
         if rec is not None:
-            rec.tag("device_group", gid)  # /debug/requests names the group
+            rec.tag("device_group", group.gid)  # /debug/requests names the group
         # the wait for the submit thread starts here and ends there
         pool = self._wait("pool")
         try:
-            route.pipe.submit_pool.submit(
-                self._run_stage, stage_fn, fut, args, rec, gid, pool, route
+            group.pipe.submit_pool.submit(
+                self._run_stage, stage_fn, group, args, pool
             )
         except RuntimeError as e:
             # close() raced the _closed check and shut the pool down:
             # resolve THIS group's future exceptionally (the pipeline
             # host-falls-back those lanes) instead of raising past
             # already-submitted groups' futures
-            self._resolve_exc(fut, e)
+            self._fail(group, e)
         return fut
 
     def _discard_pending(self, fut) -> None:
         with self._pending_lock:
             self._pending.discard(fut)
 
-    @staticmethod
-    def _tid_bound(fn):
-        """Carry the ambient flight record (set by ``_run_stage``)
-        onto the readback worker so the compute/d2h/frame stage
-        observes keep their deferred exemplar — the readback thread
-        outlives any request context."""
-        rec = current_record()
-        if rec is None:
-            return fn
-
-        def bound(*args, **kwargs):
-            with record_scope(rec):
-                return fn(*args, **kwargs)
-
-        return bound
-
-    @staticmethod
-    def _resolve_exc(fut, exc) -> None:
+    def _fail(self, group: _Group, exc) -> None:
+        """The group leaves with an exception, from whichever thread
+        had it: its slot comes back, its future resolves (the pipeline
+        host-falls-back the lanes), no other group is touched."""
+        self._device_done(group)
         # close()'s drain deadline may have resolved the future first;
         # losing that race is fine — the caller already host-fell-back
         try:
-            fut.set_exception(exc)
+            group.fut.set_exception(exc)
         except concurrent.futures.InvalidStateError:
             pass
 
-    def _run_stage(
-        self, stage_fn, fut, args, rec, gid: int, pool: _Span,
-        route: _Route,
-    ) -> None:
-        """Submit-thread trampoline: acquire an in-flight slot, stage +
-        launch, chain the readback future into the caller's. Any
-        failure resolves the caller future exceptionally (the pipeline
-        host-falls-back that group) without touching other groups.
-        The group's two waits are observed here, once each, whatever
-        the stage function does: ``pool`` ends as this is entered,
-        ``slot`` spans the acquire."""
+    def _run_stage(self, stage_fn, group: _Group, args, pool: _Span) -> None:
+        """Submit-thread trampoline: acquire a slot, stage + launch,
+        hand the group to its next worker (the stage function does).
+        Any failure resolves the caller future exceptionally (the
+        pipeline host-falls-back that group) without touching other
+        groups. The group's two waits are observed here, once each,
+        whatever the stage function does: ``pool`` ends as this is
+        entered, ``slot`` spans the acquire."""
         from ..resilience.faultinject import INJECTOR
 
         pool.end()
-        pipe = route.pipe
-        acquired = False
+        pipe = group.pipe
         try:
             INJECTOR.fire("device.encode-group")
-            # bounded in-flight groups: backpressure lands HERE (the
-            # submit thread), keeping callers non-blocking and the
-            # device at most queue_depth groups ahead of readback
+            # bounded groups on the device: backpressure lands HERE
+            # (the submit thread), keeping callers non-blocking and
+            # the device at most queue_depth groups ahead of the pull
             with self._wait("slot"):
                 pipe.slots.acquire()
-            acquired = True
             with self._stats_lock:
+                group.holds_slot = True
                 self._inflight += 1
                 pipe.inflight += 1
-            with record_scope(rec):
-                rfut = stage_fn(gid, *args, route)
+            with record_scope(group.rec):
+                stage_fn(group, *args)
         except Exception as e:
-            # resolve the caller's future instead of raising into the
-            # executor: the pipeline host-falls-back this group
-            if acquired:
-                self._release_slot(pipe)
-            self._resolve_exc(fut, e)
+            self._fail(group, e)
+
+    def _hand(self, workers, group: _Group, fn, *args) -> None:
+        """Give the group to a plan or a pull worker of its pipe."""
+        workers.submit(self._on_worker, group, fn, args)
+
+    def _on_worker(self, group: _Group, fn, args) -> None:
+        """Plan- and pull-worker trampoline: the stage runs under the
+        submitting request's flight record (its stage observes keep
+        their deferred exemplar — the workers outlive any request
+        context). A pull stage returns the group's PNGs, which resolve
+        it; a plan stage hands the group on and returns None; whatever
+        either raises fails the group, and only it."""
+        try:
+            with record_scope(group.rec):
+                out = fn(*args)
+        except Exception as e:
+            self._fail(group, e)
             return
-        rfut.add_done_callback(
-            lambda rf: self._finish_group(fut, rf, pipe)
-        )
-
-    def _release_slot(self, pipe: _Pipe) -> None:
-        with self._stats_lock:
-            self._inflight -= 1
-            pipe.inflight -= 1
-        pipe.slots.release()
-
-    def _finish_group(self, fut, rfut, pipe: _Pipe) -> None:
-        self._release_slot(pipe)
-        exc = rfut.exception()
-        if exc is not None:
-            self._resolve_exc(fut, exc)
-        else:
-            try:
-                fut.set_result(rfut.result())
-            except concurrent.futures.InvalidStateError:
-                pass  # close()'s drain deadline got there first
+        if out is None:
+            return
+        self._device_done(group)  # a mesh method's slot: held to here
+        try:
+            group.fut.set_result(out)
+        except concurrent.futures.InvalidStateError:
+            pass  # close()'s drain deadline got there first
 
     # -- staging (submit thread) ---------------------------------------
 
     def _stage_group(
-        self, gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
-        lanes, sizes, bit_depth, color_type, staged, route,
+        self, group, tiles, rows, row_bytes, bpp, filter_mode,
+        deflate_mode, lanes, sizes, bit_depth, color_type, staged,
     ):
         import jax
 
         n = len(lanes)
-        pipe, chip = route.pipe, route.chip
+        gid, pipe, chip = group.gid, group.pipe, group.chip
         mesh_mgr = self.mesh_manager
         if mesh_mgr is not None and not staged:
-            # sharded groups run ENTIRELY on the readback worker: the
+            # sharded groups run ENTIRELY on the pull worker: the
             # dispatch must block on device completion inside
             # MeshManager.dispatch, or a chip that wedges mid-compute
             # would surface at a later block_until_ready outside the
@@ -701,13 +774,13 @@ class DeviceEncodeDispatcher:
                 # between: the plan runs per shard's pulled counts
                 # inside the managed dispatch, so mesh lanes keep
                 # content-adaptive codes instead of downgrading to rle
-                return self._readback.submit(
-                    self._tid_bound(self._mesh_dynamic_group),
+                return self._hand(
+                    pipe.pull, group, self._mesh_dynamic_group,
                     gid, tiles, rows, row_bytes, bpp, filter_mode,
                     lanes, sizes, bit_depth, color_type,
                 )
-            return self._readback.submit(
-                self._tid_bound(self._mesh_group),
+            return self._hand(
+                pipe.pull, group, self._mesh_group,
                 gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
                 lanes, sizes, bit_depth, color_type,
             )
@@ -726,17 +799,17 @@ class DeviceEncodeDispatcher:
                 batch_dev, rows, row_bytes, bpp, filter_mode=filter_mode,
                 donate=self._donate_ok(),
             )
-            # the stage starts at the launch, here; the readback
-            # worker ends it when it has pulled the counts
+            # the stage starts at the launch, here; the plan worker
+            # ends it when it has pulled the counts
             hist = self._stage("hist", gid, n, chip)
-            self._note_launch(hist.t0, len(lanes), route)
+            self._note_group(hist.t0, n, group)
             # a plane-cache group arrives with its lane axis already
             # padded: the host plans only the real lanes, as it does
             # for a group it padded itself
-            return pipe.readback.submit(
-                self._tid_bound(self._dynamic_readback_group),
-                flat, counts, extras, min(real_b, n), hist, lanes, sizes,
-                bit_depth, color_type, route,
+            return self._hand(
+                pipe.plan, group, self._plan_group,
+                group, flat, counts, extras, min(real_b, n), hist, lanes,
+                sizes, bit_depth, color_type,
             )
         from ..ops.device_deflate import fused_filter_deflate_batch
 
@@ -746,20 +819,21 @@ class DeviceEncodeDispatcher:
             donate=self._donate_ok(),
         )
         compute = self._stage("compute", gid, n, chip)  # launch -> ready
-        self._note_launch(compute.t0, len(lanes), route)
-        return pipe.readback.submit(
-            self._tid_bound(self._readback_group),
-            streams, lengths, compute, lanes, sizes,
-            bit_depth, color_type, route,
+        self._note_launch(compute.t0, n, group)
+        return self._hand(
+            pipe.pull, group, self._readback_group,
+            group, streams, lengths, compute, lanes, sizes,
+            bit_depth, color_type,
         )
 
     def _stage_render_group(
-        self, gid, planes, index_tables, color_luts, rows, row_bytes,
+        self, group, planes, index_tables, color_luts, rows, row_bytes,
         filter_mode, deflate_mode, lanes, sizes, mask=None,
-        staged=False, route=None,
+        staged=False,
     ):
         import jax
 
+        gid, pull = group.gid, group.pipe.pull
         if self.mesh_manager is not None and not staged:
             # same rationale as the raw-tile mesh path: block inside
             # the managed dispatch so a sick chip degrades the mesh.
@@ -768,8 +842,8 @@ class DeviceEncodeDispatcher:
             # batch shards with its lanes); only staged
             # (device-resident) groups stay single-device — their
             # arrays already live on one chip.
-            return self._readback.submit(
-                self._tid_bound(self._mesh_render_group),
+            return self._hand(
+                pull, group, self._mesh_render_group,
                 gid, planes, index_tables, color_luts, rows, row_bytes,
                 filter_mode, deflate_mode, lanes, sizes, mask,
             )
@@ -791,19 +865,19 @@ class DeviceEncodeDispatcher:
             mask=mask_dev,
         )
         compute = self._stage("compute", gid, n)  # launch -> ready
-        self._note_launch(compute.t0, len(lanes))
-        return self._readback.submit(
-            self._tid_bound(self._readback_group),
-            streams, lengths, compute, lanes, sizes, 8, 2,
+        self._note_launch(compute.t0, n, group)
+        return self._hand(
+            pull, group, self._readback_group,
+            group, streams, lengths, compute, lanes, sizes, 8, 2,
         )
 
-    # -- mesh groups (readback worker) ---------------------------------
+    # -- mesh groups (pull worker) -------------------------------------
 
     def _mesh_render_group(
         self, gid, planes, index_tables, color_luts, rows, row_bytes,
         filter_mode, deflate_mode, lanes, sizes, mask=None,
     ):
-        """One sharded render group on the readback worker (same
+        """One sharded render group on the pull worker (same
         pow2-then-mesh-width lane padding and blocking-dispatch
         semantics as ``_mesh_group``). ``mask`` (optional) is the
         (B, H, W) uint8 ROI batch — padded and sharded exactly like
@@ -839,14 +913,14 @@ class DeviceEncodeDispatcher:
                 mask_sh = shard_batch(
                     mesh, _pad_lanes(jnp.asarray(mask), padded_b)
                 )
-            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
+            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the pull worker
             stamps["h2d"] = marks.next("compute")
             out = sharded_render_filter_deflate(
                 mesh, sharded, index_tables, color_luts, rows,
                 row_bytes, filter_mode=filter_mode,
                 deflate_mode=deflate_mode, mask=mask_sh,
             )
-            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- pull worker: the one thread that waits on device completion
 
         try:
             streams, lengths = self.mesh_manager.dispatch(
@@ -871,7 +945,7 @@ class DeviceEncodeDispatcher:
         self, gid, tiles, rows, row_bytes, bpp, filter_mode, deflate_mode,
         lanes, sizes, bit_depth, color_type,
     ):
-        """One sharded group on the readback worker: pad pow2 (the
+        """One sharded group on the pull worker: pad pow2 (the
         same per-shape jit-specialization cap the single-device path
         has, then up to the healthy mesh width), shard, run the fused
         chain, and BLOCK inside the managed dispatch so a sick chip's
@@ -899,7 +973,7 @@ class DeviceEncodeDispatcher:
                     ((0, padded_b - b),) + ((0, 0),) * (batch.ndim - 1),
                 )
             sharded = shard_batch(mesh, batch)
-            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
+            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the pull worker
             stamps["h2d"] = marks.next("compute")
             out = sharded_filter_deflate(
                 mesh, sharded, rows, row_bytes, bpp,
@@ -908,7 +982,7 @@ class DeviceEncodeDispatcher:
             # block INSIDE the managed dispatch: a mid-compute chip
             # failure must raise here, where MeshManager probes and
             # shrinks, not at a later pull
-            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- pull worker: the one thread that waits on device completion
 
         try:
             streams, lengths = self.mesh_manager.dispatch(
@@ -970,17 +1044,17 @@ class DeviceEncodeDispatcher:
                     ((0, padded_b - b),) + ((0, 0),) * (batch.ndim - 1),
                 )
             sharded = shard_batch(mesh, batch)
-            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
+            jax.block_until_ready(sharded)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the pull worker
             stamps["h2d"] = marks.next("hist")
             flat, counts, extras = sharded_filter_histogram(
                 mesh, sharded, rows, row_bytes, bpp,
                 filter_mode=filter_mode,
             )
-            counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the dynamic host hop (pass-1 counts, a few KB)
+            counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- pull worker: the dynamic host hop (pass-1 counts, a few KB)
             stamps["hist"] = marks.next("emit")
             tables = build_dynamic_tables(counts_np, extras_np, real=b)
             out = sharded_dynamic_emit(mesh, flat, tables)
-            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+            return jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- pull worker: the one thread that waits on device completion
 
         try:
             streams, lengths = self.mesh_manager.dispatch(
@@ -1001,7 +1075,7 @@ class DeviceEncodeDispatcher:
             color_type,
         )
 
-    # -- mesh-fused super-tile (readback worker) -----------------------
+    # -- mesh-fused super-tile (pull worker) ---------------------------
 
     def submit_supertile(
         self,
@@ -1031,16 +1105,16 @@ class DeviceEncodeDispatcher:
         )
 
     def _stage_supertile_group(
-        self, gid, stack, index_tables, color_luts, rel_rects,
-        tile_w, tile_h, filter_mode, deflate_mode, lanes, route=None,
+        self, group, stack, index_tables, color_luts, rel_rects,
+        tile_w, tile_h, filter_mode, deflate_mode, lanes,
     ):
         # mesh-only entry point (the pipeline routes single-device
         # groups through composite_carve_batch + submit instead);
-        # like every sharded group it runs wholly on the readback
+        # like every sharded group it runs wholly on the pull
         # worker so the blocking dispatch stays inside MeshManager
-        return self._readback.submit(
-            self._tid_bound(self._mesh_supertile_group),
-            gid, stack, index_tables, color_luts, rel_rects,
+        return self._hand(
+            group.pipe.pull, group, self._mesh_supertile_group,
+            group.gid, stack, index_tables, color_luts, rel_rects,
             tile_w, tile_h, filter_mode, deflate_mode, lanes,
         )
 
@@ -1048,7 +1122,7 @@ class DeviceEncodeDispatcher:
         self, gid, stack, index_tables, color_luts, rel_rects,
         tile_w, tile_h, filter_mode, deflate_mode, lanes,
     ):
-        """One mesh-fused super-tile on the readback worker: plan the
+        """One mesh-fused super-tile on the pull worker: plan the
         per-chip overlapped windows, slice them out of the staged
         stack, and run composite + carve + filter + deflate as one
         sharded program. The result rows come back chip-major with
@@ -1082,14 +1156,14 @@ class DeviceEncodeDispatcher:
             ])
             sub_dev = jnp.asarray(sub)
             coords_dev = jnp.asarray(coords)
-            jax.block_until_ready(sub_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the readback worker
+            jax.block_until_ready(sub_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary on the pull worker
             stamps["h2d"] = marks.next("compute")
             out = sharded_supertile_carve_deflate(
                 mesh, sub_dev, index_tables, color_luts, coords_dev,
                 tile_h, tile_w, filter_mode=filter_mode,
                 deflate_mode=deflate_mode,
             )
-            out = jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
+            out = jax.block_until_ready(out)  # ompb-lint: disable=jax-hotpath -- pull worker: the one thread that waits on device completion
             return out, rows_map
 
         try:
@@ -1109,12 +1183,12 @@ class DeviceEncodeDispatcher:
         # kept rows' streams bounded by their true max
         with self._stage("d2h", gid, n_lanes):
             sel = np.asarray(rows_map, dtype=np.int64)
-            lengths_np = np.asarray(jax.device_get(lengths))[sel]  # ompb-lint: disable=jax-hotpath -- readback worker: lengths pull, a few bytes per lane
+            lengths_np = np.asarray(jax.device_get(lengths))[sel]  # ompb-lint: disable=jax-hotpath -- pull worker: lengths pull, a few bytes per lane
             full_cap = streams.shape[1]
             max_len = int(lengths_np.max()) if len(lanes) else 0
             cap = min(full_cap, 1 << max(max_len - 1, 0).bit_length())
             streams_np = np.asarray(
-                jax.device_get(streams[:, :cap])  # ompb-lint: disable=jax-hotpath -- readback worker: the one bounded streams pull for the group
+                jax.device_get(streams[:, :cap])  # ompb-lint: disable=jax-hotpath -- pull worker: the one bounded streams pull for the group
             )[sel]
             with self._stats_lock:
                 self._dd_cap[(tile_w, tile_h)] = min(
@@ -1222,53 +1296,61 @@ class DeviceEncodeDispatcher:
             except Exception:
                 log.exception("mesh warmup failed for %s", key)
 
-    # -- readback (readback worker) ------------------------------------
+    # -- plan (plan worker) --------------------------------------------
 
-    def _dynamic_readback_group(
-        self, flat, counts, extras, real_b, hist: _Span, lanes, sizes,
-        bit_depth, color_type, route: Optional[_Route] = None,
-    ) -> Dict[int, bytes]:
-        """Dynamic mode pass 2 on the readback worker: pull the pass-1
-        counts (absorbing the histogram program's wait), build the
-        canonical code tables on host (real lanes only — pad lanes
-        keep the fixed defaults), launch + block on the emit program,
-        then the shared pull/frame tail."""
+    def _plan_group(
+        self, group: _Group, flat, counts, extras, real_b, hist: _Span,
+        lanes, sizes, bit_depth, color_type,
+    ) -> None:
+        """Dynamic mode between its passes, on the plan worker: pull
+        the pass-1 counts (absorbing the histogram program's wait),
+        build the canonical code tables on host (real lanes only — pad
+        lanes keep the fixed defaults), launch the emit program and
+        hand the group to the pull worker WITHOUT waiting for it: the
+        next group's plan runs while the chip emits this one."""
         import jax
 
-        from ..ops.device_deflate import dynamic_emit_batch
-
-        gid = hist.gid
-        chip = None if route is None else route.chip
-        with hist:  # started at the launch, on the submit thread
-            counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion (pass-1 counts, a few KB)
-        with self._stage("emit", gid, hist.lanes, chip) as emit:
-            streams, lengths = dynamic_emit_batch(
-                flat, counts_np, extras_np, real=real_b,
-            )
-            jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        self._note_compute_done(emit.t1, emit.t1 - hist.t0, route)
-        return self._pull_and_frame(
-            streams, lengths, gid, lanes, sizes, bit_depth,
-            color_type, chip,
+        from ..ops.device_deflate import (
+            build_dynamic_tables,
+            dynamic_emit_planned,
         )
 
+        gid, n, chip = group.gid, hist.lanes, group.chip
+        with hist:  # started at the launch, on the submit thread
+            counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- plan worker: the dynamic host hop (pass-1 counts, a few KB); the one wait of this thread
+        with self._stage("plan", gid, n, chip):
+            tables = build_dynamic_tables(counts_np, extras_np, real=real_b)
+        streams, lengths = dynamic_emit_planned(flat, tables, real=real_b)
+        # launch -> seen done; the pull worker ends it
+        emit = self._stage("emit", gid, n, chip)
+        self._note_last_launch(emit.t0, group)
+        self._hand(
+            group.pipe.pull, group, self._readback_group,
+            group, streams, lengths, emit, lanes, sizes, bit_depth,
+            color_type,
+        )
+
+    # -- readback (pull worker) ----------------------------------------
+
     def _readback_group(
-        self, streams, lengths, compute: _Span, lanes, sizes,
-        bit_depth, color_type, route: Optional[_Route] = None,
+        self, group: _Group, streams, lengths, last: _Span, lanes, sizes,
+        bit_depth, color_type,
     ) -> Dict[int, bytes]:
-        """Runs on the readback worker: wait for the device, pull the
+        """Runs on the pull worker: wait for the group's last program
+        (``last``: a single-pass group's ``compute``, a dynamic
+        group's ``emit``), give the slot back — the device is done
+        with the group, what is left is the host's — pull the
         compressed bytes in ONE sync, frame the PNGs."""
         import jax
 
         # intended stage boundary: this thread EXISTS to absorb the
-        # device wait so submitters never do
-        gid = compute.gid
-        with compute:  # started at the launch, on the submit thread
-            jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- readback worker: the one thread that waits on device completion
-        self._note_compute_done(compute.t1, compute.t1 - compute.t0, route)
+        # device wait so submitters and planners never do
+        with last:  # started at the launch, on the submit or plan worker
+            jax.block_until_ready((streams, lengths))  # ompb-lint: disable=jax-hotpath -- pull worker: the one thread that waits on device completion
+        self._device_done(group, last.t1)
         return self._pull_and_frame(
-            streams, lengths, gid, lanes, sizes, bit_depth,
-            color_type, None if route is None else route.chip,
+            streams, lengths, last.gid, lanes, sizes, bit_depth,
+            color_type, group.chip,
         )
 
     def _pull_and_frame(
@@ -1290,7 +1372,7 @@ class DeviceEncodeDispatcher:
         real = len(lanes)
         with self._stage("d2h", gid, real, chip):
             if chip is not None:
-                lengths_np, streams_np = jax.device_get((lengths, streams))  # ompb-lint: disable=jax-hotpath -- readback worker: the one pull of the group
+                lengths_np, streams_np = jax.device_get((lengths, streams))  # ompb-lint: disable=jax-hotpath -- pull worker: the one pull of the group
             else:
                 lengths_np, streams_np = self._pull_to_guess(
                     streams, lengths, real, sizes[0]

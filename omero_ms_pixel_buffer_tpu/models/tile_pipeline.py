@@ -255,7 +255,7 @@ class TilePipeline:
         # deflate tail pull lengths AND stream bytes in ONE host sync
         self._dd_cap: Dict[Tuple[int, int], int] = {}
         # streaming device-encode queue (built lazily on the first
-        # device-deflate batch; owns the submit + readback workers)
+        # device-deflate batch; owns the submit, plan + pull workers)
         self._dispatcher = None
         # persistent XLA compilation cache: an operator-configured dir
         # (config `jax.compilation-cache-dir`) engages at construction
@@ -1159,7 +1159,7 @@ class TilePipeline:
             try:
                 # audited: handle_batch runs on a BATCHER executor
                 # thread and the future resolves on the dispatcher's
-                # readback pool — distinct pools, no self-deadlock
+                # pull pool — distinct pools, no self-deadlock
                 with stage_all([ctxs[i] for i in idxs], "device"):
                     group = fut.result()  # ompb-lint: disable=loop-block -- executor-thread wait on a different pool
                 for i, png in group.items():
@@ -1214,7 +1214,7 @@ class TilePipeline:
         placeholders and chain delivery onto the group future: device
         bytes distribute from the readback callback; a group failure
         submits the host fallback to the encode pool (never encoding
-        on the readback worker — it must stay free to drain the next
+        on the pull worker — it must stay free to drain the next
         group)."""
         lane_futs = {}
         for i in idxs:

@@ -98,7 +98,7 @@ RECORDS_DROPPED = REGISTRY.counter(
 # Ambient record: set by the HTTP front for the request's task,
 # carried into the batch executor via the batcher's copy_context(),
 # and re-scoped onto the device queue's worker threads per group
-# (record_scope in device_dispatch._run_stage / _tid_bound).
+# (record_scope in device_dispatch._run_stage / _on_worker).
 _current_record: contextvars.ContextVar[Optional["FlightRecord"]] = (
     contextvars.ContextVar("obs_record", default=None)
 )

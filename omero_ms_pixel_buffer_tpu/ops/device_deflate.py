@@ -868,7 +868,7 @@ def build_dynamic_tables(
     min(dynamic, fixed) costs no extra dispatch. Only the first
     ``real`` lanes get a host Huffman plan (pow2 PAD lanes keep the
     prefilled fixed tables — their streams are discarded, so building
-    codes for them would be pure waste on the readback worker).
+    codes for them would be pure waste on the plan worker).
     Returns the 9-tuple of arrays ``_zlib_dynamic`` takes."""
     b = counts.shape[0]
     hdr_b = np.zeros((b, _HDR_TOKENS), np.uint32)
@@ -1155,6 +1155,16 @@ def dynamic_emit_batch(
     tables = build_dynamic_tables(
         np.asarray(counts_np), np.asarray(extras_np), real=real
     )
+    return dynamic_emit_planned(flat, tables, real=real)
+
+
+def dynamic_emit_planned(
+    flat: jax.Array, tables: tuple, real: Optional[int] = None
+) -> tuple:
+    """Pass 2's single emit dispatch alone, from ``tables`` the caller
+    has built (``build_dynamic_tables``): the device queue plans as a
+    stage of its own and launches here without waiting. ``real``
+    slices the pow2 padding back off the outputs."""
     streams, lengths = _zlib_dynamic(flat, *tables)
     if real is not None:
         return streams[:real], lengths[:real]

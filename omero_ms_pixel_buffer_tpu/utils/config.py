@@ -101,9 +101,11 @@ class PngConfig:
     # "rle" (fixed Huffman, one dispatch), or "stored". Render lanes
     # always use "rle" (their host-mirror byte-identity contract).
     device_deflate_mode: str = "dynamic"
-    # Bounded in-flight encode groups in the streaming device queue:
-    # 2 keeps the classic double buffer; deeper queues absorb longer
-    # host stalls at the cost of HBM residency per in-flight group.
+    # Bounded encode groups ON THE DEVICE in the streaming device queue
+    # (a slot is held from staging to the group's last program seen
+    # done, not through the host's pull and frame): 2 keeps the classic
+    # double buffer; deeper queues absorb longer host stalls at the
+    # cost of HBM residency per in-flight group.
     queue_depth: int = 2
 
 

@@ -1,6 +1,7 @@
 """The device queue's stages and waits on two clocks
-(models/device_dispatch.py): `device_stage_seconds` keeps its six
-labels and one observation per stage per group; every stage is also a
+(models/device_dispatch.py): `device_stage_seconds` has seven labels
+(`plan`, the host's Huffman plan of a dynamic group, since PR 34) and
+one observation per stage per group; every stage is also a
 `jax.profiler.TraceAnnotation` `ompb.queue.<stage>` that carries the
 group's id; `device_queue_wait_seconds` gets one `pool` and one `slot`
 observation per group, whatever the stage function does (a wait is a
@@ -17,11 +18,11 @@ import pytest
 from omero_ms_pixel_buffer_tpu.models import device_dispatch as dd
 from omero_ms_pixel_buffer_tpu.obs.recorder import FlightRecord, record_scope
 
-STAGES = {"h2d", "compute", "hist", "emit", "d2h", "frame"}
+STAGES = {"h2d", "compute", "hist", "plan", "emit", "d2h", "frame"}
 PER_GROUP = {
     "rle": {"h2d", "compute", "d2h", "frame"},
     "stored": {"h2d", "compute", "d2h", "frame"},
-    "dynamic": {"h2d", "hist", "emit", "d2h", "frame"},
+    "dynamic": {"h2d", "hist", "plan", "emit", "d2h", "frame"},
 }
 rng = np.random.default_rng(5)
 
@@ -113,7 +114,7 @@ def test_each_stage_is_observed_once_per_group(disp, mode):
     assert moved == {stage: groups for stage in PER_GROUP[mode]}
 
 
-def test_the_family_has_exactly_the_six_stage_labels(disp):
+def test_the_family_has_exactly_the_seven_stage_labels(disp):
     for mode in PER_GROUP:
         submit(disp, tiles(), mode).result(timeout=120)
     assert set(counts(dd.DEVICE_STAGE_SECONDS, "stage")) == STAGES
@@ -144,16 +145,35 @@ def test_one_annotation_per_stage_and_wait_with_the_groups_id(
 
 def test_a_stage_that_spans_threads_is_opened_at_the_launch(disp, notes):
     """`hist` runs from the launch (submit thread) to the counts pull
-    (readback worker): one annotation over the whole interval."""
+    (plan worker), `emit` from its launch (plan worker) to the pull
+    worker seeing it done: one annotation over each whole interval."""
     submit(disp, tiles(), "dynamic").result(timeout=120)
     by_name = {n["name"]: n for n in notes.seen}
     hist = by_name["ompb.queue.hist"]
     assert hist["opened_on"].startswith("devenc-submit")
-    assert hist["closed_on"].startswith("devenc-readback")
+    assert hist["closed_on"].startswith("devenc-plan")
+    emit = by_name["ompb.queue.emit"]
+    assert emit["opened_on"].startswith("devenc-plan")
+    assert emit["closed_on"].startswith("devenc-pull")
     assert not any("wait" in name for name in by_name)  # histograms only
-    for stage in ("emit", "d2h", "frame"):
+    plan = by_name["ompb.queue.plan"]
+    assert plan["opened_on"] == plan["closed_on"] == hist["closed_on"]
+    for stage in ("d2h", "frame"):
         note = by_name[f"ompb.queue.{stage}"]
-        assert note["opened_on"] == note["closed_on"]
+        assert note["opened_on"] == note["closed_on"] == emit["closed_on"]
+
+
+def test_a_single_pass_group_never_sees_the_plan_worker(disp, notes):
+    """`rle` has no plan: submit thread to pull worker, no `plan`
+    observed and no thread of the plan pool started."""
+    before = counts(dd.DEVICE_STAGE_SECONDS, "stage")
+    submit(disp, tiles(), "rle").result(timeout=120)
+    moved = delta(before, counts(dd.DEVICE_STAGE_SECONDS, "stage"))
+    assert "plan" not in moved and "emit" not in moved
+    compute = {n["name"]: n for n in notes.seen}["ompb.queue.compute"]
+    assert compute["opened_on"].startswith("devenc-submit")
+    assert compute["closed_on"].startswith("devenc-pull")
+    assert not disp._pipe.plan._threads
 
 
 def test_waits_are_observed_once_per_group_also_when_staging_raises(
@@ -258,6 +278,8 @@ def test_mesh_groups_annotate_by_hand_and_keep_the_histogram(notes):
         assert sorted(n["name"] for n in notes.of_group(first)) == sorted(
             f"ompb.queue.{s}" for s in
             ("h2d", "compute", "d2h", "frame"))
+        # a mesh group's plan runs inside its managed dispatch, under
+        # `emit` as it always did: no stage of its own
         assert sorted(n["name"] for n in notes.of_group(first + 1)) == sorted(
             f"ompb.queue.{s}" for s in
             ("h2d", "hist", "emit", "d2h", "frame"))
@@ -292,7 +314,7 @@ def test_the_annotations_land_in_the_profilers_trace(disp, tmp_path):
                                          event.duration_ns)
     assert set(found) == {
         f"ompb.queue.{s}" for s in
-        ("h2d", "hist", "emit", "d2h", "frame")}
+        ("h2d", "hist", "plan", "emit", "d2h", "frame")}
     for stats, duration in found.values():
         assert stats["group"] == gid and stats["lanes"] == 2
         assert duration >= 0
