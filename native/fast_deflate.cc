@@ -331,14 +331,21 @@ inline uint32_t Reverse(uint32_t code, int len) {
 // Build code lengths for `n` symbols with the given frequencies, no
 // code longer than `limit`. Frequency-damping: halve-and-rebuild until
 // the tree fits the limit (converges fast; ratio impact negligible).
-void BuildLengths(const uint32_t* freq_in, int n, int limit,
-                  uint8_t* lengths) {
-  std::vector<uint32_t> freq(freq_in, freq_in + n);
+// `tie_by_id` breaks equal frequencies on (freq, id) — a leaf's id is
+// its symbol, an internal node's is n + its creation order, which is
+// exactly the order of `nodes` — so the tree is the one the device
+// plan's Python builder (ops/device_deflate._build_lengths_np) makes.
+// Without it ties fall where the heap leaves them, as the host
+// encoder's streams always have.
+template <typename F>
+void BuildLengths(const F* freq_in, int n, int limit, uint8_t* lengths,
+                  bool tie_by_id = false) {
+  std::vector<uint64_t> freq(freq_in, freq_in + n);
   std::memset(lengths, 0, n);
   for (;;) {
     // collect used symbols
     struct Node {
-      uint32_t f;
+      uint64_t f;
       int left, right, sym;  // sym >= 0 for leaves
     };
     std::vector<Node> nodes;
@@ -354,7 +361,10 @@ void BuildLengths(const uint32_t* freq_in, int n, int limit,
       lengths[nodes[0].sym] = 1;
       return;
     }
-    auto cmp = [&](int a, int b) { return nodes[a].f > nodes[b].f; };
+    auto cmp = [&](int a, int b) {
+      if (tie_by_id && nodes[a].f == nodes[b].f) return a > b;
+      return nodes[a].f > nodes[b].f;
+    };
     std::make_heap(heap.begin(), heap.end(), cmp);
     while (heap.size() > 1) {
       std::pop_heap(heap.begin(), heap.end(), cmp);
@@ -466,7 +476,104 @@ void EncodeCodeLengths(const uint8_t* lens, int n, std::vector<ClOp>* ops,
 const int kClOrder[19] = {16, 17, 18, 0, 8,  7, 9,  6, 10, 5,
                           11, 4,  12, 3, 13, 2, 14, 1, 15};
 
+// Fixed-Huffman code length of each lit/len symbol (RFC 1951 §3.2.6).
+inline int FixedSymLen(int s) {
+  return s < 144 ? 8 : s < 256 ? 9 : s < 280 ? 7 : 8;
+}
+
 }  // namespace
+
+bool DynamicPlanLane(const int64_t* counts, int64_t extra_bits, int hdr_cap,
+                     uint32_t* hdr_b, int32_t* hdr_n, uint32_t* lit_b,
+                     int32_t* lit_n, uint32_t* ml_b, int32_t* ml_n,
+                     uint32_t* eob_b, int32_t* eob_n) {
+  int64_t match_tokens = 0;
+  for (int s = 257; s < kNumLit; ++s) match_tokens += counts[s];
+  uint64_t freq[kNumLit];
+  for (int s = 0; s < kNumLit; ++s) freq[s] = static_cast<uint64_t>(counts[s]);
+  freq[256] = 1;  // end-of-block (pass 1 histograms payload tokens only)
+  uint8_t lit_len[kNumLit];
+  BuildLengths(freq, kNumLit, 15, lit_len, /*tie_by_id=*/true);
+
+  // exact totals: code bits per symbol + match extra bits + one 1-bit
+  // distance code per match (dynamic) or 5-bit fixed distance code
+  int64_t dyn_body = extra_bits + match_tokens + lit_len[256];
+  int64_t fixed_total = 3 + extra_bits + match_tokens * 5 + 7;
+  for (int s = 0; s < kNumLit; ++s) {
+    dyn_body += counts[s] * lit_len[s];
+    fixed_total += counts[s] * FixedSymLen(s);
+  }
+
+  int hlit = kNumLit;
+  while (hlit > 257 && lit_len[hlit - 1] == 0) hlit--;
+  uint8_t all_lens[kNumLit + 1];
+  std::memcpy(all_lens, lit_len, hlit);
+  all_lens[hlit] = match_tokens > 0 ? 1 : 0;  // the one distance code
+  std::vector<ClOp> ops;
+  ops.reserve(kNumLit + 1);
+  uint32_t cl_freq[19] = {0};
+  EncodeCodeLengths(all_lens, hlit + 1, &ops, cl_freq);
+  uint8_t cl_len[19];
+  BuildLengths(cl_freq, 19, 7, cl_len, /*tie_by_id=*/true);
+  int used = 0, only = 0;
+  for (int s = 0; s < 19; ++s) {
+    if (cl_len[s]) {
+      used++;
+      only = s;
+    }
+  }
+  // a lone 1-bit CL code is an incomplete tree, which inflate rejects:
+  // a dummy 1-bit code on an unused symbol completes it
+  if (used == 1) cl_len[only != 0 ? 0 : 1] = 1;
+  uint32_t cl_code[19] = {0};
+  BuildCodes(cl_len, 19, 7, cl_code);
+  int hclen = 19;
+  while (hclen > 4 && cl_len[kClOrder[hclen - 1]] == 0) hclen--;
+
+  const int ntok = 4 + hclen + static_cast<int>(ops.size());
+  if (ntok > hdr_cap) return false;
+  int64_t hdr_bits = 3 + 5 + 5 + 4 + 3 * hclen;
+  for (const ClOp& op : ops) hdr_bits += cl_len[op.sym] + op.extra_bits;
+  if (hdr_bits + dyn_body >= fixed_total) return false;
+
+  int t = 0;
+  auto put = [&](uint32_t v, int nb) {
+    hdr_b[t] = v;
+    hdr_n[t++] = nb;
+  };
+  put(5, 3);  // BFINAL=1, BTYPE=10
+  put(static_cast<uint32_t>(hlit - 257), 5);
+  put(0, 5);  // HDIST - 1: one distance code
+  put(static_cast<uint32_t>(hclen - 4), 4);
+  for (int k = 0; k < hclen; ++k) put(cl_len[kClOrder[k]], 3);
+  for (const ClOp& op : ops) {
+    const int cn = cl_len[op.sym];
+    put(cl_code[op.sym] | (static_cast<uint32_t>(op.extra_val) << cn),
+        cn + op.extra_bits);
+  }
+  for (; t < hdr_cap; ++t) hdr_b[t] = hdr_n[t] = 0;
+
+  uint32_t lit_code[kNumLit] = {0};
+  BuildCodes(lit_len, kNumLit, 15, lit_code);
+  for (int s = 0; s < 256; ++s) {
+    lit_b[s] = lit_code[s];
+    lit_n[s] = lit_len[s];
+  }
+  const LenCode* len_table = LengthTable();
+  for (int ln = 0; ln <= kMaxRun; ++ln) {
+    ml_b[ln] = 0;
+    ml_n[ln] = 0;
+    if (ln < 3) continue;
+    const LenCode& lc = len_table[ln];
+    const int cn = lit_len[lc.sym];
+    if (cn == 0) continue;  // symbol absent from this lane
+    ml_b[ln] = lit_code[lc.sym] | (static_cast<uint32_t>(lc.extra_val) << cn);
+    ml_n[ln] = cn + lc.extra_bits + 1;  // + the 1-bit distance-1 code
+  }
+  *eob_b = lit_code[256];
+  *eob_n = lit_len[256];
+  return true;
+}
 
 size_t FastDeflate(const uint8_t* in, size_t n, uint8_t* out, size_t cap) {
   if (cap < 64) return 0;

@@ -360,8 +360,9 @@ bool LzwDecode(const uint8_t* in, size_t in_len, uint8_t* out, size_t cap,
 extern "C" {
 
 // ABI history: v2 zlib-strategy arg + fused PNG encode; v3 per-block
-// codec dispatch; v4 JPEG entropy-scan decoder (jpeg_scan.cc)
-int ompb_version() { return 4; }
+// codec dispatch; v4 JPEG entropy-scan decoder (jpeg_scan.cc); v5 the
+// device deflate's dynamic-Huffman plan (ompb_dynamic_plan_batch)
+int ompb_version() { return 5; }
 
 int ompb_pool_size() { return static_cast<int>(Pool().size()); }
 
@@ -583,6 +584,30 @@ int ompb_png_encode_batch(int n, const uint8_t** tiles,
     out_lens[i] = total;
   });
   return failed.load();
+}
+
+// The dynamic-Huffman plan of the first `real` lanes of a device group,
+// into the caller's (numpy) emit tables, which hold the fixed tables on
+// entry: a lane whose dynamic code wins gets its rows rewritten, any
+// other lane (and every lane from `real` on) keeps them. counts is
+// (lanes, 286), extras (lanes,); hdr_b/hdr_n are (lanes, hdr_cap),
+// lit_* (lanes, 256), ml_* (lanes, 259), eob_* (lanes,). Runs on the
+// calling thread (tens of microseconds a lane), not the pool, so a plan
+// never queues behind host encodes.
+void ompb_dynamic_plan_batch(int real, int hdr_cap, const int64_t* counts,
+                             const int64_t* extras, uint32_t* hdr_b,
+                             int32_t* hdr_n, uint32_t* lit_b, int32_t* lit_n,
+                             uint32_t* ml_b, int32_t* ml_n, uint32_t* eob_b,
+                             int32_t* eob_n) {
+  constexpr int kLit = 286, kCodes = 256, kLens = 259;
+  for (int i = 0; i < real; ++i) {
+    const size_t h = static_cast<size_t>(i) * hdr_cap;
+    ompb::DynamicPlanLane(counts + static_cast<size_t>(i) * kLit, extras[i],
+                          hdr_cap, hdr_b + h, hdr_n + h,
+                          lit_b + i * kCodes, lit_n + i * kCodes,
+                          ml_b + i * kLens, ml_n + i * kLens, eob_b + i,
+                          eob_n + i);
+  }
 }
 
 }  // extern "C"

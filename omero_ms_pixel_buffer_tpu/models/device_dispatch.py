@@ -352,6 +352,9 @@ class DeviceEncodeDispatcher:
         self._overlapped = 0
         self._compute_sum = 0.0
         self._computes = 0
+        # real lanes of served dynamic groups, by the implementation
+        # that planned them (device_deflate.plan_impl)
+        self._plan_lanes = {"native": 0, "python": 0}
         # mesh warmup state: recently-seen raw-tile group shapes +
         # widths already warmed (tests read _warmed)
         self._seen_mesh: Dict[tuple, None] = {}
@@ -498,6 +501,14 @@ class DeviceEncodeDispatcher:
         self._note_group(t_launch, lanes, group)
         self._note_last_launch(t_launch, group)
 
+    def _note_plan(self, lanes: int) -> None:
+        """A served dynamic group's ``lanes`` real lanes are planned."""
+        from ..ops.device_deflate import plan_impl
+
+        impl = plan_impl()
+        with self._stats_lock:
+            self._plan_lanes[impl] += lanes
+
     def _note_compute_done(self, t_done: float, dt: float) -> None:
         """A mesh method's blocking dispatch is back (the process's
         pipe; its slot stays until the method returns)."""
@@ -565,6 +576,8 @@ class DeviceEncodeDispatcher:
                     round(self._compute_sum / self._computes * 1e3, 3)
                     if self._computes else None
                 ),
+                "plan_lanes_native": self._plan_lanes["native"],
+                "plan_lanes_python": self._plan_lanes["python"],
             }
             if self._chip_pipe is not None:  # groups name their chip
                 out["chips"] = [
@@ -1066,6 +1079,7 @@ class DeviceEncodeDispatcher:
         t_h2d = stamps.get("h2d", t0)
         t_hist = stamps.get("hist", t_h2d)
         self._note_launch(t_h2d, len(lanes))
+        self._note_plan(len(lanes))  # once, however often a retry planned
         _observe_stage(t_h2d - t0, "h2d")
         _observe_stage(t_hist - t_h2d, "hist")
         _observe_stage(t_ready - t_hist, "emit")
@@ -1320,6 +1334,7 @@ class DeviceEncodeDispatcher:
             counts_np, extras_np = jax.device_get((counts, extras))  # ompb-lint: disable=jax-hotpath -- plan worker: the dynamic host hop (pass-1 counts, a few KB); the one wait of this thread
         with self._stage("plan", gid, n, chip):
             tables = build_dynamic_tables(counts_np, extras_np, real=real_b)
+        self._note_plan(real_b)
         streams, lengths = dynamic_emit_planned(flat, tables, real=real_b)
         # launch -> seen done; the pull worker ends it
         emit = self._stage("emit", gid, n, chip)

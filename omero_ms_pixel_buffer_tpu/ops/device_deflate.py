@@ -858,6 +858,21 @@ def _lane_dynamic_plan(counts: np.ndarray, extra_bits: int):
     )
 
 
+def _native_planner():
+    """The native engine when it carries the plan (ABI v5), else None:
+    ``build_dynamic_tables`` then plans in Python."""
+    from ..runtime.native import get_engine
+
+    engine = get_engine()
+    return engine if engine is not None and engine.has_dynamic_plan else None
+
+
+def plan_impl() -> str:
+    """Which implementation ``build_dynamic_tables`` plans lanes with:
+    ``"native"`` or ``"python"``."""
+    return "python" if _native_planner() is None else "native"
+
+
 def build_dynamic_tables(
     counts: np.ndarray, extras: np.ndarray, real: Optional[int] = None
 ):
@@ -868,8 +883,11 @@ def build_dynamic_tables(
     min(dynamic, fixed) costs no extra dispatch. Only the first
     ``real`` lanes get a host Huffman plan (pow2 PAD lanes keep the
     prefilled fixed tables — their streams are discarded, so building
-    codes for them would be pure waste on the plan worker).
-    Returns the 9-tuple of arrays ``_zlib_dynamic`` takes."""
+    codes for them would be pure waste on the plan worker). The plan
+    is one GIL-released native call when the engine has it, with
+    tables bit-identical to ``_lane_dynamic_plan``'s, which plans
+    otherwise. Returns the 8-tuple of tables ``_zlib_dynamic`` takes
+    after the payloads."""
     b = counts.shape[0]
     hdr_b = np.zeros((b, _HDR_TOKENS), np.uint32)
     hdr_n = np.zeros((b, _HDR_TOKENS), np.int32)
@@ -882,7 +900,14 @@ def build_dynamic_tables(
     ml_n = np.tile(_MATCH_NBITS, (b, 1))
     eob_b = np.zeros(b, np.uint32)
     eob_n = np.full(b, 7, np.int32)  # fixed EOB: 7-bit all-zero code
-    for i in range(b if real is None else min(real, b)):
+    tables = hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
+    real = b if real is None else max(min(real, b), 0)
+    engine = _native_planner()
+    if engine is not None:
+        if real:
+            engine.dynamic_plan_batch(counts, extras, real, tables)
+        return tables
+    for i in range(real):
         plan = _lane_dynamic_plan(counts[i], int(extras[i]))
         if plan is None:
             continue  # fixed wins: the prefilled tables ARE the plan
@@ -893,7 +918,7 @@ def build_dynamic_tables(
         lit_b[i], lit_n[i] = lcode, llen
         ml_b[i], ml_n[i] = mbits, mnbits
         eob_b[i], eob_n[i] = ebits, elen
-    return hdr_b, hdr_n, lit_b, lit_n, ml_b, ml_n, eob_b, eob_n
+    return tables
 
 
 @kernel("ompb_tokens")

@@ -106,6 +106,15 @@ class NativeEngine:
         self.has_crc32c = hasattr(lib, "ompb_crc32c")
         if self.has_crc32c:
             lib.ompb_crc32c.restype = ctypes.c_uint32
+        # ABI v5 added the device deflate's dynamic-Huffman plan
+        self.has_dynamic_plan = self.version >= 5 and hasattr(
+            lib, "ompb_dynamic_plan_batch"
+        )
+        if self.has_dynamic_plan:
+            lib.ompb_dynamic_plan_batch.restype = None
+            lib.ompb_dynamic_plan_batch.argtypes = (
+                [ctypes.c_int] * 2 + [ctypes.c_void_p] * 10
+            )
         self.pool_size = lib.ompb_pool_size()
 
     # -- helpers -----------------------------------------------------------
@@ -257,6 +266,33 @@ class NativeEngine:
         """CRC-32C over ``data`` (zarr v3 checksum codec)."""
         return int(
             self._lib.ompb_crc32c(data, ctypes.c_size_t(len(data)))
+        )
+
+    def dynamic_plan_batch(
+        self, counts: np.ndarray, extras: np.ndarray, real: int, tables
+    ) -> None:
+        """The dynamic-Huffman plan of the first ``real`` lanes, written
+        into ``tables`` — the eight emit arrays ``build_dynamic_tables``
+        allocates and prefills with the fixed code — in ONE
+        GIL-released call. Requires ``has_dynamic_plan``."""
+        cap = tables[0].shape[1]  # header tokens a lane
+        rows = [(cap,), (cap,), (256,), (256,), (259,), (259,), (), ()]
+        for k, (arr, row) in enumerate(zip(tables, rows)):
+            # C writes through these pointers: a wrong layout would
+            # corrupt the heap, so it is a hard error, never an assert
+            if (
+                arr.dtype != (np.uint32 if k % 2 == 0 else np.int32)
+                or not arr.flags["C_CONTIGUOUS"]
+                or arr.shape[1:] != row or arr.shape[0] < real
+            ):
+                raise ValueError(f"plan table {k} of the wrong layout")
+        counts = np.ascontiguousarray(counts[:real], dtype=np.int64)
+        extras = np.ascontiguousarray(extras[:real], dtype=np.int64)
+        if counts.shape != (real, 286) or extras.shape != (real,):
+            raise ValueError("plan counts of the wrong shape")
+        self._lib.ompb_dynamic_plan_batch(
+            real, cap, counts.ctypes.data, extras.ctypes.data,
+            *(a.ctypes.data for a in tables),
         )
 
     def jpeg_scan(

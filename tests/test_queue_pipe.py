@@ -19,6 +19,7 @@ from omero_ms_pixel_buffer_tpu.ops import device_deflate, png
 from omero_ms_pixel_buffer_tpu.ops.png import decode_png
 from omero_ms_pixel_buffer_tpu.resilience import INJECTOR
 from omero_ms_pixel_buffer_tpu.resilience.faultinject import always
+from omero_ms_pixel_buffer_tpu.runtime import native
 
 WAIT = 120  # seconds: every wait of this file is bounded
 N = 16
@@ -192,6 +193,9 @@ def break_stage(monkeypatch, site, exc):
     elif site == "plan":
         monkeypatch.setattr(
             device_deflate, "build_dynamic_tables", raising(exc))
+    elif site == "native plan":  # inside the engine's one plan call
+        monkeypatch.setattr(native.NativeEngine, "dynamic_plan_batch",
+                            raising(exc))
     elif site == "emit-launch":
         monkeypatch.setattr(
             device_deflate, "dynamic_emit_planned", raising(exc))
@@ -207,7 +211,8 @@ def break_stage(monkeypatch, site, exc):
         raise AssertionError(site)
 
 
-SITES = ["staging", "hist", "plan", "emit-launch", "wait", "pull", "frame"]
+SITES = ["staging", "hist", "plan", "native plan", "emit-launch", "wait",
+         "pull", "frame"]
 
 
 @pytest.mark.parametrize("width", [1, 4])
@@ -230,7 +235,8 @@ def test_a_storm_of_failures_gives_every_slot_back_exactly_once(
         storm = [(submit(disp, tiles(seed), mode, chip), mode, seed)
                  for seed in range(5) for mode in ("dynamic", "rle")]
         for fut, mode, seed in storm:
-            if mode == "rle" and site in ("hist", "plan", "emit-launch"):
+            if mode == "rle" and site in (
+                    "hist", "plan", "native plan", "emit-launch"):
                 assert_pngs(fut.result(timeout=WAIT), tiles(seed))
             else:
                 with pytest.raises(RuntimeError, match=f"{site} failed"):
