@@ -79,6 +79,7 @@ from ..tile_ctx import TileCtx
 from ..utils.config import Config
 from ..utils.loop_watchdog import LoopWatchdog
 from ..utils.metrics import REGISTRY
+from ..utils.process_metrics import mark_loop_thread
 from ..utils.tracing import TRACER, configure as configure_tracing
 
 log = logging.getLogger("omero_ms_pixel_buffer_tpu.http")
@@ -1243,6 +1244,7 @@ class PixelBufferApp:
         return None if disp is None else disp.mesh_manager
 
     async def _on_startup(self, app) -> None:
+        mark_loop_thread()  # thread_cpu_seconds_total{role="loop"}
         if self.watchdog is not None:
             self.watchdog.start()  # on the serving loop's thread
         await self.worker.start()

@@ -128,18 +128,20 @@ def _observe_wait(duration: float, where: str) -> None:
     DEVICE_QUEUE_WAIT_SECONDS.observe(duration, where=where)
 
 
-def _annotation(what: str, gid: int, lanes: int, chip=None):
+def _annotation(what: str, gid: int, lanes: int, chip=None, **more):
     """``jax.profiler.TraceAnnotation`` ``ompb.queue.<what>``, started
     here and ended by its ``__exit__`` (on any thread). It lands on the
     host plane of the profiler's trace, on the clock of the device's
     operations, so a device idle gap names the stage of the group the
     host was in; with no profiler running it is an atomic flag test.
-    A group sent to a named chip carries the stat ``chip``."""
+    A group sent to a named chip carries the stat ``chip``; ``more``
+    adds stats (the launch's ``program``)."""
     from jax.profiler import TraceAnnotation
 
     stats = {"group": gid, "lanes": lanes}
     if chip is not None:
         stats["chip"] = chip
+    stats.update(more)
     return TraceAnnotation(f"ompb.queue.{what}", **stats)
 
 
@@ -205,18 +207,61 @@ class _Marks:
         self._open.__exit__(None, None, None)
 
 
+# a family of its own, like the waits: a launch is no stage, and
+# group_ms sums every stage of device_stage_seconds
+DEVICE_LAUNCH_SECONDS = REGISTRY.histogram(
+    "device_launch_seconds",
+    "Wall time of one device program's launch call on the thread that "
+    "makes it (program=hist|emit|compute|render)",
+)
+DEVICE_LAUNCH_CPU_SECONDS = REGISTRY.histogram(
+    "device_launch_cpu_seconds",
+    "CPU time of the launching thread over the same launch call "
+    "(program=hist|emit|compute|render); wall less CPU is the call's "
+    "wait: for the GIL, a lock of the runtime, the device's queue",
+)
+
+
+class _Launch:
+    """``with _Launch(program, gid, n, chip):`` around one launch
+    call, opened and closed on the calling thread: the annotation
+    ``ompb.queue.launch`` (stats ``group``, ``lanes``, ``chip``,
+    ``program``) and, unless the call raises, one observation each of
+    ``device_launch_seconds`` and ``device_launch_cpu_seconds``. The
+    wall clock is read outside the thread's CPU clock on both ends, so
+    the CPU time is never the longer."""
+
+    __slots__ = ("program", "_stats", "_note", "_t0", "_c0")
+
+    def __init__(self, program: str, gid: int, lanes: int, chip=None):
+        self.program = program
+        self._stats = (gid, lanes, chip)
+
+    def __enter__(self) -> "_Launch":
+        # like _Span's, the annotation starts as it is built
+        self._note = _annotation(
+            "launch", *self._stats, program=self.program
+        )
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        c1 = time.thread_time()
+        t1 = time.perf_counter()
+        self._note.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            DEVICE_LAUNCH_SECONDS.observe(t1 - self._t0, program=self.program)
+            DEVICE_LAUNCH_CPU_SECONDS.observe(
+                c1 - self._c0, program=self.program
+            )
+
+
 DEVICE_GROUP_LANES = REGISTRY.histogram(
     "device_group_lanes",
     "Real lanes of each encode group launched on the device, before "
     "the padding to its program's lane count",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 64, float("inf")),
-)
-
-DEVICE_QUEUE_IDLE_SECONDS = REGISTRY.histogram(
-    "device_queue_idle_seconds",
-    "Device idle gap between the pipe's last in-flight group being "
-    "seen done and the next launch of a group's last program "
-    "(0-bucketed when that launch found one still in flight)",
 )
 
 # how many distinct mesh group shapes the width-change warmup replays
@@ -483,13 +528,11 @@ class DeviceEncodeDispatcher:
         with self._stats_lock:
             if pipe.emitting:
                 self._overlapped += 1
-                DEVICE_QUEUE_IDLE_SECONDS.observe(0.0)
             elif pipe.idle_since is not None:
                 gap = max(t_launch - pipe.idle_since, 0.0)
                 self._idle_gaps += 1
                 self._idle_gap_sum += gap
                 self._idle_gap_max = max(self._idle_gap_max, gap)
-                DEVICE_QUEUE_IDLE_SECONDS.observe(gap)
             pipe.emitting += 1
             if group is not None:
                 group.emitting = True
@@ -808,10 +851,11 @@ class DeviceEncodeDispatcher:
         if deflate_mode == "dynamic":
             from ..ops.device_deflate import fused_filter_histogram_batch
 
-            flat, counts, extras, real_b = fused_filter_histogram_batch(
-                batch_dev, rows, row_bytes, bpp, filter_mode=filter_mode,
-                donate=self._donate_ok(),
-            )
+            with _Launch("hist", gid, n, chip):
+                flat, counts, extras, real_b = fused_filter_histogram_batch(
+                    batch_dev, rows, row_bytes, bpp,
+                    filter_mode=filter_mode, donate=self._donate_ok(),
+                )
             # the stage starts at the launch, here; the plan worker
             # ends it when it has pulled the counts
             hist = self._stage("hist", gid, n, chip)
@@ -826,11 +870,12 @@ class DeviceEncodeDispatcher:
             )
         from ..ops.device_deflate import fused_filter_deflate_batch
 
-        streams, lengths = fused_filter_deflate_batch(
-            batch_dev, rows, row_bytes, bpp,
-            filter_mode=filter_mode, mode=deflate_mode,
-            donate=self._donate_ok(),
-        )
+        with _Launch("compute", gid, n, chip):
+            streams, lengths = fused_filter_deflate_batch(
+                batch_dev, rows, row_bytes, bpp,
+                filter_mode=filter_mode, mode=deflate_mode,
+                donate=self._donate_ok(),
+            )
         compute = self._stage("compute", gid, n, chip)  # launch -> ready
         self._note_launch(compute.t0, n, group)
         return self._hand(
@@ -872,11 +917,12 @@ class DeviceEncodeDispatcher:
                 # blocking on the INPUT transfer only: earlier groups'
                 # compute keeps the device busy meanwhile
                 jax.block_until_ready(batch_dev)  # ompb-lint: disable=jax-hotpath -- H2D stage boundary: waits on the transfer engine, overlapped with earlier groups' compute
-        streams, lengths = fused_render_filter_deflate_batch(
-            batch_dev, index_tables, color_luts, rows, row_bytes,
-            filter_mode=filter_mode, mode=deflate_mode,
-            mask=mask_dev,
-        )
+        with _Launch("render", gid, n):
+            streams, lengths = fused_render_filter_deflate_batch(
+                batch_dev, index_tables, color_luts, rows, row_bytes,
+                filter_mode=filter_mode, mode=deflate_mode,
+                mask=mask_dev,
+            )
         compute = self._stage("compute", gid, n)  # launch -> ready
         self._note_launch(compute.t0, n, group)
         return self._hand(
@@ -1335,7 +1381,10 @@ class DeviceEncodeDispatcher:
         with self._stage("plan", gid, n, chip):
             tables = build_dynamic_tables(counts_np, extras_np, real=real_b)
         self._note_plan(real_b)
-        streams, lengths = dynamic_emit_planned(flat, tables, real=real_b)
+        with _Launch("emit", gid, n, chip):
+            streams, lengths = dynamic_emit_planned(
+                flat, tables, real=real_b
+            )
         # launch -> seen done; the pull worker ends it
         emit = self._stage("emit", gid, n, chip)
         self._note_last_launch(emit.t0, group)

@@ -26,6 +26,15 @@ Two halves, mirroring the Vert.x design:
 Blocked detection is edge-triggered (one log per stall, plus one on
 recovery with the measured duration) so a long stall doesn't flood the
 log at the check frequency. ``snapshot()`` feeds ``/healthz``.
+
+The checker thread is also the process's GIL probe: it wakes every
+``PROBE_S`` (20 ms) and observes how late it woke against its
+schedule into ``gil_wait_seconds``. A sleeping thread has to take the
+GIL back before it runs again, so its lateness is the GIL's queue (the
+interpreter hands the GIL over every 5 ms; a C call that never
+releases it holds every other thread for as long as it runs) plus the
+OS's wake latency, microseconds. The stall check runs on the same
+thread on its own period, as it always did.
 """
 
 from __future__ import annotations
@@ -54,6 +63,16 @@ LOOP_MAX_LAG = REGISTRY.gauge(
     "event_loop_max_lag_seconds",
     "Largest heartbeat lag observed since start",
 )
+GIL_WAIT = REGISTRY.histogram(
+    "gil_wait_seconds",
+    "How much later than scheduled the watchdog thread woke: the wait "
+    "to take the GIL back, and the OS's wake latency",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 0.5, 1.0, float("inf")),
+)
+
+# the GIL probe's period: the watchdog thread wakes this often
+PROBE_S = 0.02
 
 
 class LoopWatchdog:
@@ -118,26 +137,39 @@ class LoopWatchdog:
         # check twice per threshold: worst-case detection latency is
         # warn_after_s * 1.5 without busy-spinning
         period = max(self.warn_after_s / 2.0, 0.01)
-        while not self._stop.wait(period):
-            last_beat, _lag = self._beat
-            stalled_s = time.monotonic() - last_beat - self.interval_s
-            if stalled_s >= self.warn_after_s:
-                if self._blocked_since is None:
-                    self._blocked_since = last_beat
-                    self._blocked_events += 1
-                    LOOP_BLOCKED.inc()
-                    log.warning(
-                        "event loop blocked for >= %.0f ms — current "
-                        "loop-thread stack:\n%s",
-                        stalled_s * 1000, self._loop_stack(),
-                    )
-            elif self._blocked_since is not None:
-                duration = time.monotonic() - self._blocked_since
-                self._blocked_since = None
+        due = time.monotonic() + PROBE_S
+        next_check = time.monotonic() + period
+        while not self._stop.is_set():
+            # a plain sleep: a quarter less CPU a wake than a timed
+            # Event.wait, and stop() waits at most one period
+            time.sleep(max(due - time.monotonic(), 0.0))
+            woke = time.monotonic()
+            GIL_WAIT.observe(max(woke - due, 0.0))
+            due = woke + PROBE_S
+            if woke >= next_check:
+                next_check = woke + period
+                self._check_stall()
+
+    def _check_stall(self) -> None:
+        last_beat, _lag = self._beat
+        stalled_s = time.monotonic() - last_beat - self.interval_s
+        if stalled_s >= self.warn_after_s:
+            if self._blocked_since is None:
+                self._blocked_since = last_beat
+                self._blocked_events += 1
+                LOOP_BLOCKED.inc()
                 log.warning(
-                    "event loop recovered after ~%.0f ms stall",
-                    duration * 1000,
+                    "event loop blocked for >= %.0f ms — current "
+                    "loop-thread stack:\n%s",
+                    stalled_s * 1000, self._loop_stack(),
                 )
+        elif self._blocked_since is not None:
+            duration = time.monotonic() - self._blocked_since
+            self._blocked_since = None
+            log.warning(
+                "event loop recovered after ~%.0f ms stall",
+                duration * 1000,
+            )
 
     def _loop_stack(self) -> str:
         frames = sys._current_frames()

@@ -5,10 +5,13 @@ event or a gate, none by a sleep: with the pull of group k held, group
 k+1's emit is launched and `overlapped` counts it; a slot comes back
 when the group's last program is seen done, and exactly once whichever
 stage raises; `close()` drains both workers and its deadline still
-abandons a wedged group."""
+abandons a wedged group. Each program's launch call is a span of its
+own on the thread that makes it (`ompb.queue.launch`, with its wall
+and CPU time), closed before the queue notes the launch."""
 
 import concurrent.futures
 import threading
+import time
 
 import jax
 import numpy as np
@@ -20,6 +23,7 @@ from omero_ms_pixel_buffer_tpu.ops.png import decode_png
 from omero_ms_pixel_buffer_tpu.resilience import INJECTOR
 from omero_ms_pixel_buffer_tpu.resilience.faultinject import always
 from omero_ms_pixel_buffer_tpu.runtime import native
+from test_queue_spans import Notes, counts, delta
 
 WAIT = 120  # seconds: every wait of this file is bounded
 N = 16
@@ -391,3 +395,119 @@ def test_closes_deadline_abandons_a_group_wedged_on_either_worker(stage):
             assert not thread.is_alive()
     assert d._pipe.slots._value == 2
     assert d.snapshot()["inflight"] == 0 and d._pipe.emitting == 0
+
+
+# -- the launch span -------------------------------------------------------
+
+# the thread that launches each program
+LAUNCHER = {"hist": "devenc-submit", "compute": "devenc-submit",
+            "emit": "devenc-plan"}
+PROGRAMS = {"dynamic": ["hist", "emit"], "rle": ["compute"]}
+
+
+@pytest.fixture
+def notes(monkeypatch):
+    recorded = Notes()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", recorded)
+    return recorded
+
+
+@pytest.mark.parametrize("width", [1, 4])
+@pytest.mark.parametrize("mode", sorted(PROGRAMS))
+def test_each_program_is_launched_under_a_span_on_the_calling_thread(
+        disp, notes, mode, width):
+    chip = chip_of(width)
+    first = next(disp._gids) + 1
+    assert_pngs(submit(disp, tiles(1), mode, chip).result(timeout=WAIT),
+                tiles(1))
+    launches = [n for n in notes.of_group(first)
+                if n["name"] == "ompb.queue.launch"]
+    assert [n["tags"]["program"] for n in launches] == PROGRAMS[mode]
+    for note in launches:
+        program = note["tags"]["program"]
+        assert note["closed"] == 1
+        assert note["opened_on"] == note["closed_on"]
+        assert note["opened_on"].startswith(LAUNCHER[program])
+        want = {"group": first, "lanes": 2, "program": program}
+        if chip is not None:
+            want["chip"] = chip.id
+        assert note["tags"] == want
+
+
+@pytest.mark.parametrize("mode", sorted(PROGRAMS))
+def test_each_launch_observes_its_wall_and_cpu_time_once(
+        disp, monkeypatch, mode):
+    seen = {"wall": [], "cpu": []}
+    for kind, hist in (("wall", dd.DEVICE_LAUNCH_SECONDS),
+                       ("cpu", dd.DEVICE_LAUNCH_CPU_SECONDS)):
+        def noted(value, exemplar=None, _real=hist.observe, _kind=kind,
+                  **labels):
+            seen[_kind].append((threading.get_ident(), labels, value))
+            _real(value, exemplar, **labels)
+        monkeypatch.setattr(hist, "observe", noted)
+    wall_before = counts(dd.DEVICE_LAUNCH_SECONDS, "program")
+    cpu_before = counts(dd.DEVICE_LAUNCH_CPU_SECONDS, "program")
+    for seed in range(3):
+        assert_pngs(submit(disp, tiles(seed), mode).result(timeout=WAIT),
+                    tiles(seed))
+    want = {program: 3 for program in PROGRAMS[mode]}
+    assert delta(wall_before, counts(dd.DEVICE_LAUNCH_SECONDS,
+                                     "program")) == want
+    assert delta(cpu_before, counts(dd.DEVICE_LAUNCH_CPU_SECONDS,
+                                    "program")) == want
+    # one launch's two observations, made back to back on its thread
+    for thread in {t for t, _, _ in seen["wall"]}:
+        walls = [(l, v) for t, l, v in seen["wall"] if t == thread]
+        cpus = [(l, v) for t, l, v in seen["cpu"] if t == thread]
+        assert [l for l, _ in walls] == [l for l, _ in cpus]
+        for (_, wall), (_, cpu) in zip(walls, cpus):
+            assert 0.0 <= cpu <= wall
+
+
+def test_a_launch_that_raises_closes_its_span_and_observes_nothing(
+        disp, notes, monkeypatch):
+    wall_before = counts(dd.DEVICE_LAUNCH_SECONDS, "program")
+    cpu_before = counts(dd.DEVICE_LAUNCH_CPU_SECONDS, "program")
+    monkeypatch.setattr(device_deflate, "dynamic_emit_planned",
+                        raising(RuntimeError("emit launch failed")))
+    with pytest.raises(RuntimeError, match="emit launch failed"):
+        submit(disp, tiles(2)).result(timeout=WAIT)
+    # pass 1 was launched and observed; the emit's launch raised
+    assert delta(wall_before, counts(dd.DEVICE_LAUNCH_SECONDS,
+                                     "program")) == {"hist": 1}
+    assert delta(cpu_before, counts(dd.DEVICE_LAUNCH_CPU_SECONDS,
+                                    "program")) == {"hist": 1}
+    emits = [n for n in notes.seen if n["name"] == "ompb.queue.launch"
+             and n["tags"]["program"] == "emit"]
+    assert len(emits) == 1 and emits[0]["closed"] == 1
+    assert disp.snapshot()["inflight"] == 0
+
+
+@pytest.mark.parametrize("mode", sorted(PROGRAMS))
+def test_the_queue_notes_a_launch_after_its_span_has_closed(
+        disp, monkeypatch, mode):
+    """The launch span holds the call and nothing else: the stamps
+    `_note_group` / `_note_last_launch` take (and the stage that opens
+    at the launch) come after it, as they came after the call."""
+    closed, noted = [], []
+    real_exit = dd._Launch.__exit__
+
+    def exit_(self, *exc):
+        real_exit(self, *exc)
+        closed.append((self.program, time.perf_counter()))
+
+    monkeypatch.setattr(dd._Launch, "__exit__", exit_)
+    for name in ("_note_group", "_note_last_launch"):
+        real = getattr(disp, name)
+
+        def note(t_launch, *args, _real=real, _name=name):
+            noted.append((_name, t_launch))
+            return _real(t_launch, *args)
+
+        setattr(disp, name, note)
+    assert_pngs(submit(disp, tiles(4), mode).result(timeout=WAIT), tiles(4))
+    ends = dict(closed)
+    first = ends[PROGRAMS[mode][0]]
+    last = ends[PROGRAMS[mode][-1]]
+    assert dict(noted)["_note_group"] >= first
+    assert dict(noted)["_note_last_launch"] >= last

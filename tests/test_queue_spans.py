@@ -24,6 +24,9 @@ PER_GROUP = {
     "stored": {"h2d", "compute", "d2h", "frame"},
     "dynamic": {"h2d", "hist", "plan", "emit", "d2h", "frame"},
 }
+# the programs a group launches, each under an `ompb.queue.launch`
+LAUNCHES = {"rle": ["compute"], "stored": ["compute"],
+            "dynamic": ["emit", "hist"]}
 rng = np.random.default_rng(5)
 
 
@@ -136,11 +139,17 @@ def test_one_annotation_per_stage_and_wait_with_the_groups_id(
         fut.result(timeout=120)
     for gid in (first, first + 1):
         mine = notes.of_group(gid)
-        assert sorted(n["name"] for n in mine) == sorted(
+        stages = [n for n in mine if n["name"] != "ompb.queue.launch"]
+        assert sorted(n["name"] for n in stages) == sorted(
             f"ompb.queue.{s}" for s in PER_GROUP[mode])
         assert all(n["closed"] == 1 for n in mine)
-        assert all(n["tags"] == {"group": gid, "lanes": 2} for n in mine)
-    assert len(notes.seen) == 2 * len(PER_GROUP[mode])  # no wait among them
+        assert all(n["tags"] == {"group": gid, "lanes": 2} for n in stages)
+        launches = [n for n in mine if n["name"] == "ompb.queue.launch"]
+        assert sorted(n["tags"].pop("program") for n in launches) == (
+            LAUNCHES[mode])
+        assert all(n["tags"] == {"group": gid, "lanes": 2} for n in launches)
+    # no wait among them
+    assert len(notes.seen) == 2 * (len(PER_GROUP[mode]) + len(LAUNCHES[mode]))
 
 
 def test_a_stage_that_spans_threads_is_opened_at_the_launch(disp, notes):
@@ -303,7 +312,7 @@ def test_the_annotations_land_in_the_profilers_trace(disp, tmp_path):
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
-    found = {}
+    found, programs = {}, []
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
             continue
@@ -312,9 +321,13 @@ def test_the_annotations_land_in_the_profilers_trace(disp, tmp_path):
                 if event.name.startswith("ompb.queue."):
                     found[event.name] = (dict(event.stats),
                                          event.duration_ns)
+                if event.name == "ompb.queue.launch":
+                    programs.append(dict(event.stats)["program"])
     assert set(found) == {
         f"ompb.queue.{s}" for s in
-        ("h2d", "hist", "plan", "emit", "d2h", "frame")}
+        ("h2d", "hist", "plan", "emit", "d2h", "frame", "launch")}
     for stats, duration in found.values():
         assert stats["group"] == gid and stats["lanes"] == 2
         assert duration >= 0
+    # a string stat: the group's two programs, one launch each
+    assert sorted(programs) == ["emit", "hist"]

@@ -6,6 +6,8 @@ import asyncio
 import logging
 import time
 
+import pytest
+
 from omero_ms_pixel_buffer_tpu.utils.loop_watchdog import LoopWatchdog
 from omero_ms_pixel_buffer_tpu.utils.metrics import REGISTRY
 
@@ -89,6 +91,7 @@ def test_metrics_exported():
     assert "event_loop_lag_seconds" in text
     assert "event_loop_blocked_total" in text
     assert "event_loop_max_lag_seconds" in text
+    assert "gil_wait_seconds" in text
 
 
 async def test_healthz_reports_loop_health(tmp_path, loop):
@@ -123,3 +126,91 @@ async def test_watchdog_disabled_by_config(tmp_path, loop):
         assert app_obj.watchdog is None
     finally:
         await client.close()
+
+
+# -- the GIL probe on the watchdog's thread ----------------------------------
+
+def _gil_waits(over=None):
+    """Observations of `gil_wait_seconds` so far; with `over`, those
+    above that bucket edge."""
+    from omero_ms_pixel_buffer_tpu.utils.loop_watchdog import GIL_WAIT
+
+    lines = {line.rpartition(" ")[0]: float(line.rpartition(" ")[2])
+             for line in GIL_WAIT.collect() if not line.startswith("#")}
+    count = lines.get("gil_wait_seconds_count", 0.0)
+    if over is None:
+        return count
+    return count - lines.get(f'gil_wait_seconds_bucket{{le="{over}"}}', 0.0)
+
+
+def test_the_probe_wakes_about_fifty_times_a_second():
+    async def scenario():
+        wd = LoopWatchdog(interval_s=0.02, warn_after_s=1.0)
+        before = _gil_waits()
+        wd.start()
+        await asyncio.sleep(1.0)
+        wd.stop()
+        return _gil_waits() - before
+
+    woke = asyncio.run(scenario())
+    assert 25 <= woke <= 52  # one wake each 20 ms, never early
+
+
+def _holds_the_gil_for(seconds: float) -> int:
+    """A size for `sum(range(n))`, one C call that never gives the GIL
+    up (a loop of Python statements would: the interpreter hands it
+    over every 5 ms), taking about `seconds`."""
+    n, took = 1_000_000, float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        sum(range(n))
+        took = min(took, time.perf_counter() - t)
+    return int(n * seconds / max(took, 1e-6))
+
+
+def test_a_c_call_that_holds_the_gil_shows_as_the_probes_wait():
+    import threading
+
+    n = _holds_the_gil_for(0.2)
+
+    async def scenario():
+        wd = LoopWatchdog(interval_s=0.02, warn_after_s=1.0)
+        wd.start()
+        await asyncio.sleep(0.1)
+        before = _gil_waits(over=0.05)
+        hog = threading.Thread(target=lambda: sum(range(n)))
+        t = time.perf_counter()
+        hog.start()
+        hog.join()
+        held_ms = 1e3 * (time.perf_counter() - t)
+        await asyncio.sleep(0.1)  # the probe wakes and observes
+        wd.stop()
+        return held_ms, _gil_waits(over=0.05) - before
+
+    held_ms, late = asyncio.run(scenario())
+    assert held_ms >= 100
+    assert late >= 1  # a wake that waited more than 50 ms for the GIL
+
+
+@pytest.mark.parametrize("stall_s, fires", [(0.35, True), (0.04, False)])
+def test_the_stall_warning_fires_once_a_stall_at_its_threshold(
+        caplog, stall_s, fires):
+    async def scenario():
+        wd = LoopWatchdog(interval_s=0.02, warn_after_s=0.1)
+        wd.start()
+        await asyncio.sleep(0.08)
+        with caplog.at_level(
+            logging.WARNING, "omero_ms_pixel_buffer_tpu.loop_watchdog"
+        ):
+            time.sleep(stall_s)  # the loop wedged, the GIL free
+            await asyncio.sleep(0.2)
+        snap = wd.snapshot()
+        wd.stop()
+        return snap
+
+    snap = asyncio.run(scenario())
+    blocked = [r for r in caplog.records
+               if "event loop blocked" in r.getMessage()]
+    assert len(blocked) == (1 if fires else 0)
+    assert snap["blocked_events"] == (1 if fires else 0)
+    assert not snap["blocked"]
